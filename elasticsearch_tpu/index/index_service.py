@@ -7,6 +7,7 @@ MapperService, AnalysisService and the index's IndexShards.
 
 from __future__ import annotations
 
+import contextlib
 import os
 import threading
 
@@ -79,6 +80,8 @@ class IndexService:
         # keep a single lock only for the shared _types.json tmp file
         self._id_locks = [threading.Lock() for _ in range(16)]
         self._meta_lock = threading.Lock()
+        # per-thread write-through batching (batched_meta_saves)
+        self._meta_batch = threading.local()
         self._types_path = (os.path.join(data_path, name, "_types.json")
                             if data_path else None)
         if self._types_path and os.path.exists(self._types_path):
@@ -213,8 +216,30 @@ class IndexService:
     def doc_type_of(self, doc_id: str) -> str:
         return self.doc_types.get(doc_id, "_doc")
 
+    @contextlib.contextmanager
+    def batched_meta_saves(self):
+        """Coalesce this thread's per-op metadata write-through into ONE
+        save at exit — for a bulk request, still BEFORE its response
+        acknowledges any item, so the durability contract of
+        _save_types is unchanged. Without it every op of a bulk rewrites
+        the whole snapshot: O(docs^2) bytes, minutes per 5k-doc chunk
+        once an index holds a few hundred thousand docs."""
+        if getattr(self._meta_batch, "on", False):
+            yield                      # nested: the outer exit saves
+            return
+        self._meta_batch.on, self._meta_batch.dirty = True, False
+        try:
+            yield
+        finally:
+            self._meta_batch.on = False
+            if self._meta_batch.dirty:
+                self._save_types()
+
     def _save_types(self) -> None:
         if self._types_path is None:
+            return
+        if getattr(self._meta_batch, "on", False):
+            self._meta_batch.dirty = True
             return
         import json
         with self._meta_lock:
@@ -229,7 +254,9 @@ class IndexService:
                     "ts": dict(self.doc_ts)}
             tmp = self._types_path + ".tmp"
             with open(tmp, "w") as f:
-                json.dump(snap, f)
+                # dumps, not dump: the C encoder (dump streams through
+                # the pure-Python one, ~10x slower on a 1M-entry map)
+                f.write(json.dumps(snap))
             os.replace(tmp, self._types_path)
 
     # -- maintenance -------------------------------------------------------

@@ -296,7 +296,7 @@ class TilePager:
 
     The lock guards only the residency map bookkeeping; uploads
     (`jax.device_put`) and breaker holds happen OUTSIDE it, so a slow
-    host->device tunnel never convoys concurrent searches (graftlint
+    host->device transfer never convoys concurrent searches (graftlint
     lock-discipline: `tiering` is a hot-lock module)."""
 
     def __init__(self):

@@ -26,32 +26,38 @@ _lib: ctypes.CDLL | None = None
 _tried = False
 
 
+# portable flags only: the checkout (and this cache inside it) is copied
+# between hosts, so the binary must run on any x86-64 — no -march=native.
+# The flags are part of the cached name, so a library built with other
+# flags is never picked up.
+_CXXFLAGS = ("-O3", "-shared", "-fPIC", "-std=c++17")
+
+
 def _build_path() -> str:
+    h = hashlib.sha256(" ".join(_CXXFLAGS).encode())
     with open(_SRC, "rb") as f:
-        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+        h.update(f.read())
     cache = os.environ.get("EST_NATIVE_CACHE",
                            os.path.join(_HERE, "_build"))
     os.makedirs(cache, exist_ok=True)
-    return os.path.join(cache, f"libestnative-{digest}.so")
+    return os.path.join(cache, f"libestnative-{h.hexdigest()[:16]}.so")
 
 
 def _compile(so_path: str) -> bool:
-    cmd = ["g++", "-O3", "-march=native", "-shared", "-fPIC", "-std=c++17",
-           _SRC, "-o", so_path + ".tmp"]
+    # per-process scratch name: test workers that find no library all
+    # build at once, and must not write through one another's file
+    tmp = f"{so_path}.{os.getpid()}.tmp"
+    cmd = ["g++", *_CXXFLAGS, _SRC, "-o", tmp]
     try:
         r = subprocess.run(cmd, capture_output=True, timeout=120)
     except (OSError, subprocess.TimeoutExpired) as e:
         logger.info("native build unavailable: %s", e)
         return False
     if r.returncode != 0:
-        # retry without -march=native (portable fallback)
-        cmd.remove("-march=native")
-        r = subprocess.run(cmd, capture_output=True, timeout=120)
-        if r.returncode != 0:
-            logger.warning("native build failed: %s",
-                           r.stderr.decode(errors="replace")[:500])
-            return False
-    os.replace(so_path + ".tmp", so_path)
+        logger.warning("native build failed: %s",
+                       r.stderr.decode(errors="replace")[:500])
+        return False
+    os.replace(tmp, so_path)
     return True
 
 

@@ -625,14 +625,24 @@ def _bundle_chunk_call(clauses: tuple, arrs: dict, text_cols: dict,
     def _bcast(bi, j):
         return (bi, 0)
 
+    # per-(batch tile, doc tile) operands narrower than a lane tile —
+    # the [bt, 1] bound columns in, the [bt, ck] / [bt, 1] strips out —
+    # carry the doc-tile axis as a LEADING squeezed dimension
+    # ([n_tiles, bp, x], block (None, bt, x)): Mosaic requires a block's
+    # last two dims to be (8, 128)-divisible or span the whole array,
+    # which a (bt, 1) window into [bp, n_tiles] is not. The kernel body
+    # sees the same [bt, x] refs either way.
     def _per_tile(bi, j, t0=t0):
-        return (bi, j + t0)
+        return (j + t0, bi, 0)
 
     def _col(bi, j, t0=t0):
         return (0, j + t0)
 
     def _out(bi, j):
         return (bi, j)
+
+    def _out_tile(bi, j):
+        return (j, bi, 0)
 
     in_specs = [
         pl.BlockSpec((btile, max(qw, 1)), _bcast, memory_space=pltpu.VMEM),
@@ -643,11 +653,12 @@ def _bundle_chunk_call(clauses: tuple, arrs: dict, text_cols: dict,
                      memory_space=pltpu.VMEM),
         pl.BlockSpec((btile, 1), _bcast, memory_space=pltpu.VMEM),
         pl.BlockSpec((btile, 1), _bcast, memory_space=pltpu.VMEM),
-        pl.BlockSpec((btile, 1), _per_tile, memory_space=pltpu.VMEM),
-        pl.BlockSpec((btile, 1), _per_tile, memory_space=pltpu.VMEM),
+        pl.BlockSpec((None, btile, 1), _per_tile, memory_space=pltpu.VMEM),
+        pl.BlockSpec((None, btile, 1), _per_tile, memory_space=pltpu.VMEM),
     ]
     inputs = [arrs["qt"], arrs["wq"], arrs["msmc"], arrs["boostc"],
-              arrs["msm"], arrs["boost"], arrs["can"], arrs["ub"]]
+              arrs["msm"], arrs["boost"],
+              arrs["can"].T[:, :, None], arrs["ub"].T[:, :, None]]
     if ck > 0:
         in_specs.append(pl.BlockSpec((btile, 1), _bcast,
                                      memory_space=pltpu.VMEM))
@@ -700,17 +711,19 @@ def _bundle_chunk_call(clauses: tuple, arrs: dict, text_cols: dict,
     out_shape = []
     if ck > 0:
         out_specs.extend([
-            pl.BlockSpec((btile, ck), _out, memory_space=pltpu.VMEM),
-            pl.BlockSpec((btile, ck), _out, memory_space=pltpu.VMEM)])
+            pl.BlockSpec((None, btile, ck), _out_tile,
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((None, btile, ck), _out_tile,
+                         memory_space=pltpu.VMEM)])
         out_shape.extend([
-            jax.ShapeDtypeStruct((bp, nt * ck), jnp.float32),
-            jax.ShapeDtypeStruct((bp, nt * ck), jnp.int32)])
+            jax.ShapeDtypeStruct((nt, bp, ck), jnp.float32),
+            jax.ShapeDtypeStruct((nt, bp, ck), jnp.int32)])
     out_specs.extend([
-        pl.BlockSpec((btile, 1), _out, memory_space=pltpu.VMEM),
-        pl.BlockSpec((btile, 1), _out, memory_space=pltpu.VMEM)])
+        pl.BlockSpec((None, btile, 1), _out_tile, memory_space=pltpu.VMEM),
+        pl.BlockSpec((None, btile, 1), _out_tile, memory_space=pltpu.VMEM)])
     out_shape.extend([
-        jax.ShapeDtypeStruct((bp, nt), jnp.int32),
-        jax.ShapeDtypeStruct((bp, nt), jnp.int32)])
+        jax.ShapeDtypeStruct((nt, bp, 1), jnp.int32),
+        jax.ShapeDtypeStruct((nt, bp, 1), jnp.int32)])
     if ck > 0:
         out_specs.append(pl.BlockSpec((btile, 1), _bcast,
                                       memory_space=pltpu.VMEM))
@@ -720,7 +733,7 @@ def _bundle_chunk_call(clauses: tuple, arrs: dict, text_cols: dict,
                                       memory_space=pltpu.VMEM))
         out_shape.append(jax.ShapeDtypeStruct((bp, nt * tile), jnp.int32))
     scratch = [pltpu.VMEM((btile, LANES), jnp.float32)] if ck > 0 else []
-    return pl.pallas_call(
+    out = list(pl.pallas_call(
         kern,
         grid=(bp // btile, nt),
         in_specs=in_specs,
@@ -728,7 +741,12 @@ def _bundle_chunk_call(clauses: tuple, arrs: dict, text_cols: dict,
         out_shape=out_shape,
         scratch_shapes=scratch,
         interpret=interpret,
-    )(*inputs)
+    )(*inputs))
+    # back to the batch-major strips callers concatenate across chunks:
+    # [nt, bp, x] -> [bp, nt * x] (tile-major within a row, as before)
+    for i in range((2 if ck > 0 else 0) + 2):
+        out[i] = out[i].transpose(1, 0, 2).reshape(bp, -1)
+    return out
 
 
 def _stack_bundle_inputs(clauses: tuple, cl_inputs: tuple):
@@ -1056,10 +1074,9 @@ def pallas_enabled() -> bool:
         return False
     if flag in ("1", "true", "on"):
         return True
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    # a backend that cannot initialise raises here, on the served path:
+    # answering "no kernels" would quietly serve everything from XLA
+    return jax.default_backend() == "tpu"
 
 
 def resident_step_ok() -> bool:
@@ -1080,7 +1097,4 @@ def resident_step_ok() -> bool:
 def interpret_mode() -> bool:
     """Forced-on kernels off-TPU must run the Pallas interpreter —
     Mosaic lowering only exists for TPU backends."""
-    try:
-        return jax.default_backend() != "tpu"
-    except Exception:
-        return True
+    return jax.default_backend() != "tpu"

@@ -33,24 +33,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # jax >= 0.8
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-import inspect as _inspect
-
-if "check_vma" in _inspect.signature(_shard_map).parameters:
-    shard_map = _shard_map
-else:
-    # older jax spells the replication check `check_rep`; translate so
-    # the call sites stay on the current-jax spelling
-    def shard_map(f=None, **kw):
-        kw["check_rep"] = kw.pop("check_vma", True)
-        return _shard_map(f, **kw) if f is not None else \
-            (lambda g: _shard_map(g, **kw))
 
 from ..index.mapping import MapperService
 from ..index.segment import (Segment, SegmentBuilder, next_pow2,
@@ -1023,7 +1007,7 @@ class DistributedSearcher:
                                ) -> list[dict]:
         """Collect with the OTHER half of replica failover: jax
         dispatch is asynchronous, so a real device failure (preemption,
-        tunnel drop, OOM) usually surfaces at the device_get inside
+        runtime drop, OOM) usually surfaces at the device_get inside
         _collect_uniform, not at enqueue — on such an error the whole
         dispatch+collect is re-entered once per remaining replica row.
         Deadline and request-shaped errors never retry, and a deadline
@@ -1099,7 +1083,7 @@ class DistributedSearcher:
 
         Scope note: a retry RE-ENTERS the same SPMD program — the
         collective spans every replica row, so this recovers TRANSIENT
-        failures (preempted queue, tunnel drop, an injected fault
+        failures (preempted queue, runtime drop, an injected fault
         pinned to one replica row via `replica=`), which is what
         replication buys without resharding. A device that is
         permanently dead fails every re-entry; the wired-in `health`

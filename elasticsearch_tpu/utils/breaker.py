@@ -19,22 +19,26 @@ import threading
 from .errors import CircuitBreakingError
 from .settings import Settings
 
-_DEFAULT_TOTAL = 16 * 1024 ** 3  # v5e has 16GB HBM/chip; overridden when detectable
+# the CPU platform reports no memory limit (tests, the CPU rehearsal):
+# account against one v5e chip's 16 GB there, and only there
+_CPU_TOTAL = 16 * 1024 ** 3
 
 
 def _device_memory_bytes() -> int:
-    try:
-        import jax
+    """Per-device HBM from the device itself. On an accelerator a
+    missing `bytes_limit` is an error — guessing a size there would
+    let the breakers admit what the chip cannot hold."""
+    import jax
 
-        d = jax.devices()[0]
-        stats = getattr(d, "memory_stats", None)
-        if stats:
-            limit = (stats() or {}).get("bytes_limit")
-            if limit:
-                return int(limit)
-    except Exception:
-        pass
-    return _DEFAULT_TOTAL
+    d = jax.devices()[0]
+    if d.platform == "cpu":
+        return _CPU_TOTAL
+    limit = (d.memory_stats() or {}).get("bytes_limit")
+    if not limit:
+        raise RuntimeError(
+            f"device {d} reports no memory_stats()['bytes_limit']; the "
+            f"HBM breakers cannot be sized")
+    return int(limit)
 
 
 class Hold:

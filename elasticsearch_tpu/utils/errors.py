@@ -306,7 +306,7 @@ class LeaseFencedError(ElasticsearchTpuError):
 
 class FaultInjectedError(ElasticsearchTpuError):
     """A deterministic injected fault (utils/faults.py) standing in for
-    a real device/shard failure — OOM, preemption, tunnel drop."""
+    a real device/shard failure — OOM, preemption, runtime drop."""
 
     status = 500
 

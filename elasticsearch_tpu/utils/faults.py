@@ -6,7 +6,7 @@ TransportSearchTypeAction.onFirstPhaseResult) with MockTransportService
 disruptions and ESIntegTestCase's random shard failures. A device-mesh
 stack has no wire to cut, so this registry injects the equivalent
 failure classes AT the dispatch boundary — the reader/executor seam a
-real device error (OOM, preemption, tunnel drop) would surface through:
+real device error (OOM, preemption, runtime drop) would surface through:
 
   * ``shard_error``  — dispatch raises FaultInjectedError (a dead shard)
   * ``shard_delay``  — dispatch sleeps (a straggler shard; deadline food)

@@ -208,3 +208,39 @@ def test_multi_field_subtypes(node):
     assert r["hits"]["total"] == 1
     r2 = node.search("mf", {"query": {"term": {"status": "Not Found Error"}}})
     assert r2["hits"]["total"] == 1
+
+
+def test_bulk_writes_doc_metadata_through_once_per_request(tmp_path,
+                                                           monkeypatch):
+    """A durable index's per-doc metadata snapshot (_types.json) is
+    written through ONCE per bulk request, before the response — not
+    once per item (which made a bulk load quadratic in index size) —
+    and still holds every item's entry when the bulk returns."""
+    import json
+    import os
+
+    from elasticsearch_tpu.node import Node
+
+    node = Node({"path.data": str(tmp_path / "data")})
+    try:
+        node.create_index("logs")
+        replaced = []
+        real_replace = os.replace
+
+        def counting_replace(src, dst):
+            if dst.endswith("_types.json"):
+                replaced.append(dst)
+            return real_replace(src, dst)
+
+        monkeypatch.setattr(os, "replace", counting_replace)
+        r = node.bulk([("index", {"_index": "logs", "_id": str(i),
+                                  "doc": {"n": i}}) for i in range(50)])
+        assert not r["errors"]
+        assert len(replaced) == 1
+        with open(replaced[0]) as f:
+            assert set(json.load(f)["ts"]) == {str(i) for i in range(50)}
+        # a lone write still writes through on its own
+        node.index_doc("logs", "solo", {"n": -1})
+        assert len(replaced) == 2
+    finally:
+        node.close()
