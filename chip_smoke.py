@@ -419,10 +419,41 @@ def positional_plan(choice_key: str) -> bool:
     return "phrase_pos" in choice_key
 
 
-def fused_stats(http: Http) -> dict:
+# the by-reason maps of `fused_scoring.admission`: a reason that did
+# not move since the snapshot is left out, as one that never occurred is
+REASON_MAPS = ("rejected", "pallas_rejected", "knn", "positional_fallbacks")
+
+
+def moved(before: dict, after: dict) -> dict:
+    """What a section of `_nodes/stats` moved by between two reads:
+    counts subtract, rates (of the whole process) are left out, and of
+    the backend choices only those made or remade since stay."""
+    out = {}
+    for k, v in after.items():
+        b = before.get(k)
+        if k == "backend_choices":
+            out[k] = {p: c for p, c in v.items() if (b or {}).get(p) != c}
+        elif isinstance(v, dict):
+            out[k] = moved(b or {}, v)
+            if k in REASON_MAPS:
+                out[k] = {r: n for r, n in out[k].items() if n}
+        elif k in ("rate", "prune_rate"):
+            continue
+        elif isinstance(v, (int, float)) and not isinstance(v, bool):
+            out[k] = v - (b or 0)
+        else:
+            out[k] = v
+    return out
+
+
+def fused_stats(http: Http, since: dict | None = None) -> dict:
+    """The node's `fused_scoring` section. Its counters are
+    process-wide (an earlier node of this process has moved them), so a
+    pass reads them as what they `moved` by `since` the snapshot it
+    took when it started."""
     _st, stats = http.call("GET", "/_nodes/stats")
-    node_stats = next(iter(stats["nodes"].values()))
-    return node_stats["fused_scoring"]
+    section = next(iter(stats["nodes"].values()))["fused_scoring"]
+    return section if since is None else moved(since, section)
 
 
 class Served:
@@ -521,7 +552,8 @@ def run_one_chip(args, chk: Checks, jax, clock: CompileClock) -> None:
 
         # -- answer: one pass per engine --------------------------------------
         answers: dict = {}
-        dispatches = [fused_stats(http)["dispatches"]]
+        base = fused_stats(http)
+        dispatches = [0]
         choices_after: dict = {}
         for engine in ENGINES:
             name = engine or "auto"
@@ -548,7 +580,7 @@ def run_one_chip(args, chk: Checks, jax, clock: CompileClock) -> None:
             chk.check(f"{name}: every response 200, timed_out false, "
                       f"_shards.failed == 0", all_ok)
             answers[name] = got
-            fs = fused_stats(http)
+            fs = fused_stats(http, base)
             dispatches.append(fs["dispatches"])
             choices_after[name] = fs["backend_choices"]
             log(f"answer[{name}]: {len(got)} responses in "
@@ -581,7 +613,7 @@ def run_one_chip(args, chk: Checks, jax, clock: CompileClock) -> None:
                   n_hits > len(specs) // 2, f"{n_hits}/{len(specs)}")
 
         # -- nothing hid the device -------------------------------------------
-        fs = fused_stats(http)
+        fs = fused_stats(http, base)
         adm = fs["admission"]
         log("fused_scoring.admission:", json.dumps(adm))
         for name, ch in choices_after.items():
@@ -653,6 +685,7 @@ def run_four_chips(args, chk: Checks, jax, clock: CompileClock) -> None:
     on_tpu = jax.devices()[0].platform == "tpu"
     with Served(args, chk) as served:
         node, corpus = served.node, served.corpus
+        base = fused_stats(served.http)
         singles, multi = make_workload(corpus, args.seed)
         work = singles + multi
         labels = [f"{i}/{s}" for i, (s, _b, _spec) in enumerate(work)]
@@ -711,7 +744,7 @@ def run_four_chips(args, chk: Checks, jax, clock: CompileClock) -> None:
                 f"{time.perf_counter() - t:.1f}s, compile "
                 f"{clock.seconds - c0:.1f}s")
             del mi
-        fs = fused_stats(served.http)
+        fs = fused_stats(served.http, base)
         log("fused_scoring.admission:", json.dumps(fs["admission"]))
         log("backend choices:", json.dumps(sorted(
             {(c["backend"], c["reason"])

@@ -116,7 +116,9 @@ class IndexOpStats:
     def on_fetch(self, took_ms: float = 0.0) -> None:
         with self._lock:
             self.fetch_total += 1
-            self.fetch_time_ms += int(took_ms)
+            # kept as a float: one search's fetch is a fraction of a
+            # millisecond, and whole ones would all count 0
+            self.fetch_time_ms += took_ms
 
     def on_build(self, took_ms: float = 0.0, docs: int = 0,
                  device: bool = False) -> None:

@@ -22,6 +22,7 @@ from .utils.settings import Settings, parse_time_value as _parse_time_value
 from .utils.errors import (IndexNotFoundError, IndexAlreadyExistsError,
                            ElasticsearchTpuError, IllegalArgumentError,
                            SearchTimeoutError, ShardFailedError)
+from .utils import profiler
 from .utils.metrics import MetricsRegistry
 from .index.index_service import IndexService
 from .search.controller import (merge_shard_results, shards_header,
@@ -946,7 +947,8 @@ class Node:
     def search(self, index: str | None, body: dict | None = None,
                scroll: str | None = None,
                search_type: str | None = None,
-               tenant: str | None = None) -> dict:
+               tenant: str | None = None,
+               request: "profiler.Request | None" = None) -> dict:
         """Admission control FIRST (search/traffic.py): the tenant's
         token bucket / concurrency quota sheds over-quota load with a
         structured 429 (TrafficRejectedError carries retry_after)
@@ -958,33 +960,66 @@ class Node:
         threads (ref: ThreadPool.java:112-127 SEARCH pool +
         EsRejectedExecutionException). Pool threads re-entering search
         (template/inner flows) run inline to stay deadlock-free and
-        are NOT re-admitted — the outer request already paid."""
+        are NOT re-admitted — the outer request already paid.
+
+        `request` is what the REST handler made for this search
+        (utils/profiler.Request: its id on every phase span, and its
+        `rest_parse` phase, out of which the search takes its time); an
+        in-process caller gets its id here. The two hand-overs, to the
+        pool thread and back, are the `pool_wait` timer."""
         if threading.current_thread().name.startswith("pool-search"):
             return self._search_inner(index, body, scroll, search_type)
+        if request is None:
+            request = profiler.Request()
+        if request.parse is not None:
+            request.parse.pause()
+        request.hand_over()
         ticket = self.traffic.admit(tenant, "search")
         try:
             pool = self.thread_pool.executor("search")
-            return pool.submit(self._search_inner, index, body, scroll,
-                               search_type, ticket.lane).result()
+            result = pool.submit(self._search_inner, index, body, scroll,
+                                 search_type, ticket.lane,
+                                 request).result()
         finally:
             ticket.release()
+        request.taken()
+        profiler.waited("pool_wait", request.waited_s)
+        if request.parse is not None:
+            request.parse.resume()
+        return result
 
     def _search_inner(self, index: str | None, body: dict | None = None,
                       scroll: str | None = None,
                       search_type: str | None = None,
-                      lane: str = "interactive") -> dict:
+                      lane: str = "interactive",
+                      request: "profiler.Request | None" = None) -> dict:
+        if request is not None:
+            request.taken()
         batch = self._dispatch.batch(lane=lane)
-        st = self._search_submit(index, body, scroll, search_type, batch)
+        rid = request.id if request is not None else None
+        st = self._search_submit(index, body, scroll, search_type, batch,
+                                 rid)
         batch.dispatch()
-        return self._search_finish(st)
+        result = self._search_finish(st)
+        if request is not None:
+            request.hand_over()
+        return result
 
     def _search_submit(self, index: str | None, body: dict | None,
                        scroll: str | None, search_type: str | None,
-                       batch) -> dict:
+                       batch, request: int | None = None) -> dict:
         """Resolve + bind + enqueue the fan-out of one search onto a
         dispatch batch (search/dispatch.py) WITHOUT collecting — so
         msearch / concurrent callers can coalesce identical plans and
-        pipeline the rest before any device round trip completes."""
+        pipeline the rest before any device round trip completes.
+        The `resolve` phase; `request` is the id its spans carry."""
+        with profiler.phase("resolve", **profiler.request_args([request])):
+            return self._search_resolve(index, body, scroll, search_type,
+                                        batch, request)
+
+    def _search_resolve(self, index: str | None, body: dict | None,
+                        scroll: str | None, search_type: str | None,
+                        batch, request: int | None) -> dict:
         body = body or {}
         services = self._resolve(index)
         shard_readers: list[tuple[str, ShardReader]] = []
@@ -1028,20 +1063,28 @@ class Node:
         if timeout not in (None, "", -1, "-1"):
             deadline = started + parse_time_value(timeout, 0) / 1000.0
         exec_st = self._submit_on_readers(shard_readers, body, batch,
-                                          deadline=deadline)
+                                          deadline=deadline,
+                                          request=request)
         if prefailed:
             exec_st["prefailed"] = prefailed
         return {"services": services, "shard_readers": shard_readers,
                 "body": body, "scan_mode": scan_mode, "scroll": scroll,
-                "started": started, "exec": exec_st}
+                "started": started, "exec": exec_st, "request": request}
 
     def _search_finish(self, st: dict) -> dict:
+        result = self._finish_on_readers(st["exec"])
+        with profiler.phase("finish",
+                            **profiler.request_args([st["request"]])):
+            return self._search_account(st, result)
+
+    def _search_account(self, st: dict, result: dict) -> dict:
+        """The rest of a search once its shards are reduced: slowlog,
+        search stats, doc types, the scroll context."""
         services = st["services"]
         shard_readers = st["shard_readers"]
         body = st["body"]
         scan_mode = st["scan_mode"]
         scroll = st["scroll"]
-        result = self._finish_on_readers(st["exec"])
         took_ms = (time.monotonic() - st["started"]) * 1000.0
         self._search_slowlog(services, body, took_ms)
         # query counter + per-group search stats (ref: body `stats`
@@ -1049,7 +1092,7 @@ class Node:
         # program here (query_then_fetch fused), suggest when requested
         for svc in services:
             svc.op_stats.on_search(body.get("stats"), took_ms)
-            svc.op_stats.on_fetch(0.0)
+            svc.op_stats.on_fetch(1e3 * st["exec"].get("fetch_s", 0.0))
             if body.get("suggest"):
                 svc.op_stats.on_suggest(took_ms)
         # surface stored per-doc mapping types on hits (no-op when the
@@ -1168,7 +1211,8 @@ class Node:
 
     def _submit_on_readers(self, shard_readers: list[tuple[str, ShardReader]],
                            body: dict, batch,
-                           deadline: float | None = None) -> dict:
+                           deadline: float | None = None,
+                           request: int | None = None) -> dict:
         """Enqueue the per-shard fan-out of one request onto a dispatch
         batch. Identical plans from other requests on the same batch
         coalesce into ONE batched device program; the rest dispatch
@@ -1179,7 +1223,7 @@ class Node:
             ap = self.settings.get_bool(
                 "search.default_allow_partial_results", True)
         st: dict = {"shard_readers": shard_readers, "body": body,
-                    "allow_partial": bool(ap)}
+                    "allow_partial": bool(ap), "request": request}
         if not shard_readers:
             st["empty"] = True
             return st
@@ -1220,7 +1264,7 @@ class Node:
                 self.traffic.note_cache(hit=r is not None)
             if r is None:
                 job = batch.submit(reader, shard_body, with_partials=True,
-                                   deadline=deadline)
+                                   deadline=deadline, request=request)
                 entries.append(("job", svc if use_cache else None,
                                 reader, cache_key, job))
             else:
@@ -1229,13 +1273,24 @@ class Node:
         return st
 
     def _finish_on_readers(self, st: dict) -> dict:
+        """The `reduce` phase: collect the shard results of one search
+        and merge them. The significant-terms sub-aggregations search
+        again and so stay outside the span (spans are leaves)."""
+        with profiler.phase("reduce",
+                            **profiler.request_args([st.get("request")])):
+            out, agg_specs = self._reduce_on_readers(st)
+        if agg_specs is not None:
+            self._apply_sig_subs(out, agg_specs, st["body"],
+                                 st["shard_readers"])
+        return out
+
+    def _reduce_on_readers(self, st: dict) -> tuple[dict, list | None]:
         body = st["body"]
         prefailed = st.get("prefailed") or []
         if st.get("empty") and not prefailed:
             # zero shards: empty result (ref: empty SearchResponse)
             return merge_shard_results([], [], [], 0,
-                                       int(body.get("size", 10)))
-        shard_readers = st["shard_readers"]
+                                       int(body.get("size", 10))), None
         agg_specs = parse_aggs(body.get("aggs") or body.get("aggregations"))
         suggest_specs = parse_suggest(body.get("suggest"))
         frm = int(body.get("from", 0))
@@ -1278,6 +1333,7 @@ class Node:
                         reader.shard_id, reader.index_name, e,
                         node=self.name))
                     continue
+                st["fetch_s"] = st.get("fetch_s", 0.0) + payload.fetch_s
                 if svc is not None:
                     svc.request_cache.put(reader, cache_key, r)
             else:
@@ -1335,8 +1391,7 @@ class Node:
                                   failures=failures, timed_out=timed_out)
         if suggest_specs:
             out["suggest"] = merge_suggests(suggest_parts, suggest_specs)
-        self._apply_sig_subs(out, agg_specs, body, shard_readers)
-        return out
+        return out, agg_specs
 
     def _apply_sig_subs(self, out: dict, agg_specs, body: dict,
                         shard_readers) -> None:
@@ -2665,7 +2720,7 @@ class Node:
                            "query_current": 0,
                            "fetch_total": sum(o.fetch_total for o in ops),
                            "fetch_time_in_millis":
-                               sum(o.fetch_time_ms for o in ops),
+                               int(sum(o.fetch_time_ms for o in ops)),
                            "fetch_current": 0},
                 "merges": {"current": 0, "current_docs": 0,
                            "current_size_in_bytes": 0,

@@ -15,10 +15,12 @@ from __future__ import annotations
 import json
 import re
 import threading
+import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from urllib.parse import urlparse, parse_qs, unquote
 
 from ..node import Node
+from ..utils import profiler
 from ..utils.errors import (ElasticsearchTpuError, IllegalArgumentError,
                             IndexNotFoundError)
 from .. import __version__
@@ -885,7 +887,8 @@ def register_routes(d: RestDispatcher) -> None:
         return node.search(None, _search_body(params, body),
                            scroll=params.get("scroll"),
                            search_type=params.get("search_type"),
-                           tenant=params.get("tenant_id"))
+                           tenant=params.get("tenant_id"),
+                           request=params.get("__request"))
 
     @d.route("GET", "/{index}/_search")
     @d.route("POST", "/{index}/_search")
@@ -893,7 +896,8 @@ def register_routes(d: RestDispatcher) -> None:
         return node.search(index, _search_body(params, body),
                            scroll=params.get("scroll"),
                            search_type=params.get("search_type"),
-                           tenant=params.get("tenant_id"))
+                           tenant=params.get("tenant_id"),
+                           request=params.get("__request"))
 
     # indexed search templates (ref: RestPutSearchTemplateAction — ES 2.0
     # stored them in the .scripts index under lang `mustache`)
@@ -1986,7 +1990,8 @@ def register_routes(d: RestDispatcher) -> None:
         idx = None if index in ("_all", "*") else index
         return node.search(idx, _search_body(params, body),
                            scroll=params.get("scroll"),
-                           search_type=params.get("search_type"))
+                           search_type=params.get("search_type"),
+                           request=params.get("__request"))
 
     @d.route("GET", "/{index}/{type}/_count")
     @d.route("POST", "/{index}/{type}/_count")
@@ -2172,9 +2177,18 @@ class RestServer:
             def log_message(self, fmt, *args):  # quiet by default
                 pass
 
+            # the search this connection's thread is handling, if it is
+            # one (utils/profiler.Request): `_respond` is then its
+            # `respond` phase
+            _search = None
+
             def _respond(self, status: int, payload, pretty: bool = False,
                          head_only: bool = False, fmt: str | None = None,
                          headers: dict | None = None):
+                if self._search is not None:
+                    # the REST thread's last leaf, to the end of the
+                    # block that `_handle` opened as `rest_parse`
+                    self._search.parse.switch("respond")
                 if isinstance(payload, (dict, list)):
                     if fmt and fmt != "json":
                         from ..utils.xcontent import render_body
@@ -2197,10 +2211,31 @@ class RestServer:
                     self.wfile.write(data)
 
             def _handle(self, method: str):
+                """A `_search` is timed from this first line to its last
+                write. One block tiles this thread's part: `rest_parse`,
+                out of which node.search takes the time the request is
+                with other threads, then `respond`; the whole is the
+                `request` timer, and `request:search` the enclosing span
+                for a person at a trace viewer."""
+                if not self.path.partition("?")[0].rstrip("/").endswith(
+                        "/_search"):
+                    self._search = None
+                    return self._serve(method, None)
+                t0 = time.perf_counter()
+                req = self._search = profiler.Request()
+                with profiler.enclosing("request:search", request=req.id):
+                    with profiler.phase("rest_parse",
+                                        request=req.id) as req.parse:
+                        self._serve(method, req)
+                profiler.waited("request", time.perf_counter() - t0)
+
+            def _serve(self, method: str, search):
                 parsed = urlparse(self.path)
                 req_path = parsed.path
                 params = {k: v[0] for k, v in parse_qs(parsed.query).items()
                           if v}
+                if search is not None:
+                    params["__request"] = search
                 # bare flags like ?pretty
                 for flag in parsed.query.split("&"):
                     if flag and "=" not in flag:

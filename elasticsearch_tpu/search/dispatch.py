@@ -47,25 +47,27 @@ counters under `["dispatch"]["traffic"]`).
 
 from __future__ import annotations
 
+import contextlib
 import os
 import threading
 import time
 
+from ..utils import profiler
 from ..utils.errors import SearchTimeoutError
 from ..utils.metrics import CounterMetric, HighWaterMetric
 
 # thread-local mirror of the LAST msearch submit's (group_sizes,
-# dispatch_count) on the CURRENT thread — how the scheduler's sync path
-# (which calls the plain reader.msearch wrapper, so monkeypatch-friendly
-# test seams keep working) reads coalescing stats without a shared
-# mutable attribute on the reader. Writers: ShardReader.msearch and
+# dispatch_count, per-body fetch seconds) on the CURRENT thread — how
+# the scheduler's sync path (which calls the plain reader.msearch
+# wrapper, so monkeypatch-friendly test seams keep working) reads
+# coalescing stats without a shared mutable attribute on the reader. Writers: ShardReader.msearch and
 # DistributedSearcher.msearch, at the END of each call (so nested
 # auxiliary msearch calls inside response building do not win).
 submit_stats = threading.local()
 
 
-def note_submit_stats(group_sizes, dispatches: int) -> None:
-    submit_stats.value = (list(group_sizes), dispatches)
+def note_submit_stats(group_sizes, dispatches: int, fetch_s=()) -> None:
+    submit_stats.value = (list(group_sizes), dispatches, list(fetch_s))
 
 
 class FailoverStats:
@@ -267,6 +269,7 @@ class DispatchStats:
 
     def snapshot(self) -> dict:
         from ..utils import race_guard, trace_guard
+        from .executor import launch_counts
         from .resident import resident_stats
         wb = self._window_batches.count
         wc = self._window_coalesced.count
@@ -291,6 +294,12 @@ class DispatchStats:
             # hits, evictions, preemptions, residency bytes — all zero
             # with ES_TPU_RESIDENT_LOOP unset
             "resident": resident_stats(),
+            # where a served search's time goes (utils/profiler.py):
+            # {name: {"count", "sum", "mean"}} in seconds per phase and
+            # wait, always on; and device-program launches by backend.
+            # Process-wide, so read them as deltas
+            "phases": profiler.phase_stats(),
+            "launches": launch_counts(),
         }
         if self.traffic is not None:
             snap["traffic"] = self.traffic.snapshot()
@@ -316,15 +325,29 @@ class _Job:
     (node._finish_on_readers) converts that into a failed-by-timeout
     shard on a `timed_out: true` response."""
 
-    __slots__ = ("reader", "body", "with_partials", "deadline", "_result",
-                 "_error", "_done")
+    __slots__ = ("reader", "body", "with_partials", "deadline", "request",
+                 "t_handed", "waited_s", "fetch_s", "_result", "_error",
+                 "_done")
 
     def __init__(self, reader, body: dict, with_partials: bool,
-                 deadline: float | None = None):
+                 deadline: float | None = None,
+                 request: int | None = None):
         self.reader = reader
         self.body = body
         self.with_partials = with_partials
         self.deadline = deadline
+        # the request's id (utils/profiler.next_request_id), named on
+        # the phase spans of whatever dispatch serves this job
+        self.request = request
+        # `scheduler_wait`, two hand-overs summed: DispatchBatch
+        # .dispatch() entry to where this job's group starts executing
+        # on whichever thread leads, and the group's end to dispatch()'s
+        # return on the caller's thread. `t_handed` is the perf_counter
+        # reading carried across each (None: not handed over)
+        self.t_handed: float | None = None
+        self.waited_s = 0.0
+        # seconds the reader spent building this job's response
+        self.fetch_s = 0.0
         self._result = None
         self._error = None
         self._done = False
@@ -352,8 +375,9 @@ class DispatchBatch:
         self._done = threading.Event()
 
     def submit(self, reader, body: dict, with_partials: bool = False,
-               deadline: float | None = None) -> _Job:
-        job = _Job(reader, body, with_partials, deadline)
+               deadline: float | None = None,
+               request: int | None = None) -> _Job:
+        job = _Job(reader, body, with_partials, deadline, request)
         self.jobs.append(job)
         return job
 
@@ -363,7 +387,15 @@ class DispatchBatch:
         if not self.jobs:
             self._done.set()
             return
+        t = time.perf_counter()
+        for j in self.jobs:
+            j.t_handed = t
         self._scheduler.run(self)
+        t = time.perf_counter()
+        for j in self.jobs:
+            if j.t_handed is not None:
+                profiler.waited("scheduler_wait",
+                                j.waited_s + t - j.t_handed)
 
 
 class DispatchScheduler:
@@ -507,23 +539,48 @@ class DispatchScheduler:
                 self._traffic.window.observe_round(len(round_))
             first = False
             try:
-                self._execute([j for b in round_ for j in b.jobs])
+                with profiler.enclosing("request:round",
+                                        batches=len(round_)):
+                    self._execute([j for b in round_ for j in b.jobs])
             finally:
                 for b in round_:
                     b._done.set()
 
     # -- execution ---------------------------------------------------------
     @staticmethod
-    def _deadline_kw(g: list[_Job]) -> dict:
-        """Deadline kwargs for a coalesced group's reader call — empty
-        when no deadline, so plain mock readers without the kwarg keep
-        working. Grouping buckets deadlines to 10 ms (see _execute), so
-        members differ by less than a bucket; the LATEST wins — a
-        cooperative timeout may fire a few ms late but must never fail
-        a request before its own deadline."""
-        if g[0].deadline is None:
-            return {}
-        return {"deadline": max(j.deadline for j in g)}
+    def _call_kw(g: list[_Job]) -> dict:
+        """Deadline and request-id kwargs for a coalesced group's reader
+        call — each left out when the group has none, so plain mock
+        readers without the kwarg keep working. Grouping buckets
+        deadlines to 10 ms (see _execute), so members differ by less
+        than a bucket; the LATEST wins — a cooperative timeout may fire
+        a few ms late but must never fail a request before its own
+        deadline."""
+        kw: dict = {}
+        if g[0].deadline is not None:
+            kw["deadline"] = max(j.deadline for j in g)
+        if any(j.request is not None for j in g):
+            kw["requests"] = [j.request for j in g]
+        return kw
+
+    @staticmethod
+    @contextlib.contextmanager
+    def _serving(g: list[_Job]):
+        """The leading thread works for this group inside the block: its
+        jobs' wait (the coalescing window's sleep and the groups ahead
+        of it in the round included) ends here and begins again after
+        it, for the round's end and their caller's wake-up."""
+        now = time.perf_counter()
+        for j in g:
+            if j.t_handed is not None:
+                j.waited_s += now - j.t_handed
+        try:
+            yield
+        finally:
+            now = time.perf_counter()
+            for j in g:
+                if j.t_handed is not None:
+                    j.t_handed = now
 
     def _fail_or_isolate(self, g: list[_Job], e: Exception) -> None:
         """A group's shared execution failed: retry singly so
@@ -575,28 +632,33 @@ class DispatchScheduler:
                 # error in the isolated fallback
                 self._run_sync(g)
                 continue
-            try:
-                pend = g[0].reader.msearch_submit(
-                    [j.body for j in g], g[0].with_partials,
-                    **self._deadline_kw(g))
-            except Exception:  # noqa: BLE001 — submit-time (parse) error
-                self._run_isolated(g)
-                continue
+            with self._serving(g):
+                try:
+                    pend = g[0].reader.msearch_submit(
+                        [j.body for j in g], g[0].with_partials,
+                        **self._call_kw(g))
+                except Exception:  # noqa: BLE001 — submit-time (parse)
+                    self._run_isolated(g)
+                    continue
             pendings.append((g, pend))
         # depth = device programs enqueued before the first collection —
         # the number of dispatch round trips actually overlapped
         self.stats.pipeline_depth.record(
             sum(p.dispatch_count for _g, p in pendings))
         for g, pend in pendings:
-            try:
-                rs = pend.finish()
-            except Exception as e:  # noqa: BLE001 — one bad body fails
-                # the shared program (see _fail_or_isolate)
-                self._fail_or_isolate(g, e)
-                continue
-            for j, r in zip(g, rs):
-                j._result = r
-                j._done = True
+            with self._serving(g):
+                try:
+                    rs = pend.finish()
+                except Exception as e:  # noqa: BLE001 — one bad body
+                    # fails the shared program (see _fail_or_isolate)
+                    self._fail_or_isolate(g, e)
+                    continue
+                for j, r in zip(g, rs):
+                    j._result = r
+                    j._done = True
+            # a mesh searcher's pend keeps no fetch times
+            for j, fetch_s in zip(g, getattr(pend, "fetch_s", ())):
+                j.fetch_s = fetch_s
             self.stats.record_groups(pend.group_sizes,
                                      pend.dispatch_count)
         for j in jobs:  # backstop: no job may leave undecided
@@ -607,22 +669,25 @@ class DispatchScheduler:
     def _run_sync(self, g: list[_Job]) -> None:
         reader = g[0].reader
         submit_stats.value = None
-        try:
-            rs = reader.msearch([j.body for j in g], g[0].with_partials,
-                                **self._deadline_kw(g))
-        except Exception as e:  # noqa: BLE001
-            self._fail_or_isolate(g, e)
-            return
-        for j, r in zip(g, rs):
-            j._result = r
-            j._done = True
+        with self._serving(g):
+            try:
+                rs = reader.msearch([j.body for j in g],
+                                    g[0].with_partials, **self._call_kw(g))
+            except Exception as e:  # noqa: BLE001
+                self._fail_or_isolate(g, e)
+                return
+            for j, r in zip(g, rs):
+                j._result = r
+                j._done = True
         sub = getattr(submit_stats, "value", None)
         if sub is not None:
             # msearch_submit enqueued every group x segment program
             # before its finish collected any — that WAS the in-flight
             # depth, even through the sync wrapper
             self.stats.pipeline_depth.record(sub[1])
-            self.stats.record_groups(*sub)
+            self.stats.record_groups(sub[0], sub[1])
+            for j, fetch_s in zip(g, sub[2]):
+                j.fetch_s = fetch_s
         else:
             self.stats.pipeline_depth.record(1)
 
@@ -633,9 +698,8 @@ class DispatchScheduler:
             if j._done:
                 continue
             try:
-                kw = {} if j.deadline is None else {"deadline": j.deadline}
                 j._result = j.reader.msearch([j.body], j.with_partials,
-                                             **kw)[0]
+                                             **self._call_kw([j]))[0]
             except Exception as e:  # noqa: BLE001
                 j._error = e
             j._done = True

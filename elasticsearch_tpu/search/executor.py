@@ -55,7 +55,8 @@ from ..ops.topk import top_k_hits, top_k_by_field
 from ..ops import aggs as agg_ops
 from ..utils.errors import (QueryParsingError, SearchParseError,
                             SearchTimeoutError)
-from ..utils.profiler import annotate as _prof_annotate
+from ..utils.metrics import MetricsRegistry as _MetricsRegistry
+from ..utils.profiler import phase as _phase
 from ..utils import trace_guard as _trace_guard
 from . import resident as _resident
 from .query_dsl import (
@@ -2183,6 +2184,7 @@ def _apply_fvf_modifier(val: jax.Array, modifier: str) -> jax.Array:
 # in nodes_stats()["fused_scoring"].loss_audit.
 # ---------------------------------------------------------------------------
 
+import contextlib as _contextlib
 import json as _json
 import os as _os
 import threading as _threading
@@ -2638,6 +2640,46 @@ class _FusedScoringStats:
 
 
 _fused_stats = _FusedScoringStats()
+
+# device-program launches by backend, counted where the program is
+# launched (`fused_scoring.dispatches` counts fused launches only, at
+# collect); process-wide like _fused_stats, read as deltas through
+# `_nodes/stats/dispatch` -> "launches"
+LAUNCH_BACKENDS = ("unfused", "fused_xla", "fused_pallas", "resident",
+                   "tiered")
+_launches = _MetricsRegistry()
+for _backend in LAUNCH_BACKENDS:
+    _launches.counter(_backend)
+
+
+def launch_counts() -> dict:
+    return _launches.snapshot()
+
+
+def _span_args(bind) -> dict:
+    """What a phase of this dispatch takes from the reader's `bind`
+    phase (None for callers that bring none): its weight, the searches
+    the reader's call serves, and the `request=` / `requests=` span
+    arguments."""
+    return {"weight": bind.weight, **bind.args} if bind is not None else {}
+
+
+@_contextlib.contextmanager
+def _launch(bind, backend: str, span: str = "dispatch"):
+    """The phase around one device-program launch, counted under its
+    backend. It takes its time out of the reader's `bind` block
+    (utils/profiler.phase: parse, bind, wire params and layout ran up
+    to here, the breaker accounting follows), so that the spans stay
+    leaves."""
+    _launches.counter(backend).inc()
+    if bind is not None:
+        bind.pause()
+    try:
+        with _phase(span, **_span_args(bind)):
+            yield
+    finally:
+        if bind is not None:
+            bind.resume()
 
 
 def fused_scoring_stats() -> dict:
@@ -4571,7 +4613,7 @@ def _execute_resident(segment: Segment, live, desc: tuple, params: tuple,
                       sort_spec: tuple, sort_params: tuple,
                       bundle: tuple, backend: str, k_eff: int,
                       b_pad: int, deadline: float | None, step_budget,
-                      shard_key: tuple | None, n_real: int):
+                      shard_key: tuple | None, n_real: int, bind=None):
     """Serve one dispatch through a pinned resident entry: stage the
     donated param feed asynchronously, invoke the AOT-compiled stepped
     executable, start the async result fetch — the split
@@ -4663,7 +4705,7 @@ def _execute_resident(segment: Segment, live, desc: tuple, params: tuple,
             desc, agg_desc, cap, k_res, sort_spec, fused=fused)
         # -- execute stage: invoke the pinned executable (donates wire)
         with _trace_guard.trap(), \
-                _prof_annotate("query_phase:resident_dispatch"):
+                _launch(bind, "resident", "resident_dispatch"):
             buf = entry.compiled(dev, wire_dev, live_dev, live_views,
                                  step_arr)
         _resident.stats.staged_feed_overlap_ms.record(
@@ -4684,7 +4726,8 @@ def _execute_resident(segment: Segment, live, desc: tuple, params: tuple,
     # BEFORE any further accounting can raise — no exit may leak the
     # out_bytes reservation (PR 4's invariant)
     layout = {**layout, "resident": True, "shard_key": shard_key,
-              "_breaker_hold": _gc_backstop(buf, req_hold)}
+              "_breaker_hold": _gc_backstop(buf, req_hold),
+              "_span_args": _span_args(bind)}
     # residency-bytes accounting (fielddata breaker, held until the
     # entry is evicted): staged feed + queued output + generated code.
     # A fielddata trip here means the entry cannot afford residency —
@@ -4711,7 +4754,8 @@ def execute_segment_async(segment: Segment, live: np.ndarray,
                           sort_params: tuple = (),
                           deadline: float | None = None,
                           step_budget=None,
-                          shard_key: tuple | None = None):
+                          shard_key: tuple | None = None,
+                          bind=None):
     """Dispatch one batched query against one segment WITHOUT syncing.
 
     Uses the packed wire format: 3 upload buffers, 1 download buffer —
@@ -4728,7 +4772,11 @@ def execute_segment_async(segment: Segment, live: np.ndarray,
     timed_out), `step_budget` carries an injected straggler budget
     (utils/faults.StepBudget), and `shard_key` = (index, shard) labels
     the timeout. All three are ignored on the cold path, whose deadline
-    stays cooperative at the caller's collect boundary."""
+    stays cooperative at the caller's collect boundary.
+
+    `bind` is the caller's open `bind` phase (utils/profiler.phase),
+    paused here around the launch; its arguments name the requests on
+    this dispatch's spans."""
     n_real = len(bounds)
     if n_real == 0:
         raise ValueError("execute_segment requires at least one bound query")
@@ -4779,7 +4827,7 @@ def execute_segment_async(segment: Segment, live: np.ndarray,
             return _execute_tiered(
                 segment, live, desc, params, agg_desc, agg_params,
                 sort_spec, sort_params, bundle, k_eff, b_pad, deadline,
-                shard_key, n_real)
+                shard_key, n_real, bind)
         # knn bundles on a paged pack take the full-upload fallback:
         # the knn tile bound is a device product (the similarity
         # column), so the HOST survivor oracle
@@ -4793,7 +4841,7 @@ def execute_segment_async(segment: Segment, live: np.ndarray,
             return _execute_resident(
                 segment, live, desc, params, agg_desc, agg_params,
                 sort_spec, sort_params, bundle, res_backend, k_eff,
-                b_pad, deadline, step_budget, shard_key, n_real)
+                b_pad, deadline, step_budget, shard_key, n_real, bind)
         # resident mode on, but the plan fell outside residency
         # admission (unfused, or an untuned Pallas candidate whose
         # first cold dispatch tunes it): cold dispatch
@@ -4875,7 +4923,8 @@ def execute_segment_async(segment: Segment, live: np.ndarray,
             dev, params, live_dev, live_views, agg_params, sort_params,
             desc, agg_desc, segment.capacity, k_eff, sort_spec,
             fused=fused)
-        with _trace_guard.trap(), _prof_annotate("query_phase:dispatch"):
+        with _trace_guard.trap(), _launch(
+                bind, "unfused" if fused is None else "fused_" + fused[1]):
             buf = _segment_program_packed(
                 dev, wire_dev, live_dev, live_views,
                 pack_static=pack_static,
@@ -4890,58 +4939,68 @@ def execute_segment_async(segment: Segment, live: np.ndarray,
     req_hold.shrink(out_bytes)
     # layout dicts are cached/shared across calls — attach the per-call
     # hold to a shallow copy
-    layout = {**layout, "_breaker_hold": _gc_backstop(buf, req_hold)}
+    layout = {**layout, "_breaker_hold": _gc_backstop(buf, req_hold),
+              "_span_args": _span_args(bind)}
     return buf, layout, n_real
+
+
+def _collect(out, layout, leaf):
+    """The `collect` phase (device time + D2H + the runtime's wake-up),
+    after which `leaf` is the `unpack` phase. That begins by releasing
+    the breaker hold: the transient device accumulators are dead once
+    the result is on host — release NOW instead of waiting for GC.
+    Released on the error exit too (a failed device_get must not pin
+    breaker bytes until collection of the GC backstop)."""
+    try:
+        with _trace_guard.trap():
+            return jax.device_get(out)
+    finally:
+        leaf.switch("unpack")
+        hold = layout.get("_breaker_hold")
+        if hold is not None:
+            hold.release()
 
 
 def collect_segment_result(out, layout, n_real: int):
     """Sync + unpack + slice an async result back to the true B."""
-    hold = layout.get("_breaker_hold")
-    if layout.get("tiered"):
-        # tiered chunked walk (see _execute_tiered): `out` is the final
-        # state pytree, not a packed wire buffer — fetch it, slice the
-        # padding, and fold the never-fetched (I/O-filtered) tiles into
-        # the prune counters as the hard skips they are
-        try:
-            with _trace_guard.trap(), _prof_annotate("query_phase:collect"):
-                host = jax.device_get(out)
-        finally:
-            if hold is not None:
-                hold.release()
-        k = layout["k"]
-        if k > 0:
-            top_s, top_i, totals, prune, agg_tree = host
-            top_score = np.asarray(top_s)[:n_real]
-            top_idx = np.asarray(top_i)[:n_real].astype(np.int32)
-        else:
-            totals, prune, agg_tree = host
-            top_score = np.zeros((n_real, 0), np.float32)
-            top_idx = np.zeros((n_real, 0), np.int32)
-        total = np.asarray(totals)[:n_real].astype(np.int32)
-        top_missing = np.zeros_like(top_idx, dtype=bool)
-        hard, thr, examined = (float(x) for x in np.asarray(prune))
-        sk = float(layout.get("skipped_tiles", 0))
-        _fused_stats.record_prune(
-            hard + sk, thr, examined + sk,
-            positional=bool(layout.get("fused_positional")))
-        # agg leaves round-trip through f32 on the packed-wire path;
-        # mirror that here so reduce-side inputs are byte-identical
-        agg_leaves = [np.asarray(leaf)[:n_real].astype(np.float32)
-                      for leaf in jax.tree_util.tree_leaves(agg_tree)]
-        agg_out = jax.tree_util.tree_unflatten(layout["agg_treedef"],
-                                               agg_leaves)
-        return (top_score, top_score, top_idx, total, top_missing), \
-            agg_out
-    try:
-        with _trace_guard.trap(), _prof_annotate("query_phase:collect"):
-            wire = jax.device_get(out)[:n_real]
-    finally:
-        # the transient device accumulators are dead once the wire
-        # buffer is on host — release NOW instead of waiting for GC.
-        # Released on the error exit too (a failed device_get must not
-        # pin breaker bytes until collection of the GC backstop).
-        if hold is not None:
-            hold.release()
+    with _phase("collect", **layout.get("_span_args") or {}) as leaf:
+        host = _collect(out, layout, leaf)
+        if layout.get("tiered"):
+            return _unpack_tiered(host, layout, n_real)
+        return _unpack_wire(host[:n_real], layout, n_real)
+
+
+def _unpack_tiered(host, layout, n_real: int):
+    """Tiered chunked walk (see _execute_tiered): `host` is the final
+    state pytree, not a packed wire buffer — slice the padding, and
+    fold the never-fetched (I/O-filtered) tiles into the prune counters
+    as the hard skips they are."""
+    k = layout["k"]
+    if k > 0:
+        top_s, top_i, totals, prune, agg_tree = host
+        top_score = np.asarray(top_s)[:n_real]
+        top_idx = np.asarray(top_i)[:n_real].astype(np.int32)
+    else:
+        totals, prune, agg_tree = host
+        top_score = np.zeros((n_real, 0), np.float32)
+        top_idx = np.zeros((n_real, 0), np.int32)
+    total = np.asarray(totals)[:n_real].astype(np.int32)
+    top_missing = np.zeros_like(top_idx, dtype=bool)
+    hard, thr, examined = (float(x) for x in np.asarray(prune))
+    sk = float(layout.get("skipped_tiles", 0))
+    _fused_stats.record_prune(
+        hard + sk, thr, examined + sk,
+        positional=bool(layout.get("fused_positional")))
+    # agg leaves round-trip through f32 on the packed-wire path;
+    # mirror that here so reduce-side inputs are byte-identical
+    agg_leaves = [np.asarray(leaf)[:n_real].astype(np.float32)
+                  for leaf in jax.tree_util.tree_leaves(agg_tree)]
+    agg_out = jax.tree_util.tree_unflatten(layout["agg_treedef"],
+                                           agg_leaves)
+    return (top_score, top_score, top_idx, total, top_missing), agg_out
+
+
+def _unpack_wire(wire, layout, n_real: int):
     k = layout["k"]
     key_is_float = layout["key_dtype"] == np.float32
     n_i = 2 * k + 1 + (0 if key_is_float else k)
@@ -5277,7 +5336,7 @@ def _execute_tiered(segment: Segment, live, desc: tuple, params: tuple,
                     agg_desc: tuple, agg_params: tuple,
                     sort_spec: tuple, sort_params: tuple, bundle: tuple,
                     k_eff: int, b_pad: int, deadline: float | None,
-                    shard_key: tuple | None, n_real: int):
+                    shard_key: tuple | None, n_real: int, bind=None):
     """Serve one fused-admitted dispatch from a PAGED pack via the
     chunked tiered walk (see the section comment above). Returns
     (state_tuple, layout, n_real) for collect_segment_result — the
@@ -5372,7 +5431,7 @@ def _execute_tiered(segment: Segment, live, desc: tuple, params: tuple,
                 raise SearchTimeoutError(sk[0], sk[1])
             tiles_dev, bufs, _ms = pending
             with _trace_guard.trap(), \
-                    _prof_annotate("query_phase:tiered_dispatch"):
+                    _launch(bind, "tiered", "tiered_dispatch"):
                 if k_run > 0:
                     state = _tiered_chunk_program(
                         seg_res, wire_dev, live_dev, tiles_dev, bufs,
@@ -5392,7 +5451,7 @@ def _execute_tiered(segment: Segment, live, desc: tuple, params: tuple,
         agg_tree = {}
         if emit:
             with _trace_guard.trap(), \
-                    _prof_annotate("query_phase:tiered_aggs"):
+                    _launch(bind, "tiered", "tiered_aggs"):
                 agg_tree = _tiered_agg_program(
                     dev, wire_dev, live_views, state[-1],
                     pack_static=pack_static, desc=desc,
@@ -5416,6 +5475,7 @@ def _execute_tiered(segment: Segment, live, desc: tuple, params: tuple,
         "skipped_tiles": skipped,
         "_breaker_hold": _gc_backstop(out_leaves[0] if out_leaves
                                       else None, req_hold),
+        "_span_args": _span_args(bind),
     }
     return out, layout, n_real
 
@@ -5460,7 +5520,8 @@ def execute_pack_async(base: Segment, delta: Segment, live_b: np.ndarray,
                        agg_params_d: tuple = (),
                        sort_spec: tuple = ("_score",),
                        deadline: float | None = None,
-                       step_budget=None, shard_key: tuple | None = None):
+                       step_budget=None, shard_key: tuple | None = None,
+                       bind=None):
     """Dispatch one batched query against a (base, delta) generation
     pair as ONE device program (see _pack_body), without syncing.
 
@@ -5533,7 +5594,7 @@ def execute_pack_async(base: Segment, delta: Segment, live_b: np.ndarray,
                 base, delta, live_b, live_d, desc, params_b, params_d,
                 agg_desc, agg_params_b, agg_params_d, bundle,
                 res_backend, k_eff, b_pad, deadline, step_budget,
-                shard_key, n_real)
+                shard_key, n_real, bind)
         _resident.stats.cold_dispatches.inc()
     from ..utils.breaker import breaker_service
     req_hold = breaker_service().breaker("request").hold(
@@ -5581,7 +5642,7 @@ def execute_pack_async(base: Segment, delta: Segment, live_b: np.ndarray,
             dev_b, dev_d, params_b, params_d, live_dev_b, live_dev_d,
             views_b, views_d, agg_params_b, agg_params_d, desc, agg_desc,
             cap_b, cap_d, k_eff, fused)
-        with _trace_guard.trap(), _prof_annotate("query_phase:dispatch"):
+        with _trace_guard.trap(), _launch(bind, "fused_" + fused[1]):
             buf = _pack_program_packed(
                 dev_b, dev_d, wire_dev, live_dev_b, live_dev_d,
                 views_b, views_d, pack_static=pack_static, desc=desc,
@@ -5593,7 +5654,8 @@ def execute_pack_async(base: Segment, delta: Segment, live_b: np.ndarray,
     est = b_pad * row_elems * 8
     out_bytes = min(est, int(getattr(buf, "nbytes", 0)) or est)
     req_hold.shrink(out_bytes)
-    layout = {**layout, "_breaker_hold": _gc_backstop(buf, req_hold)}
+    layout = {**layout, "_breaker_hold": _gc_backstop(buf, req_hold),
+              "_span_args": _span_args(bind)}
     return buf, layout, n_real
 
 
@@ -5632,7 +5694,8 @@ def _execute_pack_resident(base: Segment, delta: Segment, live_b, live_d,
                            agg_params_d: tuple, bundle: tuple,
                            backend: str, k_eff: int, b_pad: int,
                            deadline: float | None, step_budget,
-                           shard_key: tuple | None, n_real: int):
+                           shard_key: tuple | None, n_real: int,
+                           bind=None):
     """Serve a base+delta dispatch through a pinned resident entry.
     The entry key embeds BOTH generations' cache keys and the exact
     pack shape signatures — a refresh's delta rebuild (same pow2
@@ -5715,7 +5778,7 @@ def _execute_pack_resident(base: Segment, delta: Segment, live_b, live_d,
             views_b, views_d, agg_params_b, agg_params_d, desc, agg_desc,
             cap_b, cap_d, k_res, fused)
         with _trace_guard.trap(), \
-                _prof_annotate("query_phase:resident_dispatch"):
+                _launch(bind, "resident", "resident_dispatch"):
             buf = entry.compiled(dev_b, dev_d, wire_dev, live_dev_b,
                                  live_dev_d, views_b, views_d, step_arr)
         _resident.stats.staged_feed_overlap_ms.record(
@@ -5730,7 +5793,8 @@ def _execute_pack_resident(base: Segment, delta: Segment, live_b, live_d,
     out_bytes = min(est, int(getattr(buf, "nbytes", 0)) or est)
     req_hold.shrink(out_bytes)
     layout = {**layout, "resident": True, "shard_key": shard_key,
-              "_breaker_hold": _gc_backstop(buf, req_hold)}
+              "_breaker_hold": _gc_backstop(buf, req_hold),
+              "_span_args": _span_args(bind)}
     code_bytes = 0
     try:
         ma = entry.compiled.memory_analysis()
@@ -5755,13 +5819,12 @@ def collect_pack_result(out, layout, n_real: int):
     carry a 6th element, the per-row VALID count (a split list can hold
     fewer than min(total, k) entries when the other side won the
     window)."""
-    hold = layout.get("_breaker_hold")
-    try:
-        with _trace_guard.trap(), _prof_annotate("query_phase:collect"):
-            wire = jax.device_get(out)[:n_real]
-    finally:
-        if hold is not None:
-            hold.release()
+    with _phase("collect", **layout.get("_span_args") or {}) as leaf:
+        host = _collect(out, layout, leaf)
+        return _unpack_pack(host[:n_real], layout, n_real)
+
+
+def _unpack_pack(wire, layout, n_real: int):
     k = layout["k"]
     n_i = 2 * k + 2
     n_i_total = n_i

@@ -21,6 +21,8 @@ from ..index.mapping import MapperService
 from ..index.segment import Segment
 from ..utils import faults
 from ..utils.errors import SearchParseError, SearchTimeoutError
+from ..utils.profiler import phase, request_args
+from .dispatch import note_submit_stats
 from .query_dsl import QueryParser, Query
 from .executor import (QueryBinder, execute_segment, execute_segment_async,
                        execute_pack_async, collect_segment_result,
@@ -111,7 +113,7 @@ class _PendingMsearch:
     __slots__ = ("reader", "bodies", "with_partials", "started",
                  "knn_idx", "knn_sub", "parsed", "multi", "main",
                  "groups", "no_segments", "group_sizes",
-                 "dispatch_count", "deadline", "step_budget")
+                 "dispatch_count", "deadline", "step_budget", "fetch_s")
 
     def __init__(self, reader: "ShardReader", bodies: list[dict],
                  with_partials: bool, started: float,
@@ -132,6 +134,9 @@ class _PendingMsearch:
         self.group_sizes: list[int] = []
         self.dispatch_count = 0
         self.deadline: float | None = None
+        # the `fetch` seconds each body's response took to build, which
+        # the node's search stats read
+        self.fetch_s: list[float] = [0.0] * len(bodies)
         # straggler budget for resident (device-stepped) dispatches —
         # None on the cold path (utils/faults.StepBudget)
         self.step_budget = None
@@ -233,7 +238,8 @@ class ShardReader:
         return res["hits"]["total"]
 
     def msearch(self, bodies: list[dict], with_partials: bool = False,
-                deadline: float | None = None) -> list[dict]:
+                deadline: float | None = None,
+                requests: list | None = None) -> list[dict]:
         """Execute a batch of requests; structurally-identical requests are
         batched into one device program (leading dim B).
 
@@ -241,19 +247,20 @@ class ShardReader:
         for the coordinator's cross-shard reduce) instead of finalized
         "aggregations" — the QUERY phase of a distributed search."""
         pend = self.msearch_submit(bodies, with_partials,
-                                   deadline=deadline)
+                                   deadline=deadline, requests=requests)
         out = pend.finish()
         # stamped AFTER finish(): auxiliary msearch calls inside it
         # (derived aggs, rescore windows, sig_terms) wrote the same
         # thread-local, so the outermost call wins — the dispatch
         # scheduler's sync path reads the stats of the call it made
-        from .dispatch import note_submit_stats
-        note_submit_stats(pend.group_sizes, pend.dispatch_count)
+        note_submit_stats(pend.group_sizes, pend.dispatch_count,
+                          pend.fetch_s)
         return out
 
     def msearch_submit(self, bodies: list[dict],
                        with_partials: bool = False,
-                       deadline: float | None = None) -> "_PendingMsearch":
+                       deadline: float | None = None,
+                       requests: list | None = None) -> "_PendingMsearch":
         """Dispatch half of msearch: parse, group structurally-identical
         requests, and enqueue EVERY group's device programs through the
         non-syncing executor entry WITHOUT collecting — so a scheduler
@@ -272,7 +279,22 @@ class ShardReader:
         registry (utils/faults.py) hooks: an injected shard_error /
         breaker_trip raises here exactly where a real device error
         would, and an injected shard_delay makes this shard a
-        straggler."""
+        straggler.
+
+        `requests` names the request behind each body (ids from
+        utils/profiler.next_request_id) on the phase spans. All of
+        this is the `bind` phase, but for the launches: the executor
+        pauses it around each, so that the spans stay leaves. Every
+        phase of the call weighs its number of bodies: each of those
+        searches waits through all of it."""
+        ids = list(requests) if requests else [None] * len(bodies)
+        with phase("bind", len(bodies), **request_args(ids)) as bind:
+            return self._msearch_submit(bodies, with_partials, deadline,
+                                        ids, bind)
+
+    def _msearch_submit(self, bodies: list[dict], with_partials: bool,
+                        deadline: float | None, ids: list,
+                        bind: phase) -> "_PendingMsearch":
         faults.on_dispatch("reader", index=self.index_name,
                            shard=self.shard_id)
         started = time.monotonic()
@@ -352,6 +374,8 @@ class ShardReader:
             groups.setdefault(sig, []).append(i)
 
         for sig, idxs in groups.items():
+            # from here on the spans name this group's requests
+            group_args = bind.args = request_args(ids[i] for i in idxs)
             p0 = parsed[idxs[0]]
             agg_ctx = ShardAggContext(self.segments,
                                       self._ords_for(p0["agg_specs"]))
@@ -416,7 +440,7 @@ class ShardReader:
                     agg_params_d=agg_params[1] if agg_params else (),
                     sort_spec=sort_spec, deadline=deadline,
                     step_budget=step_budget,
-                    shard_key=(self.index_name, self.shard_id))
+                    shard_key=(self.index_name, self.shard_id), bind=bind)
                 if pack is not None:
                     pending.append(pack)
             if not pending:
@@ -427,10 +451,12 @@ class ShardReader:
                         agg_desc=agg_desc, agg_params=agg_params[si],
                         sort_spec=sort_spec, sort_params=sort_maps[si],
                         deadline=deadline, step_budget=step_budget,
-                        shard_key=(self.index_name, self.shard_id)))
+                        shard_key=(self.index_name, self.shard_id),
+                        bind=bind))
             pend.groups.append({"idxs": idxs, "p0": p0, "agg_ctx": agg_ctx,
                                 "pending": pending,
-                                "sort_terms": sort_terms})
+                                "sort_terms": sort_terms,
+                                "span_args": group_args})
         pend.group_sizes = [len(g["idxs"]) for g in pend.groups]
         pend.dispatch_count = sum(len(g["pending"]) for g in pend.groups)
         return pend
@@ -526,6 +552,8 @@ class ShardReader:
             agg_ctx = g["agg_ctx"]
             partials = []
             seg_tops = []
+            # `collect` and `unpack` are the executor's spans; `fetch`
+            # below is everything that builds this group's responses
             for out, layout, n_real in g["pending"]:
                 if layout.get("pack"):
                     # one pack dispatch covered (base, delta): the
@@ -540,23 +568,31 @@ class ShardReader:
                 top, aggs = collect_segment_result(out, layout, n_real)
                 seg_tops.append(top)
                 partials.append(aggs)
-            if p0["agg_specs"] and with_partials:
-                part_json = shard_partials(p0["agg_specs"], agg_ctx, partials,
-                                           len(idxs))
-                agg_json = [{} for _ in idxs]
-            elif p0["agg_specs"]:
-                part_json = None
-                agg_json = reduce_aggs(p0["agg_specs"], agg_ctx, partials,
-                                       len(idxs))
-            else:
-                part_json = None
-                agg_json = [{} for _ in idxs]
-            for bi, i in enumerate(idxs):
-                responses[i] = self._build_response(
-                    parsed[i], seg_tops, bi, agg_json[bi], started,
-                    sort_terms=g["sort_terms"])
-                if part_json is not None:
-                    responses[i]["_agg_partials"] = part_json[bi]
+            with phase("fetch", len(bodies), **g["span_args"]) as fetch:
+                if p0["agg_specs"] and with_partials:
+                    part_json = shard_partials(p0["agg_specs"], agg_ctx,
+                                               partials, len(idxs))
+                    agg_json = [{} for _ in idxs]
+                elif p0["agg_specs"]:
+                    part_json = None
+                    agg_json = reduce_aggs(p0["agg_specs"], agg_ctx,
+                                           partials, len(idxs))
+                else:
+                    part_json = None
+                    agg_json = [{} for _ in idxs]
+                for bi, i in enumerate(idxs):
+                    responses[i] = self._build_response(
+                        parsed[i], seg_tops, bi, agg_json[bi], started,
+                        sort_terms=g["sort_terms"])
+                    if part_json is not None:
+                        responses[i]["_agg_partials"] = part_json[bi]
+                # the results are on the host: let go of the device
+                # buffers here, inside the span, not where this frame
+                # and the pend die
+                g["pending"] = ()
+                out = None
+            for i in idxs:
+                pend.fetch_s[i] = fetch.seconds / len(idxs)
         for i in pend.main:
             # post-processing (rescore windows, derived aggs, sig_terms
             # fan back into msearch) is host-driven and unbounded — a

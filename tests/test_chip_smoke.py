@@ -10,6 +10,11 @@ import chip_smoke
 
 
 def test_cpu_rehearsal_passes_every_phase_but_the_device(capsys):
+    # the `fused_scoring` counters are process-wide: what an earlier test
+    # of this worker left there must not reach the smoke's verdicts
+    from elasticsearch_tpu.search.executor import _fused_stats
+    _fused_stats.record_positional("a_stranger_s_fallback")
+    _fused_stats.record_pallas_reject("kernel_unavailable")
     rc = chip_smoke.main(["--docs", "4096", "--seed", "3"])
     lines = capsys.readouterr().out.strip().splitlines()
     verdict = json.loads(lines[-1])
@@ -50,3 +55,28 @@ def test_no_accelerator_and_no_rehearsal_prints_no_result(capsys):
     assert rc == 1
     assert captured.out == ""
     assert "no TPU" in captured.err
+
+
+def test_counters_are_read_as_what_they_moved_by():
+    before = {"dispatches": 7, "prune_rate": 0.5, "backend_choices": {
+        "old": {"backend": "xla", "reason": "timed"},
+        "retuned": {"backend": "xla", "reason": "static"}},
+        "admission": {"admitted": 3, "rate": 0.3, "positional_admitted": 1,
+                      "pallas_rejected": {"kernel_unavailable": 2},
+                      "positional_fallbacks": {"slop": 1}}}
+    after = {"dispatches": 12, "prune_rate": 0.4, "backend_choices": {
+        "old": {"backend": "xla", "reason": "timed"},
+        "retuned": {"backend": "pallas", "reason": "timed"},
+        "new": {"backend": "pallas", "reason": "forced"}},
+        "admission": {"admitted": 9, "rate": 0.6, "positional_admitted": 2,
+                      "pallas_rejected": {"kernel_unavailable": 2,
+                                          "positional_mosaic": 1},
+                      "positional_fallbacks": {"slop": 1}}}
+    assert chip_smoke.moved(before, after) == {
+        "dispatches": 5,
+        "backend_choices": {
+            "retuned": {"backend": "pallas", "reason": "timed"},
+            "new": {"backend": "pallas", "reason": "forced"}},
+        "admission": {"admitted": 6, "positional_admitted": 1,
+                      "pallas_rejected": {"positional_mosaic": 1},
+                      "positional_fallbacks": {}}}

@@ -34,10 +34,10 @@ def test_concurrent_queries_coalesce_and_agree(node, monkeypatch):
     calls = []
     orig = ShardReader.msearch
 
-    def counting_msearch(self, bodies, with_partials=False):
+    def counting_msearch(self, bodies, with_partials=False, **kw):
         calls.append(len(bodies))
         time.sleep(0.02)  # emulate device dispatch time: forces overlap
-        return orig(self, bodies, with_partials)
+        return orig(self, bodies, with_partials, **kw)
     monkeypatch.setattr(ShardReader, "msearch", counting_msearch)
 
     n_threads = 24
@@ -105,9 +105,9 @@ class TestSearchPoolRejection:
             from elasticsearch_tpu.search.shard_searcher import ShardReader
             orig = ShardReader.msearch
 
-            def slow(self, bodies, with_partials=False):
+            def slow(self, bodies, with_partials=False, **kw):
                 gate.wait(timeout=10)
-                return orig(self, bodies, with_partials)
+                return orig(self, bodies, with_partials, **kw)
             ShardReader.msearch = slow
             try:
                 t = threading.Thread(

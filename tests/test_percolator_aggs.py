@@ -194,9 +194,9 @@ class TestPercolatorPruning:
             counted = []
             orig = ShardReader.msearch
 
-            def counting(self, bodies, with_partials=False):
+            def counting(self, bodies, with_partials=False, **kw):
                 counted.append(len(bodies))
-                return orig(self, bodies, with_partials)
+                return orig(self, bodies, with_partials, **kw)
             ShardReader.msearch = counting
             try:
                 r = n.percolate("alerts", {"doc": {

@@ -66,9 +66,11 @@ RULE = "shared-state-race"
 # `membership` joined with elastic pod membership (ISSUE 19): ledger,
 # lease, and abandoned-seq state are hit from exec handlers, heartbeat
 # threads, and driver retries at once.
+# `profiler` joined with the always-on phase timers (ISSUE 25): REST,
+# pool and leader threads all feed its module-level registry.
 _HOT_MODULES = {"dispatch", "traffic", "resident", "repack", "tiering",
                 "executor", "cache", "faults", "metrics", "devbuild",
-                "membership"}
+                "membership", "profiler"}
 
 # stdlib constructor tails whose instances serialize themselves (or are
 # thread-confined by construction, like threading.local); package

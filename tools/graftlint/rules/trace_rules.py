@@ -36,6 +36,13 @@ _FORBIDDEN_TAILS = {
     "tolist": "host sync `.tolist()`",
     "print": "side effect `print(...)`",
     "sleep": "side effect `time.sleep`",
+    # utils/profiler.py: a traced body runs once, while tracing, so a
+    # phase entered there would time the trace and nothing after it
+    # (the executor's `_phase` / `_launch` are the same mechanism)
+    "phase": "host timer `phase(...)`",
+    "_phase": "host timer `phase(...)`",
+    "_launch": "host timer `phase(...)`",
+    "waited": "host timer `waited(...)`",
 }
 _FORBIDDEN_DOTTED = {
     "time.time": "wall-clock read `time.time()`",
