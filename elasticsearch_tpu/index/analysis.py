@@ -340,6 +340,9 @@ class Analyzer:
             tokens = f(tokens)
         return tokens
 
+    def analyze_batch(self, texts: list[str]) -> list[list[str]]:
+        return [self.analyze(t) for t in texts]
+
     def __repr__(self) -> str:
         return f"Analyzer({self.name!r})"
 
@@ -374,7 +377,7 @@ class _NativeBackedAnalyzer(Analyzer):
         nat = self._get_native()
         if nat is not None:
             return nat.analyze_batch(texts)
-        return [super(_NativeBackedAnalyzer, self).analyze(t) for t in texts]
+        return super().analyze_batch(texts)
 
 
 def _builtin_analyzers() -> dict[str, Analyzer]:

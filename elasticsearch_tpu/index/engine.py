@@ -28,14 +28,16 @@ import threading
 # not prematurely GC a tombstone (late replicated deletes would
 # resurrect docs) or immortalize one (the map would grow unbounded)
 import time
+from typing import NamedTuple
 
 import numpy as np
 
-from ..utils.errors import (DocumentMissingError, IllegalArgumentError,
-                            ShardFailedError, ShardNotFoundError,
-                            VersionConflictError)
+from ..utils import profiler
+from ..utils.errors import (DocumentMissingError, ElasticsearchTpuError,
+                            IllegalArgumentError, ShardFailedError,
+                            ShardNotFoundError, VersionConflictError)
 from ..utils.settings import Settings
-from ..index.mapping import MapperService
+from ..index.mapping import MapperService, ParsedDocument
 from . import devbuild, durability
 from .segment import (Segment, SegmentBuilder, concat_segments,
                       merge_segments, pad_delta_shapes)
@@ -100,6 +102,22 @@ def _validate_version_type(version: int | None, version_type: str) -> None:
     if version is None and version_type != "internal":
         raise IllegalArgumentError(
             f"version type [{version_type}] requires an explicit version")
+
+
+class IndexOp(NamedTuple):
+    """One index operation of a batch, as Node hands it to
+    IndexService.index_many and that to Engine.index_many; the engine
+    reads the first five fields, the rest is the index's metadata."""
+
+    doc_id: str
+    source: dict | bytes | str
+    version: int | None = None
+    version_type: str = "internal"
+    create: bool = False          # op_type=create: fail on a live id
+    routing: str | None = None
+    parent: str | None = None
+    doc_type: str | None = None
+    timestamp_ms: int | None = None
 
 
 class Engine:
@@ -265,28 +283,75 @@ class Engine:
     def index(self, doc_id: str, source: dict | bytes | str,
               version: int | None = None, _replay: bool = False,
               version_type: str = "internal") -> dict:
+        """One document: a batch of one."""
+        r = self.index_many([IndexOp(doc_id, source, version, version_type)],
+                            _replay=_replay)[0]
+        if isinstance(r, ElasticsearchTpuError):
+            raise r
+        return r
+
+    def index_many(self, ops: list[IndexOp], _replay: bool = False
+                   ) -> list[dict | ElasticsearchTpuError]:
+        """Apply a batch in order under ONE take of the lock (ref:
+        TransportShardBulkAction.shardOperationOnPrimary: a bulk
+        request's items for this shard in one pass, one translog sync).
+        The mapper parses the batch, then each op resolves its version
+        against what the batch has written so far (the same id twice
+        gives versions n and n+1), then the translog takes every applied
+        op in one append. An op that fails (version conflict, a `create`
+        of a live id, a document the mapper refuses) is its own error in
+        the result, and the rest go on. Returns only after the translog
+        has the batch, fsynced under `request` durability: nothing of it
+        may be acknowledged before.
+
+        Unlike one document at a time, an op refused for its version has
+        been through the mapper, so the fields it introduced stay mapped."""
         with self._lock:
             self._check_open()
-            current = self._current_version(doc_id)
-            new_version = self._resolve_write_version(
-                doc_id, current, version, version_type)
-            parsed = self.mappers.parse(doc_id, source)
-            self._delete_everywhere(doc_id)
-            self.buffer.add(parsed, version=new_version)
-            self._buffer_docs[doc_id] = (new_version, parsed.source)
-            if self._delta_enabled:
-                # the delta rebuild's doc set; re-inserts land at the
-                # END (dict order), matching where a fresh segment
-                # would have put the updated doc
-                self._delta_docs[doc_id] = (parsed, new_version)
-            self.versions[doc_id] = (new_version, False)
-            self._tombstone_ts.pop(doc_id, None)  # re-index revives
-            if self.translog is not None and not _replay:
-                self.translog.add(TranslogOp(OP_INDEX, doc_id, new_version,
-                                             parsed.source))
-            self._dirty = True
-            return {"_id": doc_id, "_version": new_version,
-                    "created": current is None}
+            with profiler.phase("bulk_parse"):
+                parsed = self.mappers.parse_many(
+                    [(op.doc_id, op.source) for op in ops])
+            results: list[dict | ElasticsearchTpuError] = []
+            logged: list[TranslogOp] = []
+            with profiler.phase("bulk_apply"):
+                for op, doc in zip(ops, parsed):
+                    try:
+                        results.append(self._apply_index(op, doc, logged))
+                    except ElasticsearchTpuError as e:
+                        results.append(e)
+            if logged and self.translog is not None and not _replay:
+                with profiler.phase("bulk_translog"):
+                    self.translog.add_many(logged)
+            return results
+
+    def _apply_index(self, op: IndexOp,
+                     parsed: ParsedDocument | ElasticsearchTpuError,
+                     logged: list[TranslogOp]) -> dict:
+        doc_id = op.doc_id
+        current = self._current_version(doc_id)
+        if op.create and current is not None:
+            # op_type=create fails on ANY live doc, whatever the version
+            # type (ref: DocumentAlreadyExistsException)
+            raise VersionConflictError(self.index_name, doc_id, -1, -1)
+        new_version = self._resolve_write_version(
+            doc_id, current, op.version, op.version_type)
+        if isinstance(parsed, ElasticsearchTpuError):
+            raise parsed
+        self._delete_everywhere(doc_id)
+        self.buffer.add(parsed, version=new_version)
+        self._buffer_docs[doc_id] = (new_version, parsed.source)
+        if self._delta_enabled:
+            # the delta rebuild's doc set; re-inserts land at the
+            # END (dict order), matching where a fresh segment
+            # would have put the updated doc
+            self._delta_docs[doc_id] = (parsed, new_version)
+        self.versions[doc_id] = (new_version, False)
+        self._tombstone_ts.pop(doc_id, None)  # re-index revives
+        logged.append(TranslogOp(OP_INDEX, doc_id, new_version,
+                                 parsed.source))
+        self._dirty = True
+        return {"_id": doc_id, "_version": new_version,
+                "created": current is None}
 
     def _resolve_write_version(self, doc_id: str, current: int | None,
                                version: int | None,

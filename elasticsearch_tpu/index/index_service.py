@@ -11,11 +11,13 @@ import contextlib
 import os
 import threading
 
+from ..utils import profiler
 from ..utils.settings import Settings
-from ..utils.errors import ShardNotFoundError, DocumentMissingError
+from ..utils.errors import (DocumentMissingError, ElasticsearchTpuError,
+                            PowerLossError, ShardNotFoundError)
 from ..cluster.routing import shard_id as route_shard
 from .mapping import MapperService
-from .engine import Engine
+from .engine import Engine, IndexOp
 from .stats import IndexOpStats
 
 
@@ -121,46 +123,105 @@ class IndexService:
                   version_type: str = "internal",
                   parent: str | None = None,
                   timestamp_ms: int | None = None) -> dict:
-        routing = routing if routing is not None else parent
-        with self._id_lock(doc_id):
-            r = self.shard_for(doc_id, routing).index(
-                doc_id, source, version, version_type=version_type)
-            meta_dirty = False
-            if timestamp_ms is not None:
-                # recorded under the id lock so the persisted snapshot
-                # always includes the triggering write's timestamp
-                meta_dirty |= self.doc_ts.get(doc_id) != timestamp_ms
-                self.doc_ts[doc_id] = timestamp_ms
-            if parent is not None:
-                meta_dirty |= self.doc_parent.get(doc_id) != str(parent)
-                self.doc_parent[doc_id] = str(parent)
-            else:
-                meta_dirty |= self.doc_parent.pop(doc_id, None) is not None
-            if doc_type and doc_type != "_doc":
-                meta_dirty |= self.doc_types.get(doc_id) != doc_type
-                self.doc_types[doc_id] = doc_type
-            else:
-                meta_dirty |= self.doc_types.pop(doc_id, None) is not None
-            if routing is not None:
-                meta_dirty |= self.doc_routing.get(doc_id) != str(routing)
-                self.doc_routing[doc_id] = str(routing)
-            else:
-                meta_dirty |= self.doc_routing.pop(doc_id, None) is not None
-            # response type must be read under the same lock, or a
-            # concurrent delete could make a typed write report _doc
-            resp_type = self.doc_types.get(doc_id, "_doc")
-            if meta_dirty:
-                # write-through: the engine's translog made the DOC durable
-                # at this point, so its type/routing metadata must be
-                # durable too (crash between here and flush must not turn
-                # a typed get into a 404 after replay)
-                self._save_types()
-        r.update({"_index": self.name,
-                  "_type": resp_type,
-                  "_shards": {"total": 1 + self.num_replicas,
-                              "successful": 1, "failed": 0}})
-        self.op_stats.on_index(doc_type)
+        """One document: a batch of one."""
+        r = self.index_many([IndexOp(doc_id, source, version, version_type,
+                                     False, routing, parent, doc_type,
+                                     timestamp_ms)])[0]
+        if isinstance(r, ElasticsearchTpuError):
+            raise r
         return r
+
+    def index_many(self, ops: list[IndexOp]
+                   ) -> list[dict | ElasticsearchTpuError]:
+        """A run of index operations as one batch per shard (ref:
+        TransportBulkAction groups a request's items by shard): route
+        every id, hand each shard its ops in request order, then record
+        the metadata of those that went in. One result an op, in the
+        order given: the write's response, or the error that op alone
+        failed with (a failed or closed shard fails its own ops)."""
+        with profiler.phase("bulk_route"):
+            if self.num_shards == 1:
+                by_shard = {0: list(range(len(ops)))}
+            else:
+                by_shard: dict[int, list[int]] = {}
+                for i, op in enumerate(ops):
+                    sid = route_shard(
+                        op.doc_id, self.num_shards,
+                        op.routing if op.routing is not None else op.parent)
+                    by_shard.setdefault(sid, []).append(i)
+            stripes = sorted({hash(op.doc_id) % len(self._id_locks)
+                              for op in ops})
+        results: list = [None] * len(ops)
+        shards = {"total": 1 + self.num_replicas, "successful": 1,
+                  "failed": 0}
+        indexed: list[str | None] = []
+        batches = batch_docs = 0
+        with contextlib.ExitStack() as locks:
+            # every stripe the batch's ids fall in, in one order (a
+            # single write holds one stripe, so no two holders cross)
+            for stripe in stripes:
+                locks.enter_context(self._id_locks[stripe])
+            # one metadata write-through a batch, at this block's exit:
+            # before the caller can acknowledge any of it
+            locks.enter_context(self.batched_meta_saves())
+            for sid, slots in by_shard.items():
+                try:
+                    written = self.shard(sid).index_many(
+                        [ops[i] for i in slots])
+                except PowerLossError:
+                    raise       # the process died: nothing answers
+                except ElasticsearchTpuError as e:
+                    written = [e] * len(slots)
+                done = len(indexed)
+                for i, r in zip(slots, written):
+                    if not isinstance(r, ElasticsearchTpuError):
+                        op = ops[i]
+                        r["_index"] = self.name
+                        r["_type"] = self._record_meta(op)
+                        r["_shards"] = dict(shards)
+                        indexed.append(op.doc_type)
+                    results[i] = r
+                if len(slots) > 1:
+                    batches += 1
+                    batch_docs += len(indexed) - done
+        self.op_stats.on_index_many(indexed, batches, batch_docs)
+        return results
+
+    def _record_meta(self, op: IndexOp) -> str:
+        """Record a written doc's timestamp, parent, type and routing
+        (caller holds the id's stripe lock, so the persisted snapshot
+        always includes the triggering write's); returns the type the
+        response names, read under the same lock, or a concurrent
+        delete could make a typed write report _doc."""
+        doc_id = op.doc_id
+        routing = op.routing if op.routing is not None else op.parent
+        meta_dirty = False
+        if op.timestamp_ms is not None:
+            meta_dirty |= self.doc_ts.get(doc_id) != op.timestamp_ms
+            self.doc_ts[doc_id] = op.timestamp_ms
+        if op.parent is not None:
+            meta_dirty |= self.doc_parent.get(doc_id) != str(op.parent)
+            self.doc_parent[doc_id] = str(op.parent)
+        else:
+            meta_dirty |= self.doc_parent.pop(doc_id, None) is not None
+        if op.doc_type and op.doc_type != "_doc":
+            meta_dirty |= self.doc_types.get(doc_id) != op.doc_type
+            self.doc_types[doc_id] = op.doc_type
+        else:
+            meta_dirty |= self.doc_types.pop(doc_id, None) is not None
+        if routing is not None:
+            meta_dirty |= self.doc_routing.get(doc_id) != str(routing)
+            self.doc_routing[doc_id] = str(routing)
+        else:
+            meta_dirty |= self.doc_routing.pop(doc_id, None) is not None
+        resp_type = self.doc_types.get(doc_id, "_doc")
+        if meta_dirty:
+            # write-through: the engine's translog made the DOC durable
+            # at this point, so its type/routing metadata must be
+            # durable too (crash between here and flush must not turn
+            # a typed get into a 404 after replay)
+            self._save_types()
+        return resp_type
 
     def _check_type(self, doc_id: str, doc_type: str | None) -> str:
         stored = self.doc_types.get(doc_id, "_doc")

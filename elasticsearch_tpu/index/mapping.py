@@ -15,12 +15,15 @@ per-column exists bitmask.
 from __future__ import annotations
 
 import datetime as _dt
+import functools
 import json
 import numbers
 import re
+import threading
 from dataclasses import dataclass, field
 
-from ..utils.errors import MapperParsingError, IllegalArgumentError
+from ..utils.errors import (ElasticsearchTpuError, IllegalArgumentError,
+                            MapperParsingError)
 from ..utils.settings import Settings
 from .analysis import AnalysisService, Analyzer
 
@@ -71,6 +74,10 @@ ALL_TYPES = NUMERIC_TYPES | {TEXT, KEYWORD, DATE, BOOLEAN, IP, DENSE_VECTOR,
 _LEGACY_STRING = "string"
 
 _EPOCH = _dt.datetime(1970, 1, 1, tzinfo=_dt.timezone.utc)
+_SCALARS = (str, int, float, bool)    # by exact type: what JSON decodes to
+
+# json.dumps(obj, separators=(",", ":")) builds an encoder a call
+_compact_json = json.JSONEncoder(separators=(",", ":")).encode
 
 _DATE_FORMATS = (
     "%Y-%m-%dT%H:%M:%S.%f%z", "%Y-%m-%dT%H:%M:%S%z",
@@ -79,20 +86,48 @@ _DATE_FORMATS = (
 )
 
 
-def parse_date_millis(value) -> int:
-    """Parse a date value to epoch millis.
+_EPOCH_ORDINAL = _dt.date(1970, 1, 1).toordinal()
+_EPOCH_DIGITS = re.compile(r"[+-]?\d{10,}")
+_ISO_DATE_TIME = re.compile(
+    r"(\d{4})-(\d\d)-(\d\d)T(\d\d):(\d\d):(\d\d)(?:\.(\d{1,6}))?"
+    r"(?:Z|([+-])(\d\d):(\d\d))?", re.ASCII)
 
-    Ref: index/mapper/core/DateFieldMapper.java (joda `dateOptionalTime
-    || epoch_millis`). Accepts epoch millis ints, ISO-8601 strings, and
-    the common-log format used by the http_logs benchmark corpus.
-    """
-    if isinstance(value, bool):
-        raise MapperParsingError(f"cannot parse boolean [{value}] as date")
-    if isinstance(value, numbers.Number):
-        return int(value)
-    s = str(value).strip()
-    if re.fullmatch(r"[+-]?\d{10,}", s):
-        return int(s)
+
+def _iso_millis(s: str) -> int | None:
+    """Epoch millis of a fixed-layout ISO-8601 date-time,
+    `YYYY-MM-DDTHH:MM:SS[.f{1,6}][Z|+hh:mm|-hh:mm]`; None for anything
+    else, which then goes through `_DATE_FORMATS`. It takes only what
+    that loop takes, and computes `int(dt.timestamp() * 1000)` with the
+    same float arithmetic, so every value parses to the same millis
+    either way (tests/test_date_fast_path.py holds both to it)."""
+    m = _ISO_DATE_TIME.fullmatch(s)
+    if m is None:
+        return None
+    year, month, day, hh, mm, ss, frac, sign, oh, om = m.groups()
+    hh, mm, ss = int(hh), int(mm), int(ss)
+    if hh > 23 or mm > 59 or ss > 59:
+        return None
+    try:
+        days = _dt.date(int(year), int(month), int(day)).toordinal() \
+            - _EPOCH_ORDINAL
+    except ValueError:
+        return None
+    secs = days * 86400 + hh * 3600 + mm * 60 + ss
+    if sign is not None:
+        oh, om = int(oh), int(om)
+        if oh > 23 or om > 59:
+            return None
+        off = oh * 3600 + om * 60
+        secs -= off if sign == "+" else -off
+    if frac is None or not int(frac):
+        return secs * 1000
+    # datetime.timestamp() is (dt - epoch).total_seconds(): whole
+    # microseconds over 10**6 as one true division
+    micros = int(frac) * 10 ** (6 - len(frac))
+    return int((secs * 10 ** 6 + micros) / 10 ** 6 * 1000)
+
+
+def _format_loop_millis(s: str, value) -> int:
     for fmt in _DATE_FORMATS:
         try:
             dt = _dt.datetime.strptime(s, fmt)
@@ -102,6 +137,28 @@ def parse_date_millis(value) -> int:
         except ValueError:
             continue
     raise MapperParsingError(f"failed to parse date value [{value}]")
+
+
+def parse_date_millis(value) -> int:
+    """Parse a date value to epoch millis.
+
+    Ref: index/mapper/core/DateFieldMapper.java (joda `dateOptionalTime
+    || epoch_millis`). Accepts epoch millis ints, ISO-8601 strings, and
+    the common-log format used by the http_logs benchmark corpus.
+    """
+    if type(value) is not str:
+        if isinstance(value, bool):
+            raise MapperParsingError(
+                f"cannot parse boolean [{value}] as date")
+        if isinstance(value, numbers.Number):
+            return int(value)
+    s = str(value).strip()
+    millis = _iso_millis(s)
+    if millis is not None:
+        return millis
+    if _EPOCH_DIGITS.fullmatch(s):
+        return int(s)
+    return _format_loop_millis(s, value)
 
 
 def format_date_millis(millis: int) -> str:
@@ -115,15 +172,16 @@ _IP_RE = re.compile(r"^(\d{1,3})\.(\d{1,3})\.(\d{1,3})\.(\d{1,3})$")
 def parse_ip(value) -> int:
     """IPv4 -> uint32 (stored as a numeric column, like the reference's
     IpFieldMapper which indexes IPs as longs)."""
-    if isinstance(value, numbers.Number) and not isinstance(value, bool):
+    if type(value) is not str and isinstance(value, numbers.Number) \
+            and not isinstance(value, bool):
         return int(value)
     m = _IP_RE.match(str(value))
     if not m:
         raise MapperParsingError(f"failed to parse ip [{value}]")
-    parts = [int(g) for g in m.groups()]
-    if any(p > 255 for p in parts):
+    a, b, c, d = map(int, m.groups())
+    if a > 255 or b > 255 or c > 255 or d > 255:
         raise MapperParsingError(f"failed to parse ip [{value}]")
-    return (parts[0] << 24) | (parts[1] << 16) | (parts[2] << 8) | parts[3]
+    return (a << 24) | (b << 16) | (c << 8) | d
 
 
 def _geo_precision_chars(precision) -> int:
@@ -236,7 +294,7 @@ class FieldMapper:
         return d
 
 
-@dataclass
+@dataclass(slots=True)
 class ParsedField:
     """Columnar contribution of one field of one document."""
 
@@ -246,7 +304,7 @@ class ParsedField:
     value: object = None              # KEYWORD: str; numeric/date/bool/ip: number
 
 
-@dataclass
+@dataclass(slots=True)
 class ParsedDocument:
     """Ref: index/mapper/ParsedDocument.java — but columnar. `nested`
     carries block-join sub-documents (ref: ParsedDocument.docs() — Lucene
@@ -274,6 +332,13 @@ class DocumentMapper:
         self._fields: dict[str, FieldMapper] = {}
         self._multi_fields: dict[str, list[str]] = {}  # parent -> sub names
         self._nested_paths: set[str] = set()
+        # what the mapping decides about a field, decided once and not
+        # once a value: name -> its emitter (_emitter_for), and name ->
+        # the emitters a scalar value of a mapped leaf goes through
+        # (_leaf_plan). Emptied by _mapping_changed.
+        self._emitters: dict[str, object] = {}
+        self._leaf_plans: dict[str, tuple] = {}
+        self._plan_lock = threading.Lock()
         self.parent_type: str | None = None
         self.routing_required = False
         self.ts_enabled = False
@@ -323,8 +388,18 @@ class DocumentMapper:
             props = {k: v for k, v in mapping.items() if k not in self._META_KEYS}
         if not isinstance(props, dict):
             raise MapperParsingError("mapping [properties] must be an object")
-        for name, spec in props.items():
-            self._add_field(name, spec)
+        try:
+            for name, spec in props.items():
+                self._add_field(name, spec)
+        finally:
+            self._mapping_changed()
+
+    def _mapping_changed(self) -> None:
+        """After the last write to `_fields` / `_multi_fields` /
+        `_nested_paths`: what was decided from the old mapping goes."""
+        with self._plan_lock:
+            self._emitters.clear()
+            self._leaf_plans.clear()
 
     def _add_field(self, name: str, spec: dict) -> FieldMapper:
         if not isinstance(spec, dict):
@@ -483,29 +558,67 @@ class DocumentMapper:
             pass
         return TEXT
 
-    def _coerce(self, fm: FieldMapper, value):
-        try:
-            if fm.type == DATE:
-                return parse_date_millis(value)
-            if fm.type == BOOLEAN:
-                if isinstance(value, bool):
-                    return value
-                return str(value).lower() in ("true", "1", "on", "yes")
-            if fm.type == IP:
-                return parse_ip(value)
-            if fm.type in (LONG, INTEGER, SHORT, BYTE):
-                if isinstance(value, str) and not value.strip().lstrip("+-").isdigit():
+    @staticmethod
+    def _converter(fm: FieldMapper):
+        """`convert(value)`: a value of this date, boolean, ip or
+        numeric field as its column holds it, or MapperParsingError /
+        ValueError / TypeError."""
+        typ = fm.type
+        if typ == DATE:
+            return parse_date_millis
+        if typ == BOOLEAN:
+            return lambda value: value if isinstance(value, bool) \
+                else str(value).lower() in ("true", "1", "on", "yes")
+        if typ == IP:
+            return parse_ip
+        if typ in (LONG, INTEGER, SHORT, BYTE):
+            def to_int(value):
+                if isinstance(value, str) \
+                        and not value.strip().lstrip("+-").isdigit():
                     raise MapperParsingError(
-                        f"failed to parse [{fm.name}] as {fm.type}: [{value}]")
+                        f"failed to parse [{fm.name}] as {typ}: [{value}]")
                 return int(value)
-            if fm.type in (DOUBLE, FLOAT):
-                return float(value)
-        except (ValueError, TypeError):
-            raise MapperParsingError(f"failed to parse [{fm.name}] value [{value}]")
-        return value
+            return to_int
+        return float        # DOUBLE, FLOAT
 
     def parse(self, doc_id: str, source: dict | bytes | str) -> ParsedDocument:
         """JSON document -> columnar field contributions."""
+        parsed = self.parse_many([(doc_id, source)])[0]
+        if isinstance(parsed, ElasticsearchTpuError):
+            raise parsed
+        return parsed
+
+    def parse_many(self, docs: list[tuple[str, dict | bytes | str]]
+                   ) -> list[ParsedDocument | ElasticsearchTpuError]:
+        """Parse a batch in document order (a dynamic field maps from
+        the first document that carries it, as one at a time). A
+        document that fails is its own error in the result (a
+        MapperParsingError, or what an unknown analyzer raises) and the
+        batch goes on. Text values are not analyzed during the
+        walk: each waits as (analyzer, field to fill, text), and the
+        documents that parsed go to each analyzer together at the end,
+        one `analyze_batch` call for the whole batch."""
+        out: list[ParsedDocument | ElasticsearchTpuError] = []
+        waiting: dict[int, tuple[Analyzer, list[ParsedField], list[str]]] = {}
+        for doc_id, source in docs:
+            texts: list[tuple[Analyzer, ParsedField, str]] = []
+            try:
+                out.append(self._parse_one(doc_id, source, texts))
+            except ElasticsearchTpuError as e:
+                out.append(e)
+                continue
+            for analyzer, pf, text in texts:
+                _a, fields, strings = waiting.setdefault(
+                    id(analyzer), (analyzer, [], []))
+                fields.append(pf)
+                strings.append(text)
+        for analyzer, fields, strings in waiting.values():
+            for pf, tokens in zip(fields, analyzer.analyze_batch(strings)):
+                pf.tokens = tokens
+        return out
+
+    def _parse_one(self, doc_id: str, source: dict | bytes | str,
+                   texts: list) -> ParsedDocument:
         if isinstance(source, (bytes, str)):
             raw = source if isinstance(source, bytes) else source.encode()
             try:
@@ -514,11 +627,11 @@ class DocumentMapper:
                 raise MapperParsingError(f"failed to parse document: {e}")
         else:
             obj = source
-            raw = json.dumps(source, separators=(",", ":")).encode()
+            raw = _compact_json(source).encode()
         if not isinstance(obj, dict):
             raise MapperParsingError("document root must be an object")
         out = ParsedDocument(doc_id=doc_id, source=raw)
-        self._parse_object("", obj, out)
+        self._parse_object("", obj, out, texts)
         self._resolve_completion_contexts(obj, out)
         return out
 
@@ -559,9 +672,19 @@ class DocumentMapper:
                     resolved[ctx_name] = [str(x) for x in vals]
             entry["context"] = resolved
 
-    def _parse_object(self, prefix: str, obj: dict, out: ParsedDocument) -> None:
+    def _parse_object(self, prefix: str, obj: dict, out: ParsedDocument,
+                      texts: list) -> None:
+        plans = self._leaf_plans
         for key, value in obj.items():
             name = f"{prefix}{key}"
+            if type(value) in _SCALARS:
+                plan = plans.get(name)
+                if plan is None:
+                    plan = self._leaf_plan(name)
+                if plan is not None:
+                    for emit in plan:
+                        emit(value, out, texts)
+                    continue
             if name in self._nested_paths:
                 # each element becomes a block-join sub-document (ref:
                 # ObjectMapper nested=true -> Lucene child docs). Doubly-
@@ -572,9 +695,9 @@ class DocumentMapper:
                     if not isinstance(el, dict):
                         raise MapperParsingError(
                             f"nested field [{name}] elements must be objects")
-                    src = json.dumps(el, separators=(",", ":")).encode()
+                    src = _compact_json(el).encode()
                     sub = ParsedDocument(doc_id="", source=src)
-                    self._parse_object(f"{name}.", el, sub)
+                    self._parse_object(f"{name}.", el, sub, texts)
                     out.nested.append((name, sub.fields, src))
                     out.nested.extend(sub.nested)
                 continue
@@ -584,19 +707,19 @@ class DocumentMapper:
                                                   JOIN, COMPLETION):
                     # {"lat":..,"lon":..} point / GeoJSON shape / join /
                     # completion entry, not a sub-object
-                    self._parse_value(name, value, out)
+                    self._parse_value(name, value, out, texts)
                     continue
-                self._parse_object(f"{name}.", value, out)
+                self._parse_object(f"{name}.", value, out, texts)
                 continue
             if isinstance(value, list):
                 fm = self._fields.get(name)
                 if fm is not None and fm.type == DENSE_VECTOR:
-                    self._parse_value(name, value, out)
+                    self._parse_value(name, value, out, texts)
                     continue
                 if fm is not None and fm.type == GEO_POINT and value and \
                         isinstance(value[0], (int, float)):
                     # bare [lon, lat] pair (GeoJSON order)
-                    self._parse_value(name, value, out)
+                    self._parse_value(name, value, out, texts)
                     continue
             values = value if isinstance(value, list) else [value]
             for v in values:
@@ -605,13 +728,14 @@ class DocumentMapper:
                 if isinstance(v, dict):
                     fm = self._fields.get(name)
                     if fm is not None and fm.type in (GEO_POINT, GEO_SHAPE):
-                        self._parse_value(name, v, out)  # point/shape array
+                        self._parse_value(name, v, out, texts)  # point/shape array
                     else:
-                        self._parse_object(f"{name}.", v, out)
+                        self._parse_object(f"{name}.", v, out, texts)
                     continue
-                self._parse_value(name, v, out)
+                self._parse_value(name, v, out, texts)
 
-    def _parse_value(self, name: str, value, out: ParsedDocument) -> None:
+    def _parse_value(self, name: str, value, out: ParsedDocument,
+                     texts: list) -> None:
         fm = self._fields.get(name)
         if fm is None:
             if self.dynamic == "strict":
@@ -630,122 +754,205 @@ class DocumentMapper:
                 twin = FieldMapper(name=f"{name}.keyword", type=KEYWORD)
                 self._fields[twin.name] = twin
                 self._multi_fields.setdefault(name, []).append(twin.name)
-        self._emit_field(fm, value, out)
+            self._mapping_changed()
+        self._emit_field(fm, value, out, texts)
         # multi-fields index the same value under each sub-mapper's type
         # (ref: AbstractFieldMapper.MultiFields.parse)
         for sub_name in self._multi_fields.get(name, ()):
             sub = self._fields.get(sub_name)
             if sub is not None:
-                self._emit_field(sub, value, out)
+                self._emit_field(sub, value, out, texts)
 
-    def _emit_field(self, fm: FieldMapper, value, out: ParsedDocument) -> None:
-        if fm.type == TEXT:
+    def _leaf_plan(self, name: str) -> tuple | None:
+        """The emitters a scalar value under `name` goes through: the
+        field's own, then each multi-field's, as `_parse_value` calls
+        them. None where the mapping has no such leaf (unmapped, so
+        `_parse_value` decides on `dynamic`; or a nested path)."""
+        with self._plan_lock:
+            fm = self._fields.get(name)
+            if fm is None or name in self._nested_paths:
+                return None
+            subs = (self._fields.get(sub)
+                    for sub in self._multi_fields.get(name, ()))
+            plan = tuple(
+                emit for emit in (
+                    self._emitter_for(f) for f in (fm, *subs)
+                    if f is not None)
+                if emit is not None)
+            self._leaf_plans[name] = plan
+            return plan
+
+    def _emit_field(self, fm: FieldMapper, value, out: ParsedDocument,
+                    texts: list) -> None:
+        try:
+            emit = self._emitters[fm.name]
+        except KeyError:
+            with self._plan_lock:
+                emit = self._emitter_for(fm)
+        if emit is not None:
+            emit(value, out, texts)
+
+    def _emitter_for(self, fm: FieldMapper):
+        """`emit(value, out, texts)`: add what one value of this field
+        contributes to `out.fields`; None where the field contributes
+        nothing. Caller holds `_plan_lock`."""
+        name = fm.name
+        try:
+            return self._emitters[name]
+        except KeyError:
+            pass
+        if self._fields.get(name) is not fm:
+            return self._make_emitter(fm)     # not this mapping's: uncached
+        emit = self._emitters[name] = self._make_emitter(fm)
+        return emit
+
+    def _make_emitter(self, fm: FieldMapper):
+        name, typ = fm.name, fm.type
+        if typ == TEXT:
             if not fm.index:
-                return  # index:false text is neither searchable nor columnar
-            analyzer: Analyzer = self.analysis.analyzer(fm.analyzer)
-            out.fields.append(ParsedField(name=fm.name, type=TEXT,
-                                          tokens=analyzer.analyze(str(value))))
-        elif fm.type == COMPLETION:
-            # string | [strings] | {"input": ..., "output": ..., "weight":
-            # ..., "payload": ..., "context": ...} -> one normalized entry
-            # (ref: CompletionFieldMapper.parse)
-            if isinstance(value, dict):
-                inputs = value.get("input") or []
-                inputs = inputs if isinstance(inputs, list) else [inputs]
-                entry = {
-                    "input": [str(i) for i in inputs],
-                    "output": (str(value["output"])
-                               if value.get("output") is not None else None),
-                    "weight": int(value.get("weight", 1)),
-                    "payload": value.get("payload"),
-                    "context": (value.get("context")
-                                if isinstance(value.get("context"), dict)
-                                else {}),
-                }
-            else:
-                entry = {"input": [str(value)], "output": None,
-                         "weight": 1, "payload": None, "context": {}}
-            out.fields.append(ParsedField(name=fm.name, type=COMPLETION,
-                                          value=entry))
-        elif not fm.index and not fm.doc_values:
-            return
-        elif fm.type == KEYWORD:
-            if len(str(value)) <= 256 or "." not in fm.name:  # ignore_above on subs
-                out.fields.append(ParsedField(name=fm.name, type=KEYWORD,
-                                              value=str(value)))
-        elif fm.type == JOIN:
-            # {"name": relation, "parent": id} or bare relation string ->
-            # relation ordinal column + "<field>#parent" id column (the
-            # reference's _parent field data, ParentFieldMapper.java)
-            if isinstance(value, dict):
-                rel = value.get("name")
-                parent = value.get("parent")
-            else:
-                rel, parent = str(value), None
-            known = set()
-            for p, c in (fm.relations or {}).items():
-                known.add(p)
-                known.update(c if isinstance(c, list) else [c])
-            if rel not in known:
-                raise MapperParsingError(
-                    f"unknown join relation [{rel}] on field [{fm.name}]")
-            out.fields.append(ParsedField(name=fm.name, type=KEYWORD,
-                                          value=str(rel)))
-            if parent is not None:
-                out.fields.append(ParsedField(name=f"{fm.name}#parent",
-                                              type=KEYWORD,
-                                              value=str(parent)))
-        elif fm.type == GEO_POINT:
-            from ..ops.geo import parse_geo_point
-            from ..utils.errors import QueryParsingError
+                return None  # index:false text is neither searchable nor columnar
+
+            # an unknown analyzer fails the document, as it did
+            analyzer = self.analysis.analyzer(fm.analyzer)
+
+            def emit_text(value, out, texts):
+                pf = ParsedField(name, TEXT)
+                out.fields.append(pf)
+                texts.append((analyzer, pf, str(value)))
+            return emit_text
+        if typ == COMPLETION:
+            return functools.partial(self._emit_completion, fm)
+        if not fm.index and not fm.doc_values:
+            return None
+        if typ == KEYWORD:
+            sub_field = "." in name     # ignore_above on subs
+
+            def emit_keyword(value, out, texts):
+                s = str(value)
+                if len(s) <= 256 or not sub_field:
+                    out.fields.append(ParsedField(name, KEYWORD, None, s))
+            return emit_keyword
+        structured = {JOIN: self._emit_join, GEO_POINT: self._emit_geo_point,
+                      GEO_SHAPE: self._emit_geo_shape,
+                      DENSE_VECTOR: self._emit_dense_vector}.get(typ)
+        if structured is not None:
+            return functools.partial(structured, fm)
+        convert = self._converter(fm)
+
+        def emit_converted(value, out, texts):
             try:
-                lat, lon = parse_geo_point(value)
-            except QueryParsingError as e:
+                converted = convert(value)
+            except (ValueError, TypeError, MapperParsingError) as e:
                 if fm.ignore_malformed:
                     return
-                raise MapperParsingError(str(e))
-            out.fields.append(ParsedField(name=fm.name, type=GEO_POINT,
-                                          value=(lat, lon)))
-        elif fm.type == GEO_SHAPE:
-            # GeoJSON -> prefix-tree cell tokens in the standard postings
-            # layout, so shape queries are terms disjunctions on device
-            # (ops/geo_shape.py; ref: GeoShapeFieldMapper.parse)
-            from ..ops.geo_shape import (parse_shape, index_tokens,
-                                         effective_levels)
-            from ..utils.errors import QueryParsingError
-            try:
-                shp = parse_shape(value)
-                tree, levels, err_pct = shape_tree_config(fm)
-                toks = index_tokens(shp, tree,
-                                    effective_levels(shp, tree, levels,
-                                                     err_pct))
-            except (QueryParsingError, TypeError, ValueError, IndexError,
-                    KeyError) as e:
-                if fm.ignore_malformed:
-                    return
+                if isinstance(e, MapperParsingError):
+                    raise
                 raise MapperParsingError(
-                    f"failed to parse [{fm.name}]: {e}")
-            out.fields.append(ParsedField(name=fm.name, type=TEXT,
-                                          tokens=toks))
-        elif fm.type == DENSE_VECTOR:
-            if not isinstance(value, list):
-                raise MapperParsingError(
-                    f"dense_vector [{fm.name}] requires an array of floats")
-            vec = [float(x) for x in value]
-            if fm.dims is not None and len(vec) != fm.dims:
-                raise MapperParsingError(
-                    f"dense_vector [{fm.name}] has {len(vec)} dims, "
-                    f"mapping expects {fm.dims}")
-            out.fields.append(ParsedField(name=fm.name, type=DENSE_VECTOR,
-                                          value=vec))
+                    f"failed to parse [{name}] value [{value}]")
+            out.fields.append(ParsedField(name, typ, None, converted))
+        return emit_converted
+
+    @staticmethod
+    def _emit_completion(fm: FieldMapper, value, out: ParsedDocument,
+                         texts: list) -> None:
+        # string | [strings] | {"input": ..., "output": ..., "weight":
+        # ..., "payload": ..., "context": ...} -> one normalized entry
+        # (ref: CompletionFieldMapper.parse)
+        if isinstance(value, dict):
+            inputs = value.get("input") or []
+            inputs = inputs if isinstance(inputs, list) else [inputs]
+            entry = {
+                "input": [str(i) for i in inputs],
+                "output": (str(value["output"])
+                           if value.get("output") is not None else None),
+                "weight": int(value.get("weight", 1)),
+                "payload": value.get("payload"),
+                "context": (value.get("context")
+                            if isinstance(value.get("context"), dict)
+                            else {}),
+            }
         else:
-            try:
-                coerced = self._coerce(fm, value)
-            except MapperParsingError:
-                if fm.ignore_malformed:
-                    return
-                raise
-            out.fields.append(ParsedField(name=fm.name, type=fm.type, value=coerced))
+            entry = {"input": [str(value)], "output": None,
+                     "weight": 1, "payload": None, "context": {}}
+        out.fields.append(ParsedField(name=fm.name, type=COMPLETION,
+                                      value=entry))
+
+    @staticmethod
+    def _emit_join(fm: FieldMapper, value, out: ParsedDocument,
+                   texts: list) -> None:
+        # {"name": relation, "parent": id} or bare relation string ->
+        # relation ordinal column + "<field>#parent" id column (the
+        # reference's _parent field data, ParentFieldMapper.java)
+        if isinstance(value, dict):
+            rel = value.get("name")
+            parent = value.get("parent")
+        else:
+            rel, parent = str(value), None
+        known = set()
+        for p, c in (fm.relations or {}).items():
+            known.add(p)
+            known.update(c if isinstance(c, list) else [c])
+        if rel not in known:
+            raise MapperParsingError(
+                f"unknown join relation [{rel}] on field [{fm.name}]")
+        out.fields.append(ParsedField(name=fm.name, type=KEYWORD,
+                                      value=str(rel)))
+        if parent is not None:
+            out.fields.append(ParsedField(name=f"{fm.name}#parent",
+                                          type=KEYWORD,
+                                          value=str(parent)))
+
+    @staticmethod
+    def _emit_geo_point(fm: FieldMapper, value, out: ParsedDocument,
+                        texts: list) -> None:
+        from ..ops.geo import parse_geo_point
+        from ..utils.errors import QueryParsingError
+        try:
+            lat, lon = parse_geo_point(value)
+        except QueryParsingError as e:
+            if fm.ignore_malformed:
+                return
+            raise MapperParsingError(str(e))
+        out.fields.append(ParsedField(name=fm.name, type=GEO_POINT,
+                                      value=(lat, lon)))
+
+    @staticmethod
+    def _emit_geo_shape(fm: FieldMapper, value, out: ParsedDocument,
+                        texts: list) -> None:
+        # GeoJSON -> prefix-tree cell tokens in the standard postings
+        # layout, so shape queries are terms disjunctions on device
+        # (ops/geo_shape.py; ref: GeoShapeFieldMapper.parse)
+        from ..ops.geo_shape import (parse_shape, index_tokens,
+                                     effective_levels)
+        from ..utils.errors import QueryParsingError
+        try:
+            shp = parse_shape(value)
+            tree, levels, err_pct = shape_tree_config(fm)
+            toks = index_tokens(shp, tree,
+                                effective_levels(shp, tree, levels,
+                                                 err_pct))
+        except (QueryParsingError, TypeError, ValueError, IndexError,
+                KeyError) as e:
+            if fm.ignore_malformed:
+                return
+            raise MapperParsingError(
+                f"failed to parse [{fm.name}]: {e}")
+        out.fields.append(ParsedField(name=fm.name, type=TEXT,
+                                      tokens=toks))
+
+    @staticmethod
+    def _emit_dense_vector(fm: FieldMapper, value, out: ParsedDocument,
+                           texts: list) -> None:
+        if not isinstance(value, list):
+            raise MapperParsingError(
+                f"dense_vector [{fm.name}] requires an array of floats")
+        vec = [float(x) for x in value]
+        if fm.dims is not None and len(vec) != fm.dims:
+            raise MapperParsingError(
+                f"dense_vector [{fm.name}] has {len(vec)} dims, "
+                f"mapping expects {fm.dims}")
+        out.fields.append(ParsedField(name=fm.name, type=DENSE_VECTOR,
+                                      value=vec))
 
 
 class MapperService:
@@ -773,6 +980,9 @@ class MapperService:
 
     def parse(self, doc_id: str, source) -> ParsedDocument:
         return self.mapper.parse(doc_id, source)
+
+    def parse_many(self, docs):
+        return self.mapper.parse_many(docs)
 
     def merge_mapping(self, mapping: dict) -> None:
         self.mapper.merge(mapping)

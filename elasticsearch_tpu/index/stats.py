@@ -43,6 +43,11 @@ class IndexOpStats:
         self.delete_total = 0
         self.delete_time_ms = 0
         self.noop_update_total = 0
+        # index ops that reached their shard in a batch of two or more
+        # (IndexService.index_many), and those batches: against
+        # index_total, the share of writes that batching engaged on
+        self.bulk_batches = 0
+        self.bulk_batch_docs = 0
         self.types: dict[str, _Counter] = {}       # per-type index counters
         # get (ref: index/get/ShardGetService stats)
         self.get_total = 0
@@ -80,12 +85,15 @@ class IndexOpStats:
         self.percolate_time_ms = 0
 
     # -- record sites ------------------------------------------------------
-    def on_index(self, doc_type: str | None, took_ms: float = 0.0) -> None:
+    def on_index_many(self, doc_types: list[str | None], batches: int,
+                      batch_docs: int) -> None:
+        """One take of the lock for a run of written docs."""
         with self._lock:
-            self.index_total += 1
-            self.index_time_ms += int(took_ms)
-            t = self.types.setdefault(doc_type or "_doc", _Counter())
-            t.inc(took_ms)
+            self.index_total += len(doc_types)
+            self.bulk_batches += batches
+            self.bulk_batch_docs += batch_docs
+            for doc_type in doc_types:
+                self.types.setdefault(doc_type or "_doc", _Counter()).inc()
 
     def on_delete(self, took_ms: float = 0.0) -> None:
         with self._lock:

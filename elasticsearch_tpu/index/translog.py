@@ -18,9 +18,10 @@ CONTAINS the shard (ref: TranslogCorruptedException vs the tolerated
 truncated-translog case).
 
 Durability modes (`index.translog.durability`):
-  * ``request`` (default) — fsync after every op: an op is on disk
-    before its caller sees the ack. Survives kill -9 AND power loss.
-  * ``async``  — flush (page cache) per op, fsync only at explicit
+  * ``request`` (default) — fsync after every add_many (one op, or a
+    bulk request's batch for this shard): an op is on disk before its
+    caller sees the ack. Survives kill -9 AND power loss.
+  * ``async``  — flush (page cache) per add_many, fsync only at explicit
     sync()/flush/rotate: an op survives kill -9 (the page cache
     belongs to the OS, not the process) but power loss may drop the
     window since the last sync. `_synced_size` tracks the known-
@@ -47,6 +48,7 @@ from ..utils.errors import ElasticsearchTpuError
 from . import durability as durability_stats
 
 _HEADER = struct.Struct("<II")
+_json_str = json.encoder.encode_basestring_ascii    # a str as json.dumps has it
 
 OP_INDEX = "index"
 OP_DELETE = "delete"
@@ -71,6 +73,15 @@ class TranslogOp:
     source: bytes | None = None   # for index ops
 
     def to_payload(self) -> bytes:
+        if type(self.doc_id) is str and type(self.version) is int:
+            # what the encoder below prints for these types, put together
+            # by hand (tests/test_bulk_batch.py holds the two to the
+            # same bytes)
+            src = "" if self.source is None else \
+                ',"src":' + _json_str(self.source.decode("utf-8"))
+            return (f'{{"op":{_json_str(self.op)},"id":'
+                    f'{_json_str(self.doc_id)},"v":{self.version}{src}}}'
+                    ).encode("utf-8")
         d = {"op": self.op, "id": self.doc_id, "v": self.version}
         if self.source is not None:
             d["src"] = self.source.decode("utf-8")
@@ -88,9 +99,10 @@ class Translog:
     """Append-only op log with crc-checked records and generations.
 
     When the native layer is available (native/src/estnative.cpp), appends
-    go through est_wal_append — one write() per record with C-side CRC and
-    fdatasync control; the record format on disk is identical, so either
-    implementation can recover the other's files.
+    go through est_wal_write — one write() per add_many, the records
+    framed here — and fsyncs through est_wal_sync; the record format on
+    disk is identical, so either implementation can recover the other's
+    files.
     """
 
     def __init__(self, path: str, sync_each_op: bool = False,
@@ -165,53 +177,57 @@ class Translog:
 
     # -- write path --------------------------------------------------------
     def add(self, op: TranslogOp) -> None:
-        payload = op.to_payload()
-        rec = _HEADER.pack(len(payload), zlib.crc32(payload)) + payload
+        self.add_many([op])
 
-        def torn_append():
-            # the crash residue a real mid-append death leaves: the
-            # record's prefix on disk, its tail missing — recovery's
-            # torn-tail truncation is what chews this. The native WAL
-            # has not written yet, so the tear lands via a throwaway
-            # append fd (the process "dies" right after)
-            half = rec[: max(len(rec) // 2, 1)]
-            if self._fh is not None:
-                self._fh.write(half)
-                self._fh.flush()
-            else:
-                with open(self._file_for(self.generation), "ab") as f:
-                    f.write(half)
-        faults.on_storage_write("translog", "append", index=self.index,
-                                shard=self.shard, partial=torn_append,
-                                unsynced_drop=self._drop_unsynced)
-        if self._wal is not None:
-            if self.sync_each_op:
-                # the native WAL fsyncs INSIDE est_wal_append, so the
-                # fsync crash site fires here (record lost whole — the
-                # pre-ack shape; the python path's fsync fires after
-                # the buffered write, record present-but-unfsynced:
-                # both are legal states for an un-acked op)
-                faults.on_storage_write(
-                    "translog", "fsync", index=self.index,
-                    shard=self.shard,
-                    unsynced_drop=self._drop_unsynced)
-            size = self._lib.est_wal_append(
-                self._wal, payload, len(payload),
-                1 if self.sync_each_op else 0)
-            if size < 0:
-                raise OSError("translog append failed")
-            self._size_in_gen = size
-            self._ops_in_gen += 1
-            if self.sync_each_op:
-                self._synced_size = size
+    def add_many(self, ops: list[TranslogOp]) -> None:
+        """Append a batch: the records one op at a time would write,
+        byte for byte and in order, joined and written with ONE write.
+        `request` durability fsyncs once, after the write and before
+        this returns, so before the caller acknowledges any op of the
+        batch (the reference syncs the translog once a shard bulk
+        request); `async` hands the bytes to the page cache and leaves
+        the fsync to sync()/flush/rotate."""
+        if not ops:
             return
-        self._fh.write(rec)
-        self._ops_in_gen += 1
-        self._size_in_gen += len(rec)
+        records = []
+        for op in ops:
+            payload = op.to_payload()
+            records.append(
+                _HEADER.pack(len(payload), zlib.crc32(payload)) + payload)
+        if faults.active().rules:
+            self._append_crash_sites(records)
+        blob = b"".join(records)
+        if self._wal is not None:
+            if self._lib.est_wal_write(self._wal, blob, len(blob)) < 0:
+                raise OSError("translog append failed")
+        else:
+            self._fh.write(blob)
+            self._fh.flush()
+        self._ops_in_gen += len(records)
+        self._size_in_gen += len(blob)
         if self.sync_each_op:
             self.sync()
-        else:
-            self._fh.flush()
+
+    def _append_crash_sites(self, records: list[bytes]) -> None:
+        """One `append` crash site a record, as when each record was a
+        write of its own: a rule that fires at record i leaves what a
+        death in the middle of the batch's write leaves, the records
+        before it whole and the first half of record i, which
+        recovery's torn-tail truncation chews. The native WAL has not
+        written yet, so the tear lands via a throwaway append fd (the
+        process "dies" right after)."""
+        for i, rec in enumerate(records):
+            def torn_append():
+                torn = b"".join(records[:i]) + rec[: max(len(rec) // 2, 1)]
+                if self._fh is not None:
+                    self._fh.write(torn)
+                    self._fh.flush()
+                else:
+                    with open(self._file_for(self.generation), "ab") as f:
+                        f.write(torn)
+            faults.on_storage_write("translog", "append", index=self.index,
+                                    shard=self.shard, partial=torn_append,
+                                    unsynced_drop=self._drop_unsynced)
 
     def _drop_unsynced(self) -> None:
         """Power-loss simulation (crash_point `unsynced=drop`): the OS
@@ -222,8 +238,8 @@ class Translog:
         tests pin."""
         if self._fh is not None:
             self._fh.flush()
-        # works for the native WAL too: est_wal_append is one write()
-        # per record, so unfsynced bytes live in the page cache (the
+        # works for the native WAL too: est_wal_write is a plain
+        # write(), so unfsynced bytes live in the page cache (the
         # file), and the "power loss" truncates the file itself — the
         # process is dead right after, nobody writes through the stale
         # handle again

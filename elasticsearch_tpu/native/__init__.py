@@ -74,8 +74,8 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
     lib.est_tokenize_batch.restype = c.c_int64
     lib.est_wal_open.argtypes = [c.c_char_p]
     lib.est_wal_open.restype = c.c_void_p
-    lib.est_wal_append.argtypes = [c.c_void_p, c.c_char_p, c.c_int64, c.c_int]
-    lib.est_wal_append.restype = c.c_int64
+    lib.est_wal_write.argtypes = [c.c_void_p, c.c_char_p, c.c_int64]
+    lib.est_wal_write.restype = c.c_int64
     lib.est_wal_sync.argtypes = [c.c_void_p]
     lib.est_wal_sync.restype = c.c_int
     lib.est_wal_size.argtypes = [c.c_void_p]

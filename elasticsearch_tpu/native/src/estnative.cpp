@@ -16,6 +16,7 @@
 // Pure C ABI (extern "C") consumed via ctypes — no pybind11 dependency.
 // Build: g++ -O3 -shared -fPIC (see ../build.py).
 
+#include <cerrno>
 #include <cstdint>
 #include <cstdio>
 #include <cstring>
@@ -161,26 +162,21 @@ void* est_wal_open(const char* path) {
     return w;
 }
 
-// record: [u32 len][u32 crc32(payload)][payload]; returns new size or -1
-int64_t est_wal_append(void* h, const uint8_t* payload, int64_t len,
-                       int do_sync) {
+// `len` bytes of whole records, each [u32 len][u32 crc32(payload)]
+// [payload], framed by the caller: a batch goes down in one write(),
+// which O_APPEND keeps contiguous. Returns new size or -1
+int64_t est_wal_write(void* h, const uint8_t* records, int64_t len) {
     Wal* w = (Wal*)h;
-    uint32_t hdr[2];
-    hdr[0] = (uint32_t)len;
-    hdr[1] = est_crc32(payload, len);
-    struct iovec {
-        void* base;
-        size_t len;
-    };
-    // single write() of header+payload keeps records contiguous even with
-    // concurrent appenders on the same fd (O_APPEND atomicity)
-    std::vector<uint8_t> rec(8 + len);
-    memcpy(rec.data(), hdr, 8);
-    memcpy(rec.data() + 8, payload, len);
-    ssize_t n = ::write(w->fd, rec.data(), rec.size());
-    if (n != (ssize_t)rec.size()) return -1;
-    w->size += n;
-    if (do_sync) ::fdatasync(w->fd);
+    int64_t done = 0;
+    while (done < len) {
+        ssize_t n = ::write(w->fd, records + done, len - done);
+        if (n < 0) {
+            if (errno == EINTR) continue;
+            return -1;
+        }
+        done += n;
+        w->size += n;
+    }
     return w->size;
 }
 

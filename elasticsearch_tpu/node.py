@@ -21,9 +21,11 @@ import time
 from .utils.settings import Settings, parse_time_value as _parse_time_value
 from .utils.errors import (IndexNotFoundError, IndexAlreadyExistsError,
                            ElasticsearchTpuError, IllegalArgumentError,
-                           SearchTimeoutError, ShardFailedError)
+                           PowerLossError, SearchTimeoutError,
+                           ShardFailedError)
 from .utils import profiler
 from .utils.metrics import MetricsRegistry
+from .index.engine import IndexOp
 from .index.index_service import IndexService
 from .search.controller import (merge_shard_results, shards_header,
                                 shard_failure)
@@ -592,17 +594,51 @@ class Node:
                   doc_type: str | None = None,
                   version_type: str = "internal",
                   parent: str | None = None,
-                  timestamp: str | None = None) -> dict:
+                  timestamp: str | None = None,
+                  op_type: str = "index") -> dict:
+        """One document: a run of one through the bulk write path."""
         svc = self._ensure_index(index)
+        op = self._index_op(svc, self._write_context(svc), doc_id, body,
+                            routing, doc_type, op_type == "create", version,
+                            version_type, ttl, parent, timestamp)
+        r = self._index_ops(svc, [op])[0]
+        if isinstance(r, ElasticsearchTpuError):
+            raise r
+        if refresh:
+            # per-shard refresh: a doc-level refresh only publishes the
+            # WRITTEN shard (ref: TransportIndexAction refresh flag is a
+            # shard-level operation; delete/50_refresh.yaml encodes it).
+            # Parent folds into routing exactly as the write path did.
+            svc.shard_for(op.doc_id,
+                          routing if routing is not None else parent
+                          ).refresh()
+        return r
+
+    @staticmethod
+    def _write_context(svc) -> tuple[bool, int | None, int]:
+        """What every write of a run needs of its index, read once a run
+        (`_index_op` takes it): is routing required, the mapping-level
+        default TTL (ref: TTLFieldMapper default), the write time."""
+        mapper = svc.mappers
+        return (mapper.parent_type is not None or mapper.routing_required,
+                getattr(mapper.mapper, "ttl_default_ms", None),
+                int(time.time() * 1000))
+
+    def _index_op(self, svc, context: tuple, doc_id: str | None, body,
+                  routing=None, doc_type: str | None = None,
+                  create: bool = False, version: int | None = None,
+                  version_type: str = "internal", ttl=None, parent=None,
+                  timestamp=None) -> IndexOp:
+        """One write of the index API or of a `_bulk` request as the
+        IndexOp its index takes; raises what fails this write alone."""
+        need_routing, ttl_default, ts = context
         if doc_id is None:
             import uuid
             doc_id = uuid.uuid4().hex[:20]
-        self._check_routing_required(svc, doc_id, routing, parent)
-        if ttl is None:
-            # mapping-level default TTL (ref: TTLFieldMapper default)
-            dflt = getattr(svc.mappers.mapper, "ttl_default_ms", None)
-            if dflt:
-                ttl = int(dflt)
+        if need_routing:
+            self._check_routing_required(svc, doc_id, routing, parent)
+        if ttl is None and ttl_default:
+            ttl = int(ttl_default)
         # index timestamp: explicit millis/date param or write time
         # (ref: index/mapper/internal/TimestampFieldMapper.java)
         if timestamp is not None:
@@ -611,8 +647,6 @@ class Node:
                 ts = int(timestamp)
             except (TypeError, ValueError):
                 ts = parse_date_millis(timestamp)
-        else:
-            ts = int(time.time() * 1000)
         if ttl is not None:
             # _ttl metadata (ref: index/mapper/internal/TTLFieldMapper +
             # indices/ttl/IndicesTTLService): expiry stored as a normal
@@ -625,24 +659,31 @@ class Node:
             if expiry <= int(time.time() * 1000):
                 raise IllegalArgumentError(
                     f"AlreadyExpiredException: already expired "
-                    f"[{index}]/[{doc_id}]")
+                    f"[{svc.name}]/[{doc_id}]")
             body["_ttl_expiry"] = expiry
-        _t0 = time.monotonic()
-        r = svc.index_doc(doc_id, body, version, routing, doc_type=doc_type,
-                          version_type=version_type, parent=parent,
-                          timestamp_ms=ts)
-        self._indexing_slowlog(svc, doc_id, body,
-                               (time.monotonic() - _t0) * 1000.0)
-        if refresh:
-            # per-shard refresh: a doc-level refresh only publishes the
-            # WRITTEN shard (ref: TransportIndexAction refresh flag is a
-            # shard-level operation; delete/50_refresh.yaml encodes it).
-            # Parent folds into routing exactly as the write path did.
-            svc.shard_for(doc_id,
-                          routing if routing is not None else parent
-                          ).refresh()
-        self.metrics.counter("indexing.index_total").inc()
-        return r
+        return IndexOp(doc_id, body, version, version_type, create, routing,
+                       parent, doc_type, ts)
+
+    def _index_ops(self, svc, ops: list[IndexOp]
+                   ) -> list[dict | ElasticsearchTpuError]:
+        """A run of writes to one index as one batch per shard
+        (IndexService.index_many). One result an op, in order: its
+        response, or the error it alone failed with."""
+        if self._indexing_slowlog_on(svc):
+            # a threshold is on a document's own time: one at a time
+            written = []
+            for op in ops:
+                _t0 = time.monotonic()
+                written.extend(svc.index_many([op]))
+                if type(written[-1]) is dict:
+                    self._indexing_slowlog(
+                        svc, op.doc_id, op.source,
+                        (time.monotonic() - _t0) * 1000.0)
+        else:
+            written = svc.index_many(ops)
+        self.metrics.counter("indexing.index_total").inc(
+            sum(1 for r in written if type(r) is dict))
+        return written
 
     @staticmethod
     def _slowlog(logger_name: str, settings, threshold_prefix: str,
@@ -668,18 +709,23 @@ class Node:
                 log_fn(fmt, *args)
                 return
 
+    @staticmethod
+    def _indexing_slowlog_on(svc) -> bool:
+        """Is any indexing slowlog threshold configured at all — the
+        common (unconfigured) write path must not tax every document."""
+        return any(
+            svc.settings.get_str(
+                f"index.indexing.slowlog.threshold.index.{lvl}") is not None
+            for lvl in ("warn", "info", "debug", "trace"))
+
     @classmethod
     def _indexing_slowlog(cls, svc, doc_id: str, body,
                           took_ms: float) -> None:
         """Per-index indexing slowlog (ref: index/indexing/slowlog/
         ShardSlowLogIndexingService.java; source truncated per
-        index.indexing.slowlog.source). Serializing the source is paid
-        only when a threshold is configured at all — the common
-        (unconfigured) write path must not tax every document."""
+        index.indexing.slowlog.source); the caller has seen
+        `_indexing_slowlog_on`."""
         prefix = "index.indexing.slowlog.threshold.index"
-        if not any(svc.settings.get_str(f"{prefix}.{lvl}") is not None
-                   for lvl in ("warn", "info", "debug", "trace")):
-            return
         limit = svc.settings.get_int("index.indexing.slowlog.source", 1000)
         src = json.dumps(body, default=str)[:limit] \
             if not isinstance(body, (bytes, str)) else str(body)[:limit]
@@ -896,31 +942,80 @@ class Node:
     def bulk(self, operations: list[tuple[str, dict]], refresh: bool = False) -> dict:
         """operations: [(action, payload)] where action in index/create/
         delete/update; payload carries _index/_id/doc. Ref:
-        TransportBulkAction.executeBulk grouping by shard."""
+        TransportBulkAction.executeBulk grouping by shard.
+
+        Each run of consecutive index / create items for one index is
+        applied as one batch per shard (`_index_ops`); a delete, an
+        update or another index ends the run, so a write and a later
+        delete of the same id keep their order. Items answer in request
+        order, each with its own status or error."""
         started = time.monotonic()
         items = []
         errors = False
         touched: set[str] = set()
         batched: set[str] = set()
+
+        def enter_meta_batch(idx: str) -> None:
+            if idx not in batched and idx in self.indices:
+                batched.add(idx)
+                meta_batches.enter_context(
+                    self.indices[idx].batched_meta_saves())
+
+        def index_run(idx: str, run: list[tuple[str, dict]]) -> None:
+            nonlocal errors
+            results: list = [None] * len(run)
+            ops, slots = [], []
+            try:
+                svc = self._ensure_index(idx)
+                enter_meta_batch(idx)
+                context = self._write_context(svc)
+                for i, (action, p) in enumerate(run):
+                    try:
+                        ops.append(self._index_op(
+                            svc, context, p.get("_id"), p["doc"],
+                            p.get("_routing"), p.get("_type"),
+                            action == "create"))
+                        slots.append(i)
+                    except ElasticsearchTpuError as e:
+                        results[i] = e
+                for i, r in zip(slots, self._index_ops(svc, ops)):
+                    results[i] = r
+            except PowerLossError:
+                raise       # the process died: no item is answered
+            except ElasticsearchTpuError as e:
+                results = [e] * len(run)
+            for (action, _p), r in zip(run, results):
+                if type(r) is dict:
+                    touched.add(idx)
+                    r["status"] = 201 if r["created"] else 200
+                    items.append({action: r})
+                else:
+                    errors = True
+                    items.append({action: {"error": r.to_dict(),
+                                           "status": r.status}})
+
         # one metadata write-through per index per bulk REQUEST (at the
         # stack's exit, before the response acks anything), not per item
         with contextlib.ExitStack() as meta_batches:
-            for action, payload in operations:
+            at, n = 0, len(operations)
+            while at < n:
+                action, payload = operations[at]
+                if action in ("index", "create"):
+                    idx = payload["_index"]
+                    end = at + 1
+                    while end < n and operations[end][0] in (
+                            "index", "create") \
+                            and operations[end][1]["_index"] == idx:
+                        end += 1
+                    index_run(idx, operations[at:end])
+                    at = end
+                    continue
+                at += 1
                 try:
                     idx = payload["_index"]
-                    if idx not in batched and idx in self.indices:
-                        batched.add(idx)
-                        meta_batches.enter_context(
-                            self.indices[idx].batched_meta_saves())
+                    enter_meta_batch(idx)
                     typ = payload.get("_type")
-                    if action in ("index", "create"):
-                        r = self.index_doc(idx, payload.get("_id"), payload["doc"],
-                                           routing=payload.get("_routing"),
-                                           doc_type=typ)
-                        touched.add(idx)
-                        items.append({action: {**r, "status": 201 if r.get("created")
-                                               else 200}})
-                    elif action == "delete":
+                    if action == "delete":
                         r = self.delete_doc(idx, payload["_id"], doc_type=typ,
                                             routing=payload.get("_routing"))
                         touched.add(idx)
@@ -2584,10 +2679,17 @@ class Node:
             # block (index/durability.py): salvage/containment events
             # a chaos run asserts on — and a clean recovery asserts
             # are ZERO (the "durability" key shadows a same-named
-            # index here; accepted, the stats API still serves it)
+            # index here; accepted, the stats API still serves it).
+            # "indexing", likewise node-wide: writes, and how many of
+            # them reached their shard in a batch of two or more
             "indices": {**{name: svc.stats()
                            for name, svc in self.indices.items()},
-                        "durability": _durability_snapshot()},
+                        "durability": _durability_snapshot(),
+                        "indexing": {
+                            key: sum(getattr(svc.op_stats, key)
+                                     for svc in self.indices.values())
+                            for key in ("index_total", "bulk_batches",
+                                        "bulk_batch_docs")}},
             "os": monitor.os_stats(),
             "process": monitor.process_stats(),
             "jvm": monitor.runtime_stats(),   # python runtime, jvm-shaped
@@ -2697,6 +2799,9 @@ class Node:
                     "delete_current": 0,
                     "noop_update_total":
                         sum(o.noop_update_total for o in ops),
+                    "bulk_batches": sum(o.bulk_batches for o in ops),
+                    "bulk_batch_docs":
+                        sum(o.bulk_batch_docs for o in ops),
                     "build_total": sum(o.build_total for o in ops),
                     "build_time_in_millis": build_ms,
                     "build_docs": build_docs,

@@ -25,6 +25,26 @@ from ..utils.errors import (ElasticsearchTpuError, IllegalArgumentError,
                             IndexNotFoundError)
 from .. import __version__
 
+_scan_json = json.JSONDecoder().scan_once
+
+
+def _ndjson(text: str) -> list:
+    """`json.loads` of every non-blank line. A `_bulk` body is ten
+    thousand of them, so a line goes straight to the decoder's scanner;
+    one that the scanner does not take whole (surrounding whitespace,
+    trailing data, no JSON at all) goes to `json.loads`, for its result
+    or its error."""
+    out = []
+    for line in text.splitlines():
+        if not line.strip():
+            continue
+        try:
+            obj, end = _scan_json(line, 0)
+        except StopIteration:
+            end = -1
+        out.append(obj if end == len(line) else json.loads(line))
+    return out
+
 
 class Route:
     def __init__(self, method: str, pattern: str, handler):
@@ -1119,20 +1139,11 @@ def register_routes(d: RestDispatcher) -> None:
     def index_doc(node, params, body, index, id, doc_type=None):
         version = params.get("version")
         vt = params.get("version_type", "internal")
-        if params.get("op_type") == "create":
-            # op_type=create fails on ANY existing doc, independent of
-            # version type (ref: TransportIndexAction autogenerate/
-            # create → DocumentAlreadyExistsException)
-            from ..utils.errors import VersionConflictError
-            exists = True
-            try:
-                node.get_doc(index, id,
-                             routing=params.get("routing")
-                             or params.get("parent"))
-            except ElasticsearchTpuError:
-                exists = False
-            if exists:
-                raise VersionConflictError(index, id, -1, -1)
+        # op_type=create fails on ANY existing doc, independent of
+        # version type: the engine decides, under its lock (ref:
+        # TransportIndexAction autogenerate/create →
+        # DocumentAlreadyExistsException)
+        op_type = "create" if params.get("op_type") == "create" else "index"
         return node.index_doc(index, id, body or {},
                               version=int(version) if version else None,
                               routing=params.get("routing"),
@@ -1141,7 +1152,8 @@ def register_routes(d: RestDispatcher) -> None:
                               doc_type=doc_type,
                               version_type=vt,
                               parent=params.get("parent"),
-                              timestamp=params.get("timestamp"))
+                              timestamp=params.get("timestamp"),
+                              op_type=op_type)
 
     @d.route("GET", "/{index}/_doc/{id}")
     def get_doc(node, params, body, index, id, doc_type=None):
@@ -2257,9 +2269,7 @@ class RestServer:
                         # count — a one-action _bulk body is still ndjson
                         if req_path.rstrip("/").endswith(
                                 ("_bulk", "_msearch", "_mpercolate")):
-                            body = [json.loads(line)
-                                    for line in raw.decode("utf-8")
-                                    .splitlines() if line.strip()]
+                            body = _ndjson(raw.decode("utf-8"))
                         else:
                             # content negotiation: JSON/YAML/CBOR bodies
                             # (ref: common/xcontent/XContentFactory)
