@@ -1477,13 +1477,21 @@ class Node:
         if failures:
             self.metrics.counter("search.shard_failures_total").inc(
                 len(failures))
-        out = merge_shard_results(responses, agg_specs, partials,
-                                  frm=frm, size=size, descending=descending,
-                                  score_sort=score_sort,
-                                  multi_orders=multi_orders,
-                                  total_shards=(len(st.get("entries", ()))
-                                                + len(prefailed)),
-                                  failures=failures, timed_out=timed_out)
+        # the coordinator's merge proper, apart from the rest of
+        # `reduce` (the jobs' results, the request-cache puts): an
+        # always-on timer beside `phases` and a span for the viewer
+        stats = self._dispatch.stats
+        stats.record_merge(responses)
+        with profiler.enclosing("request:merge", timer=stats.merge,
+                                **profiler.request_args(
+                                    [st.get("request")]),
+                                shards=len(responses)):
+            out = merge_shard_results(
+                responses, agg_specs, partials, frm=frm, size=size,
+                descending=descending, score_sort=score_sort,
+                multi_orders=multi_orders,
+                total_shards=len(st.get("entries", ())) + len(prefailed),
+                failures=failures, timed_out=timed_out)
         if suggest_specs:
             out["suggest"] = merge_suggests(suggest_parts, suggest_specs)
         return out, agg_specs
@@ -1574,7 +1582,10 @@ class Node:
             search_type = item[2] if len(item) > 2 else None
             t0 = time.monotonic()
             try:
-                st = self._search_submit(i, b, None, search_type, batch)
+                # an id of its own for each item: its spans name it, and
+                # the batch counts its searches by their ids
+                st = self._search_submit(i, b, None, search_type, batch,
+                                         profiler.next_request_id())
                 prepared.append((t0, None, st))
             except ElasticsearchTpuError as e:
                 prepared.append((t0, e, None))

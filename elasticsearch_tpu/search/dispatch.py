@@ -41,8 +41,26 @@ controller's AdaptiveWindow decides per drain from observed arrival
 rate and per-round merge depth: 0 for sequential traffic (a lone
 query never sleeps), up to a few ms under real concurrency.
 
+**What a search waits for here.** `scheduler_wait` is recorded once a
+SEARCH, by `DispatchBatch.dispatch()`: the time between its call and
+its return in which the leading thread served none of that batch's
+jobs (the coalescing window's sleep, the round in flight, other
+batches' reader groups, the round's end, the caller's wake-up). The
+time the leader did serve one of them is in the leaf phases `bind` /
+`dispatch` / `collect` / `unpack` / `fetch`, which weigh the searches a
+reader call serves, so phases and wait tile the search whether it is
+one shard job or a fan-out of five. A batch that holds several
+searches (`_msearch`) records the same wait for each of them: all of
+them waited from the one `dispatch()` call to its return. Searches of
+two batches coalesced into one reader call are each served by it:
+both batches' clocks stop.
+
 Stats surface under `nodes_stats()["dispatch"]` (lanes/window/tenant
-counters under `["dispatch"]["traffic"]`).
+counters under `["dispatch"]["traffic"]`): `searches` coordinated and
+the shard jobs run for them (`queries`), the `leader` timer (rounds led,
+the seconds some thread spent leading one, the reader groups served)
+and the node's `merge` timer stand beside `phases`, not inside it:
+they are no tiles of a search.
 """
 
 from __future__ import annotations
@@ -54,7 +72,7 @@ import time
 
 from ..utils import profiler
 from ..utils.errors import SearchTimeoutError
-from ..utils.metrics import CounterMetric, HighWaterMetric
+from ..utils.metrics import CounterMetric, HighWaterMetric, MeanMetric
 
 # thread-local mirror of the LAST msearch submit's (group_sizes,
 # dispatch_count, per-body fetch seconds) on the CURRENT thread — how
@@ -231,9 +249,20 @@ class DispatchStats:
 
     Granularity: `queries` and `coalesced_queries` count PER-SHARD query
     executions (one search against an S-shard index is S entries) —
-    the unit the scheduler actually batches and dispatches."""
+    the unit the scheduler actually batches and dispatches; `searches`
+    counts the searches those jobs were submitted for, so `queries`
+    over `searches` is the fan-out.
+
+    `leader` is fed once a round with the seconds its leading thread
+    spent in `_execute` (count = rounds led); one thread leads at a
+    time, so its sum cannot outrun the wall clock. `merge` is the
+    node's coordinator merge of one search's shard results
+    (Node._reduce_on_readers), with the shard results and hits that
+    went in. Neither is a tile of a search (a round serves many, the
+    merge lies inside `reduce`), so they stay out of `phases`."""
 
     def __init__(self):
+        self.searches = CounterMetric()
         self.queries = CounterMetric()
         self.coalesced_queries = CounterMetric()
         self.batches_dispatched = CounterMetric()
@@ -241,6 +270,11 @@ class DispatchStats:
         self._window_batches = CounterMetric()
         self._window_coalesced = CounterMetric()
         self._adopted_batches = CounterMetric()
+        self.leader = MeanMetric()
+        self.leader_groups = CounterMetric()
+        self.merge = MeanMetric()
+        self.merge_shard_results = CounterMetric()
+        self.merge_hits = CounterMetric()
         # traffic control plane (search/traffic.py) — set by the
         # scheduler when a node wires one in; snapshot() then reports
         # per-tenant admission counters, lane depths, the adaptive
@@ -261,6 +295,11 @@ class DispatchStats:
         elif n_batches > 1:
             self._adopted_batches.inc(n_batches - 1)
 
+    def record_merge(self, shard_responses: list[dict]) -> None:
+        self.merge_shard_results.inc(len(shard_responses))
+        self.merge_hits.inc(sum(len(r["hits"]["hits"])
+                                for r in shard_responses))
+
     def record_groups(self, group_sizes, dispatches: int) -> None:
         self.batches_dispatched.inc(dispatches)
         for sz in group_sizes:
@@ -274,6 +313,7 @@ class DispatchStats:
         wb = self._window_batches.count
         wc = self._window_coalesced.count
         snap = {
+            "searches": self.searches.count,
             "queries": self.queries.count,
             "coalesced_queries": self.coalesced_queries.count,
             "batches_dispatched": self.batches_dispatched.count,
@@ -300,6 +340,15 @@ class DispatchStats:
             # Process-wide, so read them as deltas
             "phases": profiler.phase_stats(),
             "launches": launch_counts(),
+            # what is no tile of a search: the rounds led (count), the
+            # seconds their leaders spent executing them and the reader
+            # groups they served; the coordinator's merges, with the
+            # shard results and hits that went in
+            "leader": {**self.leader.snapshot(),
+                       "groups": self.leader_groups.count},
+            "merge": {**self.merge.snapshot(),
+                      "shard_results": self.merge_shard_results.count,
+                      "hits": self.merge_hits.count},
         }
         if self.traffic is not None:
             snap["traffic"] = self.traffic.snapshot()
@@ -325,13 +374,14 @@ class _Job:
     (node._finish_on_readers) converts that into a failed-by-timeout
     shard on a `timed_out: true` response."""
 
-    __slots__ = ("reader", "body", "with_partials", "deadline", "request",
-                 "t_handed", "waited_s", "fetch_s", "_result", "_error",
-                 "_done")
+    __slots__ = ("batch", "reader", "body", "with_partials", "deadline",
+                 "request", "fetch_s", "_result", "_error", "_done")
 
-    def __init__(self, reader, body: dict, with_partials: bool,
-                 deadline: float | None = None,
+    def __init__(self, batch: "DispatchBatch", reader, body: dict,
+                 with_partials: bool, deadline: float | None = None,
                  request: int | None = None):
+        # whose wait clock stops while the leader serves this job
+        self.batch = batch
         self.reader = reader
         self.body = body
         self.with_partials = with_partials
@@ -339,13 +389,6 @@ class _Job:
         # the request's id (utils/profiler.next_request_id), named on
         # the phase spans of whatever dispatch serves this job
         self.request = request
-        # `scheduler_wait`, two hand-overs summed: DispatchBatch
-        # .dispatch() entry to where this job's group starts executing
-        # on whichever thread leads, and the group's end to dispatch()'s
-        # return on the caller's thread. `t_handed` is the perf_counter
-        # reading carried across each (None: not handed over)
-        self.t_handed: float | None = None
-        self.waited_s = 0.0
         # seconds the reader spent building this job's response
         self.fetch_s = 0.0
         self._result = None
@@ -373,29 +416,38 @@ class DispatchBatch:
         self.lane = lane
         self.jobs: list[_Job] = []
         self._done = threading.Event()
+        # `scheduler_wait`: the time inside dispatch() in which the
+        # leading thread serves none of this batch's jobs. `t_handed`
+        # is the perf_counter reading from which the batch is waiting
+        # (None: not inside dispatch()); DispatchScheduler._serving
+        # banks the wait so far where the leader takes up one of its
+        # reader groups and starts the clock again where it lets go
+        self.t_handed: float | None = None
+        self.waited_s = 0.0
 
     def submit(self, reader, body: dict, with_partials: bool = False,
                deadline: float | None = None,
                request: int | None = None) -> _Job:
-        job = _Job(reader, body, with_partials, deadline, request)
+        job = _Job(self, reader, body, with_partials, deadline, request)
         self.jobs.append(job)
         return job
 
     def dispatch(self) -> None:
         """Execute every submitted job; per-job errors are re-raised by
-        job.result(), never by dispatch() itself."""
+        job.result(), never by dispatch() itself. Records the batch's
+        `scheduler_wait` once for each search it holds (the jobs of one
+        search carry one request id; in-process callers' jobs, which
+        carry none, are one search)."""
         if not self.jobs:
             self._done.set()
             return
-        t = time.perf_counter()
-        for j in self.jobs:
-            j.t_handed = t
+        searches = len({j.request for j in self.jobs})
+        self._scheduler.stats.searches.inc(searches)
+        self.t_handed = time.perf_counter()
         self._scheduler.run(self)
-        t = time.perf_counter()
-        for j in self.jobs:
-            if j.t_handed is not None:
-                profiler.waited("scheduler_wait",
-                                j.waited_s + t - j.t_handed)
+        wait = self.waited_s + time.perf_counter() - self.t_handed
+        for _ in range(searches):
+            profiler.waited("scheduler_wait", wait)
 
 
 class DispatchScheduler:
@@ -539,9 +591,7 @@ class DispatchScheduler:
                 self._traffic.window.observe_round(len(round_))
             first = False
             try:
-                with profiler.enclosing("request:round",
-                                        batches=len(round_)):
-                    self._execute([j for b in round_ for j in b.jobs])
+                self._execute([j for b in round_ for j in b.jobs])
             finally:
                 for b in round_:
                     b._done.set()
@@ -552,7 +602,7 @@ class DispatchScheduler:
         """Deadline and request-id kwargs for a coalesced group's reader
         call — each left out when the group has none, so plain mock
         readers without the kwarg keep working. Grouping buckets
-        deadlines to 10 ms (see _execute), so members differ by less
+        deadlines to 10 ms (see _reader_groups), so members differ by less
         than a bucket; the LATEST wins — a cooperative timeout may fire
         a few ms late but must never fail a request before its own
         deadline."""
@@ -566,21 +616,24 @@ class DispatchScheduler:
     @staticmethod
     @contextlib.contextmanager
     def _serving(g: list[_Job]):
-        """The leading thread works for this group inside the block: its
-        jobs' wait (the coalescing window's sleep and the groups ahead
-        of it in the round included) ends here and begins again after
-        it, for the round's end and their caller's wake-up."""
+        """The leading thread works for this group inside the block, so
+        for every batch that has a job in it: their wait (the coalescing
+        window's sleep and other batches' groups ahead in the round
+        included) ends here and begins again after it, for the rest of
+        the round and their caller's wake-up. A batch with jobs in
+        several groups (a fan-out) stops its clock in each of them and
+        waits through none of its own."""
+        waiting = [b for b in {j.batch for j in g}
+                   if b.t_handed is not None]
         now = time.perf_counter()
-        for j in g:
-            if j.t_handed is not None:
-                j.waited_s += now - j.t_handed
+        for b in waiting:
+            b.waited_s += now - b.t_handed
         try:
             yield
         finally:
             now = time.perf_counter()
-            for j in g:
-                if j.t_handed is not None:
-                    j.t_handed = now
+            for b in waiting:
+                b.t_handed = now
 
     def _fail_or_isolate(self, g: list[_Job], e: Exception) -> None:
         """A group's shared execution failed: retry singly so
@@ -595,10 +648,11 @@ class DispatchScheduler:
         else:
             self._run_isolated(g)
 
-    def _execute(self, jobs: list[_Job]) -> None:
-        self.stats.queries.inc(len(jobs))
+    @staticmethod
+    def _reader_groups(jobs: list[_Job]) -> list[list[_Job]]:
+        """The round's jobs by the reader call that will serve them, in
+        order of first arrival."""
         groups: dict[tuple, list[_Job]] = {}
-        order: list[tuple] = []
         for j in jobs:
             # deadlines bucket at 10 ms rather than keying raw floats:
             # msearch items sharing one `timeout` compute deadlines
@@ -608,23 +662,33 @@ class DispatchScheduler:
             # (100ms vs 10s) still split, as they must.
             dkey = (None if j.deadline is None
                     else int(j.deadline * 100))
-            key = (id(j.reader), j.with_partials, dkey)
-            g = groups.get(key)
-            if g is None:
-                groups[key] = g = []
-                order.append(key)
-            g.append(j)
-        if len(order) == 1:
+            groups.setdefault((id(j.reader), j.with_partials, dkey),
+                              []).append(j)
+        return list(groups.values())
+
+    def _execute(self, jobs: list[_Job]) -> None:
+        """One round on the leading thread: the `leader` timer and the
+        `request:round` span, around every reader group's service."""
+        self.stats.queries.inc(len(jobs))
+        groups = self._reader_groups(jobs)
+        self.stats.leader_groups.inc(len(groups))
+        with profiler.enclosing("request:round", timer=self.stats.leader,
+                                batches=len({j.batch for j in jobs}),
+                                groups=len(groups)):
+            self._serve_groups(jobs, groups)
+
+    def _serve_groups(self, jobs: list[_Job],
+                      groups: list[list[_Job]]) -> None:
+        if len(groups) == 1:
             # single target: the plain synchronous reader path (same
             # signature-grouped batching inside, nothing to pipeline)
-            self._run_sync(groups[order[0]])
+            self._run_sync(groups[0])
             return
         # pipelined: enqueue EVERY group's device programs back-to-back
         # through the reader's non-syncing submit, then collect in
         # submission order — round trips overlap instead of serializing
         pendings = []
-        for key in order:
-            g = groups[key]
+        for g in groups:
             if not hasattr(g[0].reader, "msearch_submit"):
                 # reader without a split entry (plain mock / legacy):
                 # sync per-group, still batched within the reader — and

@@ -102,6 +102,12 @@ class MeanMetric:
         with self._lock:
             return self._sum / self._count if self._count else 0.0
 
+    def snapshot(self) -> dict:
+        """{"count", "sum", "mean"} of one inc() generation."""
+        with self._lock:
+            return {"count": self._count, "sum": self._sum,
+                    "mean": self._sum / self._count if self._count else 0.0}
+
 
 class EWMA:
     """Exponentially-weighted moving average. Ref: common/metrics/EWMA.java.
@@ -232,7 +238,7 @@ class MetricsRegistry:
             if isinstance(m, CounterMetric):
                 out[name] = m.count
             elif isinstance(m, MeanMetric):
-                out[name] = {"count": m.count, "sum": m.sum, "mean": m.mean}
+                out[name] = m.snapshot()
             elif isinstance(m, MeterMetric):
                 out[name] = {"count": m.count, "mean_rate": m.mean_rate,
                              "one_minute_rate": m.one_minute_rate}

@@ -14,7 +14,9 @@ the phase times without a trace; while a trace is active the block is
 also a span of that trace carrying `args` (`request=<id>`, or
 `requests="<id>|<id>"` and `n=` where one dispatch serves several
 searches). `waited(name, seconds)` feeds the same registry and opens
-no span: for time in which no thread works for the request.
+no span: for time in which no thread works for the request. The phases
+and waits tile a search's `request`, whether it is one shard job or a
+fan-out of five, alone on its reader calls or sharing them.
 
 Spans are LEAVES: no `query_phase:` span may lie inside another on its
 thread (the benchmark's trace reduction gives each device gap to the
@@ -26,7 +28,6 @@ body runs only while tracing (graftlint's trace-purity pass flags it).
 
 from __future__ import annotations
 
-import contextlib
 import itertools
 import threading
 import time
@@ -204,14 +205,36 @@ def waited(name: str, seconds: float) -> None:
     _phases.mean(name).inc(seconds)
 
 
-def enclosing(name: str, **args):
+class enclosing:
     """A span around other spans, for a person at a trace viewer
-    (`request:search`, `request:round`): `name` lies outside the
-    `query_phase:` prefix, so the trace reduction drops it, and it
-    feeds no timer. A no-op while no trace is active."""
-    if _active_dir is None:
-        return contextlib.nullcontext()
-    return _span(name, args)
+    (`request:search`, `request:round`, `request:merge`): `name` lies
+    outside the `query_phase:` prefix, so the trace reduction drops it,
+    and it feeds no phase timer: what it covers is no tile of a search
+    (the leaves inside or around it are). `timer`, where given, is the
+    owner's MeanMetric and always gets the block's seconds once (the
+    scheduler's `leader`, the node's `merge`: shown beside `phases`,
+    never inside it). With no trace active and no timer, two clock
+    readings."""
+
+    __slots__ = ("_span", "_timer", "_t0")
+
+    def __init__(self, name: str, timer=None, **args):
+        self._span = _span(name, args) if _active_dir is not None else None
+        self._timer = timer
+        self._t0 = 0.0
+
+    def __enter__(self) -> "enclosing":
+        if self._span is not None:
+            self._span.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        seconds = time.perf_counter() - self._t0
+        if self._span is not None:
+            self._span.__exit__(None, None, None)
+        if self._timer is not None:
+            self._timer.inc(seconds)
 
 
 def phase_stats() -> dict:

@@ -250,6 +250,20 @@ def test_no_phase_span_encloses_another_on_its_thread(traced_search):
     assert phases == len(SPANS) + 2
 
 
+def test_the_enclosing_spans_say_what_they_enclosed(traced_search):
+    """`request:round` names the batches and reader groups it served,
+    `request:merge` the search and the shard results it merged; both lie
+    outside the `query_phase:` prefix."""
+    spans = [s for line in traced_search.values() for s in line]
+    rid = next(s for s in spans if s[0] == "request:search")[3]["request"]
+    assert [s[3] for s in spans if s[0] == "request:round"] \
+        == [{"batches": 1, "groups": 1}]
+    merges = [s for s in spans if s[0] == "request:merge"]
+    assert [s[3] for s in merges] == [{"request": rid, "shards": 1}]
+    reduce_ = next(s for s in spans if s[0] == "query_phase:reduce")
+    assert reduce_[1] <= merges[0][1] and merges[0][2] <= reduce_[2]
+
+
 def test_profiler_refuses_a_second_start_and_a_second_stop(tmp_path):
     from elasticsearch_tpu.utils.errors import IllegalArgumentError
     trace_dir = str(tmp_path / "trace")
