@@ -308,7 +308,7 @@ class DispatchStats:
 
     def snapshot(self) -> dict:
         from ..utils import race_guard, trace_guard
-        from .executor import launch_counts
+        from .executor import collect_counts, collect_lead, launch_counts
         from .resident import resident_stats
         wb = self._window_batches.count
         wc = self._window_coalesced.count
@@ -340,6 +340,11 @@ class DispatchStats:
             # Process-wide, so read them as deltas
             "phases": profiler.phase_stats(),
             "launches": launch_counts(),
+            # collects, those whose launch had started the copy to the
+            # host, and the host's seconds between a launch's return
+            # and its collect (what the copy could hide behind)
+            "collects": collect_counts(),
+            "collect_lead": collect_lead(),
             # what is no tile of a search: the rounds led (count), the
             # seconds their leaders spent executing them and the reader
             # groups they served; the coordinator's merges, with the

@@ -55,7 +55,8 @@ from ..ops.topk import top_k_hits, top_k_by_field
 from ..ops import aggs as agg_ops
 from ..utils.errors import (QueryParsingError, SearchParseError,
                             SearchTimeoutError)
-from ..utils.metrics import MetricsRegistry as _MetricsRegistry
+from ..utils.metrics import (MeanMetric as _MeanMetric,
+                             MetricsRegistry as _MetricsRegistry)
 from ..utils.profiler import phase as _phase
 from ..utils import trace_guard as _trace_guard
 from . import resident as _resident
@@ -2656,6 +2657,41 @@ def launch_counts() -> dict:
     return _launches.snapshot()
 
 
+# collects, and those whose launch had already asked for the result's
+# device-to-host copy (`_start_fetch`); and the host's seconds between
+# a launch's return and the start of its collect, which is what that
+# copy could hide behind. Process-wide, read as deltas through
+# `_nodes/stats/dispatch` -> "collects", "collect_lead" (beside
+# "launches", never inside "phases": neither is a tile of a search)
+_collects = _MetricsRegistry()
+for _name in ("total", "prefetched"):
+    _collects.counter(_name)
+_collect_lead = _MeanMetric()
+
+
+def collect_counts() -> dict:
+    return _collects.snapshot()
+
+
+def collect_lead() -> dict:
+    return _collect_lead.snapshot()
+
+
+def _start_fetch(buf) -> dict:
+    """Ask for a launched program's result on the host now, so that the
+    copy runs behind whatever the launching thread does next (the next
+    shard's bind and launch, the previous shard's unpack and fetch) and
+    `_collect`'s device_get finds the bytes there or in flight. Called
+    right after the program call, inside its `_launch` block. Returns
+    the per-launch fields of the layout that `_collect` counts by."""
+    try:
+        buf.copy_to_host_async()
+        prefetched = True
+    except (AttributeError, RuntimeError):
+        prefetched = False
+    return {"_prefetched": prefetched, "_launched": _time.perf_counter()}
+
+
 def _span_args(bind) -> dict:
     """What a phase of this dispatch takes from the reader's `bind`
     phase (None for callers that bring none): its weight, the searches
@@ -4713,10 +4749,7 @@ def _execute_resident(segment: Segment, live, desc: tuple, params: tuple,
         # -- fetch stage: start the device->host copy now so it overlaps
         # with whatever executes next; collect's device_get then finds
         # the bytes already in flight
-        try:
-            buf.copy_to_host_async()
-        except (AttributeError, RuntimeError):
-            pass
+        fetch = _start_fetch(buf)
     except BaseException:
         req_hold.release()
         raise
@@ -4725,7 +4758,7 @@ def _execute_resident(segment: Segment, live, desc: tuple, params: tuple,
     # the request-breaker hold is attached (with its GC backstop)
     # BEFORE any further accounting can raise — no exit may leak the
     # out_bytes reservation (PR 4's invariant)
-    layout = {**layout, "resident": True, "shard_key": shard_key,
+    layout = {**layout, **fetch, "resident": True, "shard_key": shard_key,
               "_breaker_hold": _gc_backstop(buf, req_hold),
               "_span_args": _span_args(bind)}
     # residency-bytes accounting (fielddata breaker, held until the
@@ -4776,7 +4809,13 @@ def execute_segment_async(segment: Segment, live: np.ndarray,
 
     `bind` is the caller's open `bind` phase (utils/profiler.phase),
     paused here around the launch; its arguments name the requests on
-    this dispatch's spans."""
+    this dispatch's spans.
+
+    The result's device-to-host copy is asked for at the launch, inside
+    the `dispatch` phase (`_start_fetch`), so a caller that launches
+    several programs before it collects the first (a fan-out round, an
+    `_msearch` batch, a reader of several segments) finds the bytes on
+    the host; `collect` then times what is left of the copy."""
     n_real = len(bounds)
     if n_real == 0:
         raise ValueError("execute_segment requires at least one bound query")
@@ -4930,6 +4969,7 @@ def execute_segment_async(segment: Segment, live: np.ndarray,
                 pack_static=pack_static,
                 desc=desc, agg_desc=agg_desc, cap=segment.capacity,
                 k=k_eff, sort_spec=sort_spec, fused=fused)
+            fetch = _start_fetch(buf)
     except BaseException:
         req_hold.release()
         raise
@@ -4938,19 +4978,32 @@ def execute_segment_async(segment: Segment, live: np.ndarray,
     out_bytes = min(est, int(getattr(buf, "nbytes", 0)) or est)
     req_hold.shrink(out_bytes)
     # layout dicts are cached/shared across calls — attach the per-call
-    # hold to a shallow copy
-    layout = {**layout, "_breaker_hold": _gc_backstop(buf, req_hold),
+    # hold and the launch's own fields to a shallow copy
+    layout = {**layout, **fetch,
+              "_breaker_hold": _gc_backstop(buf, req_hold),
               "_span_args": _span_args(bind)}
     return buf, layout, n_real
 
 
 def _collect(out, layout, leaf):
-    """The `collect` phase (device time + D2H + the runtime's wake-up),
-    after which `leaf` is the `unpack` phase. That begins by releasing
-    the breaker hold: the transient device accumulators are dead once
-    the result is on host — release NOW instead of waiting for GC.
-    Released on the error exit too (a failed device_get must not pin
-    breaker bytes until collection of the GC backstop)."""
+    """The `collect` phase, after which `leaf` is the `unpack` phase.
+    The launch asked for the device-to-host copy (`_start_fetch`), so
+    the phase times what is left of device time, copy and the runtime's
+    wake-up when the launching thread gets here: the whole round trip
+    only where the collect follows its launch at once. Counted in
+    `collects` (`prefetched` where the layout says the launch started
+    the copy) and, by the host's time since the launch returned, in
+    `collect_lead`. `unpack` begins by releasing the breaker hold: the
+    transient device accumulators are dead once the result is on host —
+    release NOW instead of waiting for GC. Released on the error exit
+    too (a failed device_get must not pin breaker bytes until
+    collection of the GC backstop)."""
+    _collects.counter("total").inc()
+    if layout.get("_prefetched"):
+        _collects.counter("prefetched").inc()
+    launched = layout.get("_launched")
+    if launched is not None:
+        _collect_lead.inc(_time.perf_counter() - launched)
     try:
         with _trace_guard.trap():
             return jax.device_get(out)
@@ -5648,13 +5701,15 @@ def execute_pack_async(base: Segment, delta: Segment, live_b: np.ndarray,
                 views_b, views_d, pack_static=pack_static, desc=desc,
                 agg_desc=agg_desc, cap_b=cap_b, cap_d=cap_d, k=k_eff,
                 fused=fused)
+            fetch = _start_fetch(buf)
     except BaseException:
         req_hold.release()
         raise
     est = b_pad * row_elems * 8
     out_bytes = min(est, int(getattr(buf, "nbytes", 0)) or est)
     req_hold.shrink(out_bytes)
-    layout = {**layout, "_breaker_hold": _gc_backstop(buf, req_hold),
+    layout = {**layout, **fetch,
+              "_breaker_hold": _gc_backstop(buf, req_hold),
               "_span_args": _span_args(bind)}
     return buf, layout, n_real
 
@@ -5783,16 +5838,13 @@ def _execute_pack_resident(base: Segment, delta: Segment, live_b, live_d,
                                  live_dev_d, views_b, views_d, step_arr)
         _resident.stats.staged_feed_overlap_ms.record(
             (_time.perf_counter() - t_stage) * 1000.0)
-        try:
-            buf.copy_to_host_async()
-        except (AttributeError, RuntimeError):
-            pass
+        fetch = _start_fetch(buf)
     except BaseException:
         req_hold.release()
         raise
     out_bytes = min(est, int(getattr(buf, "nbytes", 0)) or est)
     req_hold.shrink(out_bytes)
-    layout = {**layout, "resident": True, "shard_key": shard_key,
+    layout = {**layout, **fetch, "resident": True, "shard_key": shard_key,
               "_breaker_hold": _gc_backstop(buf, req_hold),
               "_span_args": _span_args(bind)}
     code_bytes = 0
