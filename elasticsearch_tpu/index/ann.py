@@ -111,9 +111,6 @@ def min_docs() -> int:
 
 
 def declared_recall() -> float:
-    env = os.environ.get("ES_TPU_ANN_RECALL")
-    if env is not None:
-        return float(env)
     with _cfg_lock:
         return _cfg_recall if _cfg_recall is not None else DEFAULT_RECALL
 
@@ -128,9 +125,6 @@ def default_nprobe(n_clusters: int, recall: float | None = None) -> int:
     cluster-bound threshold prune then skips most probed clusters
     without scoring them, which is why over-probing is cheap.
     """
-    env = os.environ.get("ES_TPU_ANN_NPROBE")
-    if env is not None:
-        return max(1, next_pow2(int(env), floor=1))
     with _cfg_lock:
         cfg = _cfg_nprobe
     if cfg is not None:

@@ -30,7 +30,7 @@ maps to two dense-tensor formulations here, each with a fused kernel:
 
 The jnp implementations in ops/scoring.py remain the reference
 semantics (and the CPU path); tests run these kernels in interpret mode
-against them, and bench.py A/Bs them on the real chip.
+against them.
 """
 
 from __future__ import annotations

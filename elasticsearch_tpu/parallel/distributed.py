@@ -895,14 +895,10 @@ class DistributedSearcher:
         one device program per group — the mesh analog of the host
         path's signature grouping in shard_searcher.msearch. Each body
         keeps its OWN aggregations. (with_partials is accepted for
-        scheduler interface parity — the sync and isolated-retry paths
-        of search/dispatch.py call reader.msearch(bodies, wp) — and is
+        scheduler interface parity — the isolated retry of
+        search/dispatch.py calls reader.msearch(bodies, wp) — and is
         ignored: mesh responses are always complete.)"""
-        pend = self.msearch_submit(bodies, deadline=deadline)
-        out = pend.finish()
-        from ..search.dispatch import note_submit_stats
-        note_submit_stats(pend.group_sizes, pend.dispatch_count)
-        return out
+        return self.msearch_submit(bodies, deadline=deadline).finish()
 
     def msearch_submit(self, bodies: list[dict],
                        with_partials: bool = False,

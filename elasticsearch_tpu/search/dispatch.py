@@ -1,11 +1,7 @@
 """Search dispatch scheduler: cross-request coalescing + pipelining.
 
-Every device dispatch costs a flat per-dispatch round trip (bench.py's
-`dispatch_round_trip_ms`; not measured on this round's code), which can
-dominate single-query latency while the batched per-query cost is
-sub-millisecond. This scheduler closes the unbatched-traffic gap two
-ways, one layer ABOVE the per-reader signature batching the executor
-already does:
+Two things, one layer ABOVE the per-reader signature batching the
+executor already does:
 
 * **coalescing** — concurrent searches whose plans finalize to the same
   (desc, agg_desc, sort_spec, k, segment) group into ONE batched device
@@ -21,6 +17,18 @@ Callers build a `DispatchBatch`, submit (reader, body) jobs, and call
 `dispatch()`. Batches arriving while another batch executes queue up
 and are drained by the next leader (the adaptive zero-latency
 coalescing the per-reader MicroBatcher pioneered, now cross-reader).
+
+**What a reader is.** A round is served one way whatever its number of
+reader groups (`_serve_groups`), through one call:
+`reader.msearch_submit(bodies, with_partials, deadline=, requests=)`
+(each keyword left out when the group has none) enqueues the group's
+device programs without collecting and returns an object with
+`finish()` (collects, returns one response a body), `group_sizes`
+(bodies per coalesced signature group), `dispatch_count` (device
+programs enqueued) and, where it has them, `fetch_s` (seconds spent
+building each body's response). `ShardReader` and `DistributedSearcher`
+are the two readers. The isolated retry of a failed group calls
+`reader.msearch`, which is `msearch_submit(...).finish()`.
 
 **Priority lanes** (traffic control plane, search/traffic.py): every
 batch carries a lane (`interactive` / `msearch` / `scroll` / `bulk`)
@@ -73,19 +81,6 @@ import time
 from ..utils import profiler
 from ..utils.errors import SearchTimeoutError
 from ..utils.metrics import CounterMetric, HighWaterMetric, MeanMetric
-
-# thread-local mirror of the LAST msearch submit's (group_sizes,
-# dispatch_count, per-body fetch seconds) on the CURRENT thread — how
-# the scheduler's sync path (which calls the plain reader.msearch
-# wrapper, so monkeypatch-friendly test seams keep working) reads
-# coalescing stats without a shared mutable attribute on the reader. Writers: ShardReader.msearch and
-# DistributedSearcher.msearch, at the END of each call (so nested
-# auxiliary msearch calls inside response building do not win).
-submit_stats = threading.local()
-
-
-def note_submit_stats(group_sizes, dispatches: int, fetch_s=()) -> None:
-    submit_stats.value = (list(group_sizes), dispatches, list(fetch_s))
 
 
 class FailoverStats:
@@ -684,30 +679,18 @@ class DispatchScheduler:
 
     def _serve_groups(self, jobs: list[_Job],
                       groups: list[list[_Job]]) -> None:
-        if len(groups) == 1:
-            # single target: the plain synchronous reader path (same
-            # signature-grouped batching inside, nothing to pipeline)
-            self._run_sync(groups[0])
-            return
-        # pipelined: enqueue EVERY group's device programs back-to-back
-        # through the reader's non-syncing submit, then collect in
-        # submission order — round trips overlap instead of serializing
+        # enqueue EVERY group's device programs back-to-back through
+        # the reader's non-syncing submit, then collect in submission
+        # order — round trips overlap instead of serializing
         pendings = []
         for g in groups:
-            if not hasattr(g[0].reader, "msearch_submit"):
-                # reader without a split entry (plain mock / legacy):
-                # sync per-group, still batched within the reader — and
-                # never let a missing interface masquerade as a parse
-                # error in the isolated fallback
-                self._run_sync(g)
-                continue
             with self._serving(g):
                 try:
                     pend = g[0].reader.msearch_submit(
                         [j.body for j in g], g[0].with_partials,
                         **self._call_kw(g))
-                except Exception:  # noqa: BLE001 — submit-time (parse)
-                    self._run_isolated(g)
+                except Exception as e:  # noqa: BLE001 — submit-time (parse)
+                    self._fail_or_isolate(g, e)
                     continue
             pendings.append((g, pend))
         # depth = device programs enqueued before the first collection —
@@ -734,31 +717,6 @@ class DispatchScheduler:
             if not j._done:
                 j._error = RuntimeError("dispatch job was not executed")
                 j._done = True
-
-    def _run_sync(self, g: list[_Job]) -> None:
-        reader = g[0].reader
-        submit_stats.value = None
-        with self._serving(g):
-            try:
-                rs = reader.msearch([j.body for j in g],
-                                    g[0].with_partials, **self._call_kw(g))
-            except Exception as e:  # noqa: BLE001
-                self._fail_or_isolate(g, e)
-                return
-            for j, r in zip(g, rs):
-                j._result = r
-                j._done = True
-        sub = getattr(submit_stats, "value", None)
-        if sub is not None:
-            # msearch_submit enqueued every group x segment program
-            # before its finish collected any — that WAS the in-flight
-            # depth, even through the sync wrapper
-            self.stats.pipeline_depth.record(sub[1])
-            self.stats.record_groups(sub[0], sub[1])
-            for j, fetch_s in zip(g, sub[2]):
-                j.fetch_s = fetch_s
-        else:
-            self.stats.pipeline_depth.record(1)
 
     def _run_isolated(self, g: list[_Job]) -> None:
         """Per-job fallback: each body runs alone so only the bad one

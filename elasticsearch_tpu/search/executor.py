@@ -3239,7 +3239,7 @@ def eval_fused_match(seg: dict, desc: tuple, params: tuple,
 # accumulators would exceed this executes as sequential lax.map chunks
 # inside ONE program — one device dispatch (each one pays a flat round
 # trip), bounded HBM transients
-_CHUNK_ELEMS = int(_os.environ.get("ES_TPU_CHUNK_ELEMS", str(1 << 27)))
+_CHUNK_ELEMS = 1 << 27
 
 
 def _chunk_b(B: int, cap: int) -> int:
@@ -4336,8 +4336,7 @@ def _pack_program_packed(seg_b: dict, seg_d: dict, wire,
 # host clock (deadline) and meters any injected straggler delay, so a
 # laggard step can exit within one chunk of the cutoff instead of
 # finishing its whole tile walk
-_RESIDENT_CHUNKS = max(1, int(_os.environ.get("ES_TPU_RESIDENT_CHUNKS",
-                                              "8")))
+_RESIDENT_CHUNKS = 8
 
 
 def _step_poll(hi, lo, delay_left, per_chunk, timed):

@@ -29,9 +29,8 @@ dispatch; the entry key carries the engine.
 
 Residency is opt-in via ``ES_TPU_RESIDENT_LOOP`` (unset => every
 response stays byte-identical to the cold path and all counters here
-read zero). ``search.resident.max_entries`` /
-``ES_TPU_RESIDENT_MAX_ENTRIES`` cap the pinned-entry LRU. Stats surface
-under ``nodes_stats()["dispatch"]["resident"]``.
+read zero). ``search.resident.max_entries`` caps the pinned-entry LRU.
+Stats surface under ``nodes_stats()["dispatch"]["resident"]``.
 """
 
 from __future__ import annotations
@@ -51,14 +50,6 @@ def enabled() -> bool:
     """Residency is an explicit opt-in: with the env unset the read
     path never touches this module's caches or counters."""
     return os.environ.get("ES_TPU_RESIDENT_LOOP", "").lower() in _TRUE
-
-
-def default_max_entries() -> int:
-    try:
-        return int(os.environ.get("ES_TPU_RESIDENT_MAX_ENTRIES",
-                                  str(DEFAULT_MAX_ENTRIES)))
-    except ValueError:
-        return DEFAULT_MAX_ENTRIES
 
 
 class ResidentStats:
@@ -166,7 +157,7 @@ class ResidentCache:
         # key -> ResidentEntry (LRU order)
         self._entries: dict = race_guard.guarded_dict(
             self._mx, "resident.ResidentCache._entries")
-        self.max_entries = max_entries or default_max_entries()
+        self.max_entries = max_entries or DEFAULT_MAX_ENTRIES
 
     def configure(self, max_entries: int) -> None:
         with self._mx:
@@ -321,7 +312,7 @@ def reset() -> None:
     the default entry cap."""
     global stats
     cache.clear()
-    cache.configure(default_max_entries())
+    cache.configure(DEFAULT_MAX_ENTRIES)
     # graftlint: ok(shared-state-race): test-only hook, called between
     # requests with no dispatch in flight; the rebind itself is atomic
     stats = ResidentStats()

@@ -22,7 +22,6 @@ from ..index.segment import Segment
 from ..utils import faults
 from ..utils.errors import SearchParseError, SearchTimeoutError
 from ..utils.profiler import phase, request_args
-from .dispatch import note_submit_stats
 from .query_dsl import QueryParser, Query
 from .executor import (QueryBinder, execute_segment, execute_segment_async,
                        execute_pack_async, collect_segment_result,
@@ -246,16 +245,8 @@ class ShardReader:
         with_partials=True attaches "_agg_partials" (keyed shard partials
         for the coordinator's cross-shard reduce) instead of finalized
         "aggregations" — the QUERY phase of a distributed search."""
-        pend = self.msearch_submit(bodies, with_partials,
-                                   deadline=deadline, requests=requests)
-        out = pend.finish()
-        # stamped AFTER finish(): auxiliary msearch calls inside it
-        # (derived aggs, rescore windows, sig_terms) wrote the same
-        # thread-local, so the outermost call wins — the dispatch
-        # scheduler's sync path reads the stats of the call it made
-        note_submit_stats(pend.group_sizes, pend.dispatch_count,
-                          pend.fetch_s)
-        return out
+        return self.msearch_submit(bodies, with_partials, deadline=deadline,
+                                   requests=requests).finish()
 
     def msearch_submit(self, bodies: list[dict],
                        with_partials: bool = False,

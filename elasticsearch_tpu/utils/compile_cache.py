@@ -18,8 +18,8 @@ DEFAULT_CACHE_DIR = os.path.join(_CHECKOUT, ".jax_cache")
 
 def configure_compile_cache() -> str:
     """Point JAX's persistent compile cache somewhere stable; returns
-    the directory in effect. Call before the first jit (Node.__init__,
-    chip_smoke.py and bench.py do). Idempotent.
+    the directory in effect. Call before the first jit (Node.__init__
+    and chip_smoke.py do). Idempotent.
 
     With JAX_COMPILATION_CACHE_DIR set, no directory is set in code —
     JAX reads the variable. Otherwise `<checkout>/.jax_cache`. Whether

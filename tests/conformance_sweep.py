@@ -99,8 +99,6 @@ def main() -> None:
     for s in clean_suites:
         print(f'    "{s}",')
     if json_path:
-        # the committed SWEEP_r{N}.json artifact is written HERE, whole,
-        # from the run that produced it — never hand-edited
         import json as _json
         payload = {
             "pass": npass, "fail": nfail, "skip": nskip,

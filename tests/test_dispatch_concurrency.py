@@ -32,13 +32,13 @@ def test_concurrent_queries_coalesce_and_agree(node, monkeypatch):
     import time
     from elasticsearch_tpu.search.shard_searcher import ShardReader
     calls = []
-    orig = ShardReader.msearch
+    orig = ShardReader.msearch_submit
 
-    def counting_msearch(self, bodies, with_partials=False, **kw):
+    def counting_submit(self, bodies, with_partials=False, **kw):
         calls.append(len(bodies))
         time.sleep(0.02)  # emulate device dispatch time: forces overlap
         return orig(self, bodies, with_partials, **kw)
-    monkeypatch.setattr(ShardReader, "msearch", counting_msearch)
+    monkeypatch.setattr(ShardReader, "msearch_submit", counting_submit)
 
     n_threads = 24
     results: list = [None] * n_threads
@@ -103,12 +103,12 @@ class TestSearchPoolRejection:
             n.thread_pool.pools["search"] = NamedPool("search", 1, 0)
             gate = threading.Event()
             from elasticsearch_tpu.search.shard_searcher import ShardReader
-            orig = ShardReader.msearch
+            orig = ShardReader.msearch_submit
 
             def slow(self, bodies, with_partials=False, **kw):
                 gate.wait(timeout=10)
                 return orig(self, bodies, with_partials, **kw)
-            ShardReader.msearch = slow
+            ShardReader.msearch_submit = slow
             try:
                 t = threading.Thread(
                     target=lambda: n.search("q", {"size": 0}))
@@ -120,7 +120,7 @@ class TestSearchPoolRejection:
                 assert ei.value.status == 429
             finally:
                 gate.set()
-                ShardReader.msearch = orig
+                ShardReader.msearch_submit = orig
                 t.join(timeout=10)
             assert n.thread_pool.pools["search"].stats()["rejected"] >= 1
         finally:

@@ -795,7 +795,7 @@ class TestExecutorBundleIdentity:
 
 
 class TestAutotunerTiming:
-    """Warmup + best-of-N timing (the BENCH_r05 mischoice fix) and the
+    """Warmup + best-of-N timing (an earlier round's mischoice fix) and the
     persisted choice store."""
 
     def _fresh_key(self, tag):
@@ -813,8 +813,8 @@ class TestAutotunerTiming:
             calls[backend] += 1
             if backend == "xla":
                 # first post-compile execution pays a one-time cost —
-                # the skew that made BENCH_r05 commit to pallas; steady
-                # state xla is the faster backend
+                # the skew that made an earlier round's run commit to
+                # pallas; steady state xla is the faster backend
                 _t.sleep(0.02 if calls["xla"] == 2 else 0.001)
             else:
                 _t.sleep(0.005)

@@ -19,6 +19,7 @@ Three layers:
 import gc
 import random
 import threading
+import types
 
 import numpy as np
 import pytest
@@ -295,8 +296,10 @@ class TestTrafficControllerStress:
         from elasticsearch_tpu.search.traffic import TrafficController
 
         class _Reader:
-            def msearch(self, bodies, with_partials=False, **kw):
-                return [{"ok": b["q"]} for b in bodies]
+            def msearch_submit(self, bodies, with_partials=False, **kw):
+                return types.SimpleNamespace(
+                    finish=lambda: [{"ok": b["q"]} for b in bodies],
+                    group_sizes=[len(bodies)], dispatch_count=1)
 
         sched = DispatchScheduler(traffic=TrafficController({}))
         reader = _Reader()

@@ -493,19 +493,3 @@ class TestMeshResidentReuse:
             assert resident.stats.resident_hits.count > hits_before
         finally:
             n.close()
-
-
-@pytest.mark.slow
-def test_bench_lone_query_smoke(resident_off, monkeypatch):
-    """bench.py lone_query scenario end-to-end at reduced scale:
-    identity gate + counters report (the <=0.6x latency gate only arms
-    past a measurable per-dispatch round trip)."""
-    monkeypatch.setenv("BENCH_DISPATCH_DOCS", "2000")
-    monkeypatch.setenv("BENCH_AGG_REPS", "6")
-    import importlib
-    import bench
-    importlib.reload(bench)
-    out = bench.bench_lone_query(0.0)
-    assert out["metric"] == "lone_query_p50_ms"
-    assert out["resident"]["resident_hits"] > 0
-    assert out["resident"]["entry_count"] > 0
