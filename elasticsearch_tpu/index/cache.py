@@ -49,10 +49,14 @@ from collections import OrderedDict
 import numpy as np
 
 
-def canonical_key(body: dict) -> str:
-    """Stable request identity (the reference hashes request bytes)."""
+def canonical_key(body: dict, strict: bool = False) -> str:
+    """Stable request identity (the reference hashes request bytes).
+    A value JSON has no form for goes in as its `str`, which may not
+    tell two of them apart (a long array prints abridged); `strict`
+    raises TypeError there instead, for a caller that keys more than a
+    result's reuse by it (search/bound_plans.py)."""
     return json.dumps(body, sort_keys=True, separators=(",", ":"),
-                      default=str)
+                      default=None if strict else str)
 
 
 def _estimate_bytes(obj) -> int:

@@ -339,6 +339,9 @@ class DocumentMapper:
         self._emitters: dict[str, object] = {}
         self._leaf_plans: dict[str, tuple] = {}
         self._plan_lock = threading.Lock()
+        # counts the mapping's changes (_mapping_changed): what a reader
+        # keeps of a parsed and bound body is keyed by it
+        self.version = 0
         self.parent_type: str | None = None
         self.routing_required = False
         self.ts_enabled = False
@@ -400,6 +403,7 @@ class DocumentMapper:
         with self._plan_lock:
             self._emitters.clear()
             self._leaf_plans.clear()
+            self.version += 1
 
     def _add_field(self, name: str, spec: dict) -> FieldMapper:
         if not isinstance(spec, dict):
@@ -986,6 +990,12 @@ class MapperService:
 
     def merge_mapping(self, mapping: dict) -> None:
         self.mapper.merge(mapping)
+
+    @property
+    def version(self) -> int:
+        """Moves with every change of the merged mapping: a merge
+        (PUT _mapping) and a field a document brought."""
+        return self.mapper.version
 
     def put_type_mapping(self, type_name: str, spec: dict) -> None:
         """Merge `spec` into the named type's view AND the engine's

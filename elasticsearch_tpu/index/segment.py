@@ -432,6 +432,25 @@ class Segment:
             return m
         return self.parent_of == -1
 
+    def device_changed(self, rebuilt: bool = False) -> None:
+        """Called by whatever writes to the uploaded column tree
+        (`_device`): a new tree (`rebuilt`) or leaves added to the one
+        that is there (the executor's ensure_* uploads). What was kept
+        from the tree's shape (a reader's bound plans:
+        search/bound_plans.py) is valid while `device_epoch` reads the
+        same; the epoch outlives drop_device, so a tree uploaded anew
+        never reads as the old one."""
+        built, added = getattr(self, "_device_epoch", (0, 0))
+        self._device_epoch = ((built + 1, 0) if rebuilt
+                              else (built, added + 1))
+
+    def device_epoch(self) -> tuple | None:
+        """(trees built, leaves added since) of the uploaded column
+        tree; None while nothing is uploaded."""
+        if getattr(self, "_device", None) is None:
+            return None
+        return getattr(self, "_device_epoch", None)
+
     def drop_device(self) -> None:
         """Drop every piece of HBM-resident device state derived from
         this segment — uploaded columns, the cached live-mask upload,
@@ -561,6 +580,7 @@ class Segment:
                 import jax.numpy as jnp
                 self._device = {**dev, "kw": {**dev["kw"],
                                               field: jnp.asarray(ords)}}
+                self.device_changed(rebuilt=True)
             return True
 
     def field_kind(self, name: str) -> str | None:

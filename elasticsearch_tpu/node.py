@@ -1333,6 +1333,10 @@ class Node:
         shard_body.pop("timeout", None)
         shard_body.pop("allow_partial_search_results", None)
         from .index.cache import cacheable, canonical_key
+        from .search.bound_plans import body_key
+        # made once for all shards of the search: what each reader keys
+        # its kept plans by
+        plan_key = body_key(shard_body)
         cache_key = None
         cache_by_index: dict[str, bool] = {}
         entries: list[tuple] = []
@@ -1349,7 +1353,7 @@ class Node:
             r = None
             if use_cache:
                 if cache_key is None:
-                    cache_key = canonical_key(shard_body)
+                    cache_key = plan_key or canonical_key(shard_body)
                 # generation-exact key (reader.generation_key inside
                 # the cache): a hit is a pure host-side copy — zero
                 # device dispatches/transfers/compiles — and is
@@ -1359,7 +1363,8 @@ class Node:
                 self.traffic.note_cache(hit=r is not None)
             if r is None:
                 job = batch.submit(reader, shard_body, with_partials=True,
-                                   deadline=deadline, request=request)
+                                   deadline=deadline, request=request,
+                                   key=plan_key)
                 entries.append(("job", svc if use_cache else None,
                                 reader, cache_key, job))
             else:

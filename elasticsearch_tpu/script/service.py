@@ -32,6 +32,11 @@ def parse_script_spec(spec) -> tuple[str, dict]:
         params.update(p2)
         return src, params
     src = spec.get("inline") or spec.get("source")
+    if src is None and ("id" in spec or "file" in spec):
+        # the registry changes under a reader: what was parsed from it
+        # is not kept with the body
+        from ..search.bound_plans import note_volatile
+        note_volatile()
     if src is None and "id" in spec:
         src = ScriptService.instance().get_stored(spec["id"])
     if src is None and "file" in spec:

@@ -20,8 +20,9 @@ coalescing the per-reader MicroBatcher pioneered, now cross-reader).
 
 **What a reader is.** A round is served one way whatever its number of
 reader groups (`_serve_groups`), through one call:
-`reader.msearch_submit(bodies, with_partials, deadline=, requests=)`
-(each keyword left out when the group has none) enqueues the group's
+`reader.msearch_submit(bodies, with_partials, deadline=, requests=,
+keys=)` (each keyword left out when the group has none) enqueues the
+group's
 device programs without collecting and returns an object with
 `finish()` (collects, returns one response a body), `group_sizes`
 (bodies per coalesced signature group), `dispatch_count` (device
@@ -303,6 +304,7 @@ class DispatchStats:
 
     def snapshot(self) -> dict:
         from ..utils import race_guard, trace_guard
+        from . import bound_plans
         from .executor import collect_counts, collect_lead, launch_counts
         from .resident import resident_stats
         wb = self._window_batches.count
@@ -340,6 +342,11 @@ class DispatchStats:
             # and its collect (what the copy could hide behind)
             "collects": collect_counts(),
             "collect_lead": collect_lead(),
+            # what the readers kept of the bodies they had served, once
+            # a reader call's group: launched from kept plans (hits),
+            # built and kept (misses), not kept (bypassed); evictions
+            # at a reader's capacity, entries live readers hold
+            "bound_plans": bound_plans.counts(),
             # what is no tile of a search: the rounds led (count), the
             # seconds their leaders spent executing them and the reader
             # groups they served; the coordinator's merges, with the
@@ -375,11 +382,11 @@ class _Job:
     shard on a `timed_out: true` response."""
 
     __slots__ = ("batch", "reader", "body", "with_partials", "deadline",
-                 "request", "fetch_s", "_result", "_error", "_done")
+                 "request", "key", "fetch_s", "_result", "_error", "_done")
 
     def __init__(self, batch: "DispatchBatch", reader, body: dict,
                  with_partials: bool, deadline: float | None = None,
-                 request: int | None = None):
+                 request: int | None = None, key: str | None = None):
         # whose wait clock stops while the leader serves this job
         self.batch = batch
         self.reader = reader
@@ -389,6 +396,9 @@ class _Job:
         # the request's id (utils/profiler.next_request_id), named on
         # the phase spans of whatever dispatch serves this job
         self.request = request
+        # the body's bound_plans.body_key where the caller made it once
+        # for all shards of a search (None: the reader makes its own)
+        self.key = key
         # seconds the reader spent building this job's response
         self.fetch_s = 0.0
         self._result = None
@@ -427,8 +437,10 @@ class DispatchBatch:
 
     def submit(self, reader, body: dict, with_partials: bool = False,
                deadline: float | None = None,
-               request: int | None = None) -> _Job:
-        job = _Job(self, reader, body, with_partials, deadline, request)
+               request: int | None = None,
+               key: str | None = None) -> _Job:
+        job = _Job(self, reader, body, with_partials, deadline, request,
+                   key)
         self.jobs.append(job)
         return job
 
@@ -599,8 +611,9 @@ class DispatchScheduler:
     # -- execution ---------------------------------------------------------
     @staticmethod
     def _call_kw(g: list[_Job]) -> dict:
-        """Deadline and request-id kwargs for a coalesced group's reader
-        call — each left out when the group has none, so plain mock
+        """Deadline, request-id and body-key kwargs for a coalesced
+        group's reader call — each left out when the group has none, so plain
+        mock
         readers without the kwarg keep working. Grouping buckets
         deadlines to 10 ms (see _reader_groups), so members differ by less
         than a bucket; the LATEST wins — a cooperative timeout may fire
@@ -611,6 +624,8 @@ class DispatchScheduler:
             kw["deadline"] = max(j.deadline for j in g)
         if any(j.request is not None for j in g):
             kw["requests"] = [j.request for j in g]
+        if any(j.key is not None for j in g):
+            kw["keys"] = [j.key for j in g]
         return kw
 
     @staticmethod

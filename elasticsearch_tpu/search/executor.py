@@ -60,6 +60,7 @@ from ..utils.metrics import (MeanMetric as _MeanMetric,
 from ..utils.profiler import phase as _phase
 from ..utils import trace_guard as _trace_guard
 from . import resident as _resident
+from .bound_plans import note_volatile as _note_volatile
 from .query_dsl import (
     Query, MatchAllQuery, MatchNoneQuery, TermQuery, RangeQuery, ExistsQuery,
     IdsQuery, PrefixQuery, WildcardQuery, FuzzyQuery, BoolQuery,
@@ -175,6 +176,7 @@ def device_arrays(segment: Segment) -> dict:
                 "is_child": jnp.asarray(segment.parent_of >= 0),
             }
         segment._device = dev  # type: ignore[attr-defined]
+        segment.device_changed(rebuilt=True)
     return dev
 
 
@@ -197,6 +199,7 @@ def ensure_kw_sorted(segment: Segment, field: str) -> None:
     _host_perms(segment)[("kw", field)] = perm
     dev.setdefault("kw_sorted", {})[field] = {
         "perm": jnp.asarray(perm), "starts": jnp.asarray(starts)}
+    segment.device_changed()
 
 
 def ensure_num_sorted(segment: Segment, field: str) -> None:
@@ -219,6 +222,7 @@ def ensure_num_sorted(segment: Segment, field: str) -> None:
         "perm": jnp.asarray(perm),
         "vals": jnp.asarray(vals[perm]),
         "sexists": jnp.asarray(nc.exists[perm])}
+    segment.device_changed()
 
 
 def ensure_num_tiles(segment: Segment, field: str) -> bool:
@@ -245,6 +249,7 @@ def ensure_num_tiles(segment: Segment, field: str) -> bool:
         return False
     entry["tile_lo"] = jnp.asarray(mm[0])
     entry["tile_hi"] = jnp.asarray(mm[1])
+    segment.device_changed()
     return True
 
 
@@ -261,6 +266,7 @@ def ensure_script_vals(segment: Segment, fields) -> None:
         if nc is not None and "script_vals" not in dev["num"][f]:
             dev["num"][f]["script_vals"] = \
                 jnp.asarray(nc.raw.astype(np.float32))
+            segment.device_changed()
 
 
 # ---------------------------------------------------------------------------
@@ -341,6 +347,10 @@ def ensure_agg_views(segment: Segment, bound: "Bound", agg_desc: tuple,
                 continue
             need_num = num_f | {f for _n, f, mk in subs
                                 if mk in ("avg", "sum", "value_count")}
+            if "vw_kw_mv" not in store:
+                # the three (empty) view dicts below are new nodes of
+                # the tree
+                segment.device_changed()
             vw_num = store.setdefault("vw_num", {})
             for f in need_num:
                 nc = segment.numerics.get(f)
@@ -352,6 +362,7 @@ def ensure_agg_views(segment: Segment, bound: "Bound", agg_desc: tuple,
                     col["mv_values"] = jnp.asarray(nc.mv_values[perm])
                     col["mv_exists"] = jnp.asarray(nc.mv_exists[perm])
                 vw_num[f] = col
+                segment.device_changed()
             vw_kw = store.setdefault("vw_kw", {})
             vw_kw_mv = store.setdefault("vw_kw_mv", {})
             for f in kw_f:
@@ -361,6 +372,7 @@ def ensure_agg_views(segment: Segment, bound: "Bound", agg_desc: tuple,
                 vw_kw[f] = jnp.asarray(kc.ords[perm])
                 if kc.mv_ords is not None:
                     vw_kw_mv[f] = jnp.asarray(kc.mv_ords[perm])
+                segment.device_changed()
 
 
 def _desc_view_ok(desc: tuple, store: dict, seg: dict) -> bool:
@@ -1283,6 +1295,7 @@ class QueryBinder:
         from ..script import compile_script
         from ..script.service import numeric_param
         cs = compile_script(q.script)  # validate (raises ScriptException)
+        _note_volatile()  # the bind uploads
         ensure_script_vals(self.seg, cs.fields)
         pnames = ",".join(n for n, _ in q.params)
         scalars = {"boost": q.boost}
@@ -1311,6 +1324,7 @@ class QueryBinder:
                 return parse_time_value(v) / 1000.0
             if v == "now" or v is None:
                 import time as _t
+                _note_volatile()
                 return float(_t.time())
             return parse_date_millis(v) / 1000.0
         try:
@@ -1322,6 +1336,7 @@ class QueryBinder:
                 return float(parse_time_value(v))
             if v == "now" or v is None:
                 import time as _t
+                _note_volatile()
                 return _t.time() * 1000.0
             return float(parse_date_millis(v))
 
@@ -1359,6 +1374,7 @@ class QueryBinder:
             from ..script import compile_script
             from ..script.service import numeric_param
             cs = compile_script(fn.script)
+            _note_volatile()  # the bind uploads
             ensure_script_vals(self.seg, cs.fields)
             pnames = ",".join(n for n, _ in fn.script_params)
             scalars = {"weight": fn.weight}
@@ -4779,6 +4795,110 @@ def _execute_resident(segment: Segment, live, desc: tuple, params: tuple,
     return buf, layout, n_real
 
 
+@dataclass(slots=True)
+class SegmentPlan:
+    """What `execute_segment_async` resolves before the launch of
+    `_segment_program_packed` from the bound queries, the group's
+    descriptors and the segment alone, kept by the reader
+    (search/bound_plans.py) so that the next search with the same
+    bodies launches from it: the descriptors, the packed wire
+    parameters as the device array the upload returned, the fused
+    decision as it was resolved (bundle and backend, or the reject
+    reason), the breaker estimate and the output layout. `epoch` is
+    the segment's `device_epoch` when the layout was made: the layout
+    and the program's branches follow the column tree's shape
+    (`segment_plan_valid`)."""
+
+    desc: tuple
+    agg_desc: tuple
+    sort_spec: tuple
+    pack_static: tuple
+    wire_dev: jax.Array
+    k_eff: int
+    fused: tuple | None
+    reject: str | None
+    pallas_reason: str | None
+    tune_key: tuple | None
+    switches: tuple | None
+    est: int
+    layout: dict
+    epoch: tuple | None
+
+
+def plan_switches() -> tuple:
+    """The process-wide gates every kept bind and plan was resolved
+    under (fused admission, positional clauses): part of the reader's
+    stamp, so that nothing resolved under other values is served. (The
+    resident loop, paged packs and the pack dispatch leave the path
+    that keeps plans and are checked where they branch; what only a
+    fused plan reads is `_fused_switches`.)"""
+    return fused_enabled(), _positional_enabled()
+
+
+def _fused_switches() -> tuple:
+    """What a fused plan's backend and kernel verdict were resolved
+    under: the kernel coverage and the forced backend."""
+    return (_pallas_coverage(),
+            _os.environ.get("ES_TPU_FUSED_BACKEND", "").lower())
+
+
+def segment_plan_valid(segment: Segment, plan: SegmentPlan) -> bool:
+    """May `plan` launch against the segment as it stands? Not once the
+    column tree has changed since the layout was made (an ensure_*
+    upload added leaves and switched the program's branches, or
+    drop_device forgot the tree), not on a pack that pages, and not
+    where a fused plan's switches have moved or the autotuner no longer
+    holds the backend the plan runs."""
+    if plan.epoch != segment.device_epoch() \
+            or _tiering.paged_fields(segment):
+        return False
+    if plan.fused is None:
+        return True
+    switches = _fused_switches()
+    # a forced backend outranks the tuner's choice
+    return plan.switches == switches and plan.fused[1] in (
+        switches[1], _autotune_choices.get(plan.tune_key))
+
+
+def _launch_plan(segment: Segment, live, plan: SegmentPlan, req_hold,
+                 bind, inputs: tuple | None = None):
+    """The launch of `_segment_program_packed` from a plan, under the
+    breaker hold `req_hold` (released here on any raise): everything
+    that is done per launch whatever the reader kept. The column tree,
+    the live mask and its views (`inputs`, where the caller has just
+    made them) are this launch's; the program call and the request for
+    the result's copy to the host are the `dispatch` phase."""
+    fused = plan.fused
+    try:
+        if inputs is None:
+            live_dev = _device_live(segment, live)
+            inputs = (device_arrays(segment), live_dev,
+                      _live_views_for(segment, live_dev, plan.agg_desc))
+        dev, live_dev, live_views = inputs
+        with _trace_guard.trap(), _launch(
+                bind, "unfused" if fused is None else "fused_" + fused[1]):
+            buf = _segment_program_packed(
+                dev, plan.wire_dev, live_dev, live_views,
+                pack_static=plan.pack_static,
+                desc=plan.desc, agg_desc=plan.agg_desc,
+                cap=segment.capacity, k=plan.k_eff,
+                sort_spec=plan.sort_spec, fused=fused)
+            fetch = _start_fetch(buf)
+    except BaseException:
+        req_hold.release()
+        raise
+    # program enqueued: downgrade the transient estimate to the queued
+    # OUTPUT buffer's footprint (held until collection or GC)
+    est = plan.est
+    req_hold.shrink(min(est, int(getattr(buf, "nbytes", 0)) or est))
+    # layout dicts are shared across calls — attach the per-call hold
+    # and the launch's own fields to a shallow copy
+    layout = {**plan.layout, **fetch,
+              "_breaker_hold": _gc_backstop(buf, req_hold),
+              "_span_args": _span_args(bind)}
+    return buf, layout
+
+
 def execute_segment_async(segment: Segment, live: np.ndarray,
                           bounds: Sequence[Bound], k: int,
                           agg_desc: tuple = (), agg_params: tuple = (),
@@ -4787,7 +4907,8 @@ def execute_segment_async(segment: Segment, live: np.ndarray,
                           deadline: float | None = None,
                           step_budget=None,
                           shard_key: tuple | None = None,
-                          bind=None):
+                          bind=None, plan: SegmentPlan | None = None,
+                          keep: list | None = None):
     """Dispatch one batched query against one segment WITHOUT syncing.
 
     Uses the packed wire format: 3 upload buffers, 1 download buffer —
@@ -4814,10 +4935,36 @@ def execute_segment_async(segment: Segment, live: np.ndarray,
     the `dispatch` phase (`_start_fetch`), so a caller that launches
     several programs before it collects the first (a fan-out round, an
     `_msearch` batch, a reader of several segments) finds the bytes on
-    the host; `collect` then times what is left of the copy."""
+    the host; `collect` then times what is left of the copy.
+
+    `plan` is what an earlier call with these bounds on this segment
+    put into `keep` (a `SegmentPlan`, which the caller has checked with
+    `segment_plan_valid`). With it the call skips `finalize`, the fused
+    admission, the packing and the upload of the wire parameters, the
+    autotuner's lookup and the output layout, and goes to what is done
+    per launch whatever was kept: the admission counters, the breaker
+    hold (taken for the kept estimate, shrunk to the output and given
+    its GC backstop), the live mask and its views, the launch with its
+    `launches` count and its `dispatch` phase, the copy request.
+    Without it, a call that ends in the cold launch of
+    `_segment_program_packed` appends its plan to `keep` (the paged
+    walk and the resident loop keep none)."""
     n_real = len(bounds)
     if n_real == 0:
         raise ValueError("execute_segment requires at least one bound query")
+    from ..utils.breaker import breaker_service
+    if plan is not None:
+        if plan.fused is not None:
+            _fused_stats.record_admit(
+                positional=_bundle_positional(plan.fused[0]))
+            if plan.pallas_reason is not None:
+                _fused_stats.record_pallas_reject(plan.pallas_reason)
+        else:
+            _fused_stats.record_reject(plan.reject)
+        buf, layout = _launch_plan(
+            segment, live, plan,
+            breaker_service().breaker("request").hold(plan.est), bind)
+        return buf, layout, n_real
     b_pad = next_pow2(n_real, floor=1)
     if b_pad != n_real:
         bounds = list(bounds) + [bounds[-1]] * (b_pad - n_real)
@@ -4893,14 +5040,17 @@ def execute_segment_async(segment: Segment, live: np.ndarray,
     # estimate is checked here and swapped for an output-buffer-sized
     # hold once the program is enqueued; holding full transients per
     # queued dispatch would spuriously trip on any async batch loop.
-    from ..utils.breaker import breaker_service
     req_breaker = breaker_service().breaker("request")
     # chunked bodies bound the transient to one chunk's worth
     row_elems = fused_width if fused is not None else segment.capacity
     est = _chunk_b(b_pad, row_elems) * row_elems * 8
     req_hold = req_breaker.hold(est)
+    tune_key = pallas_reason = None
     try:
         dev = device_arrays(segment)
+        # before the tree's shape is read: a leaf added meanwhile makes
+        # the kept plan stale, not wrong
+        epoch = segment.device_epoch()
         live_dev = _device_live(segment, live)
         live_views = _live_views_for(segment, live_dev, agg_desc)
         wire, pack_static = _pack_trees(params, agg_params, sort_params)
@@ -4961,26 +5111,17 @@ def execute_segment_async(segment: Segment, live: np.ndarray,
             dev, params, live_dev, live_views, agg_params, sort_params,
             desc, agg_desc, segment.capacity, k_eff, sort_spec,
             fused=fused)
-        with _trace_guard.trap(), _launch(
-                bind, "unfused" if fused is None else "fused_" + fused[1]):
-            buf = _segment_program_packed(
-                dev, wire_dev, live_dev, live_views,
-                pack_static=pack_static,
-                desc=desc, agg_desc=agg_desc, cap=segment.capacity,
-                k=k_eff, sort_spec=sort_spec, fused=fused)
-            fetch = _start_fetch(buf)
     except BaseException:
         req_hold.release()
         raise
-    # program enqueued: downgrade the transient estimate to the queued
-    # OUTPUT buffer's footprint (held until collection or GC)
-    out_bytes = min(est, int(getattr(buf, "nbytes", 0)) or est)
-    req_hold.shrink(out_bytes)
-    # layout dicts are cached/shared across calls — attach the per-call
-    # hold and the launch's own fields to a shallow copy
-    layout = {**layout, **fetch,
-              "_breaker_hold": _gc_backstop(buf, req_hold),
-              "_span_args": _span_args(bind)}
+    plan = SegmentPlan(desc, agg_desc, sort_spec, pack_static, wire_dev,
+                       k_eff, fused, reject, pallas_reason, tune_key,
+                       _fused_switches() if fused is not None else None,
+                       est, layout, epoch)
+    buf, layout = _launch_plan(segment, live, plan, req_hold, bind,
+                               (dev, live_dev, live_views))
+    if keep is not None:
+        keep.append(plan)
     return buf, layout, n_real
 
 
@@ -5221,6 +5362,7 @@ def ensure_fwd_cols(segment: Segment) -> None:
             hold.release()
             raise
         _gc_backstop(segment, hold)
+        segment.device_changed()
     _tiering.clear_paged(segment)
     _tiering.stats.unfused_full_uploads.inc()
 

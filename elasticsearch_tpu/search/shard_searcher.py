@@ -11,6 +11,7 @@ collection; cross-SHARD merging lives in search/controller.py.
 from __future__ import annotations
 
 import json
+import pickle
 import time
 from dataclasses import dataclass
 
@@ -23,9 +24,11 @@ from ..utils import faults
 from ..utils.errors import SearchParseError, SearchTimeoutError
 from ..utils.profiler import phase, request_args
 from .query_dsl import QueryParser, Query
+from . import bound_plans
 from .executor import (QueryBinder, execute_segment, execute_segment_async,
                        execute_pack_async, collect_segment_result,
-                       collect_pack_result)
+                       collect_pack_result, plan_switches,
+                       segment_plan_valid)
 from .aggregations import (parse_aggs, ShardAggContext, reduce_aggs,
                            shard_partials, AggSpec)
 from .highlight import parse_highlight, highlight_hit
@@ -180,6 +183,10 @@ class ShardReader:
         self.shard_id = shard_id
         self._global_ords: dict[str, tuple[list[str], list[np.ndarray]]] = {}
         self._generation_key: tuple | None = None
+        # what the submit made of the bodies this view has served
+        # (search/bound_plans.py); like the two above it lives and dies
+        # with the view
+        self._bound_plans = bound_plans.BoundPlans()
 
     def generation_key(self) -> tuple:
         """Content-exact generation of this point-in-time view — the
@@ -238,7 +245,8 @@ class ShardReader:
 
     def msearch(self, bodies: list[dict], with_partials: bool = False,
                 deadline: float | None = None,
-                requests: list | None = None) -> list[dict]:
+                requests: list | None = None,
+                keys: list | None = None) -> list[dict]:
         """Execute a batch of requests; structurally-identical requests are
         batched into one device program (leading dim B).
 
@@ -246,12 +254,13 @@ class ShardReader:
         for the coordinator's cross-shard reduce) instead of finalized
         "aggregations" — the QUERY phase of a distributed search."""
         return self.msearch_submit(bodies, with_partials, deadline=deadline,
-                                   requests=requests).finish()
+                                   requests=requests, keys=keys).finish()
 
     def msearch_submit(self, bodies: list[dict],
                        with_partials: bool = False,
                        deadline: float | None = None,
-                       requests: list | None = None) -> "_PendingMsearch":
+                       requests: list | None = None,
+                       keys: list | None = None) -> "_PendingMsearch":
         """Dispatch half of msearch: parse, group structurally-identical
         requests, and enqueue EVERY group's device programs through the
         non-syncing executor entry WITHOUT collecting — so a scheduler
@@ -277,15 +286,56 @@ class ShardReader:
         this is the `bind` phase, but for the launches: the executor
         pauses it around each, so that the spans stay leaves. Every
         phase of the call weighs its number of bodies: each of those
-        searches waits through all of it."""
+        searches waits through all of it.
+
+        What the call makes of a body and the reader alone is made once
+        and kept on the reader (search/bound_plans.py). A body served
+        before skips the parse and the per-segment bind; a group of
+        bodies served before skips the aggregation context, the sort's
+        maps, the view uploads and, in `execute_segment_async`, the
+        finalize, the fused admission, the packing and the upload of
+        the wire parameters and the output layout: it goes from its key
+        to the launches. Done per call whatever is kept: the faults
+        hook, the deadline and the step budget, the grouping; per
+        launch: the breaker hold, the live mask and its views, the
+        launch and its counters, the request for the result's copy, the
+        spans with this call's request ids. Nothing of a result is
+        kept. `keys` brings the bodies' `bound_plans.body_key`s where
+        the caller has them (a fan-out hands one body to every shard);
+        without them they are made here. Not kept, and counted as
+        `bypassed`: `knn` items, multi-key sorts, a reader without
+        segments, a (base, delta) pair, the resident loop when it is
+        on, a paged pack, a body whose parse or bind read the clock or
+        the script registry or uploaded a column."""
         ids = list(requests) if requests else [None] * len(bodies)
         with phase("bind", len(bodies), **request_args(ids)) as bind:
             return self._msearch_submit(bodies, with_partials, deadline,
-                                        ids, bind)
+                                        ids, bind, keys)
+
+    def _pack_pair(self) -> bool:
+        """A (base, delta) generation pair, which the pack dispatch
+        serves in one program (streaming write path)."""
+        return len(self.segments) == 2 \
+            and getattr(self.segments[1], "delta_parent", None) is not None \
+            and _pack_dispatch_enabled()
+
+    def _bind_body(self, body: dict) -> "bound_plans.BoundBody":
+        """Parse one body and bind it against every segment."""
+        p = self._parse_request(body)
+        if not self.segments or p["sort_spec"][0] == "multi":
+            return bound_plans.BoundBody(p, [], None)
+        bounds = [QueryBinder(seg, self.mappers,
+                              live=self.live[seg.seg_id],
+                              dfs=p["dfs_stats"]).bind(p["query"])
+                  for seg in self.segments]
+        # groups by (plan signature per segment, agg/sort/k sig)
+        sig = (tuple(b.signature() for b in bounds), p["static_sig"])
+        return bound_plans.BoundBody(p, bounds, sig)
 
     def _msearch_submit(self, bodies: list[dict], with_partials: bool,
                         deadline: float | None, ids: list,
-                        bind: phase) -> "_PendingMsearch":
+                        bind: phase, keys: list | None
+                        ) -> "_PendingMsearch":
         faults.on_dispatch("reader", index=self.index_name,
                            shard=self.shard_id)
         started = time.monotonic()
@@ -305,9 +355,11 @@ class ShardReader:
         bodies = list(bodies)
         knn_idx = []
         knn_modes: dict[int, str] = {}
+        knn_any = set()  # the rewritten ones too
         for i, b in enumerate(bodies):
             if not (b or {}).get("knn"):
                 continue
+            knn_any.add(i)
             mode, reason = knn_body_mode(b, self.mappers)
             if mode != "candidates":
                 # candidates items record "ivf" / "exact" from the
@@ -324,14 +376,47 @@ class ShardReader:
                 knn_idx.append(i)
                 knn_modes[i] = mode
         knn_set = set(knn_idx)
-        parsed = {i: self._parse_request(bodies[i])
-                  for i in range(n) if i not in knn_set}
+        # the kept plans serve the grouped cold path of a reader with
+        # segments; a (base, delta) pair goes through the pack dispatch
+        # and the resident loop through its pinned entries
+        plans = self._bound_plans
+        kept = bool(self.segments) and not _resident_enabled() \
+            and not self._pack_pair()
+        if kept:
+            plans.open((self.mappers.version, plan_switches()))
+        bound: dict[int, bound_plans.BoundBody] = {}
+        body_keys: dict[int, str | None] = {}
+        for i in range(n):
+            if i in knn_set:
+                bound_plans.bypassed.inc()
+                continue
+            key = None
+            if kept and i not in knn_any:
+                key = keys[i] if keys and keys[i] is not None \
+                    else bound_plans.body_key(bodies[i])
+            bb = plans.get(key) if key is not None else None
+            if bb is None:
+                with bound_plans.keep_scope() as scope:
+                    # a kept parse outlives the caller's dict: it is
+                    # made from a copy (the key has shown the body to
+                    # be plain JSON values)
+                    bb = self._bind_body(
+                        pickle.loads(pickle.dumps(bodies[i], -1))
+                        if key is not None else bodies[i])
+                if bb.sig is None or not scope.keep:
+                    key = None
+                if key is not None:
+                    plans.put(key, bb)
+            bound[i] = bb
+            body_keys[i] = key
+        parsed = {i: bb.parsed for i, bb in bound.items()}
         pend = _PendingMsearch(self, bodies, with_partials, started,
                                knn_idx, parsed)
         pend.deadline = deadline
         pend.step_budget = step_budget
         if not self.segments:
             pend.no_segments = True
+            bound_plans.bypassed.inc(len(parsed))
             return pend
         for i in knn_idx:
             # pure-knn items dispatch their per-segment candidate
@@ -347,110 +432,158 @@ class ShardReader:
             pend.knn_sub[i] = sub
         pend.multi = {i for i, p in parsed.items()
                       if p["sort_spec"][0] == "multi"}
+        bound_plans.bypassed.inc(len(pend.multi))
         pend.main = [i for i in range(n)
                      if i not in knn_set and i not in pend.multi]
 
-        # group request indices by (plan signature per segment, agg/sort/k sig)
         groups: dict[tuple, list[int]] = {}
-        bound_per_req: dict[int, list] = {}
         for i in pend.main:
-            p = parsed[i]
-            per_seg_bounds = [
-                QueryBinder(seg, self.mappers,
-                            live=self.live[seg.seg_id],
-                            dfs=p["dfs_stats"]).bind(p["query"])
-                for seg in self.segments]
-            bound_per_req[i] = per_seg_bounds
-            sig = (tuple(b.signature() for b in per_seg_bounds), p["static_sig"])
-            groups.setdefault(sig, []).append(i)
+            groups.setdefault(bound[i].sig, []).append(i)
 
-        for sig, idxs in groups.items():
+        for idxs in groups.values():
             # from here on the spans name this group's requests
             group_args = bind.args = request_args(ids[i] for i in idxs)
             p0 = parsed[idxs[0]]
-            agg_ctx = ShardAggContext(self.segments,
-                                      self._ords_for(p0["agg_specs"]))
-            agg_desc, agg_params = agg_ctx.build(p0["agg_specs"])
-            k = p0["from"] + p0["size"]
-            if k == 0 and (p0["sort_spec"][0] != "_score"
-                           or p0["rescore"] is not None):
-                # size-0 requests skip top-k entirely only on the plain
-                # score-sort path; sorted/rescored requests keep k>=1
-                k = 1
-            sort_spec = p0["sort_spec"]
-            if p0["agg_specs"]:
-                # sorted-space query views: project the filter columns
-                # onto each agg layout so the agg mask never rides a
-                # per-query permutation gather (see executor.py)
-                from .executor import ensure_agg_views
-                for si, seg in enumerate(self.segments):
-                    ensure_agg_views(seg, bound_per_req[idxs[0]][si],
-                                     agg_desc)
-            sort_terms = None
-            sort_maps = [() for _ in self.segments]
-            if sort_spec[0] == "field" and sort_spec[3] == "kw":
-                sort_terms, seg_maps = self.global_ords(sort_spec[1])
-                sort_maps = [(m,) for m in seg_maps]
-            elif sort_spec[0] == "field" and sort_spec[3] == "script":
-                from ..script import compile_script
-                from .executor import ensure_script_vals
-                cs = compile_script(sort_spec[1].split("\x00", 1)[0])
-                for seg in self.segments:
-                    ensure_script_vals(seg, cs.fields)
-            elif sort_spec[0] == "field" and len(sort_spec) > 4:
-                # extended spec (geo origin etc.): extras become dynamic
-                # sort_params; the static jit key keeps only the 4-tuple
-                extras = tuple(np.float32(e) for e in sort_spec[4:])
-                sort_maps = [extras for _ in self.segments]
-                sort_spec = sort_spec[:4]
-            # dispatch all segments async; collection happens in
-            # finish(), so round trips overlap across segments AND
-            # across groups/readers. Nested-scope requests (aggregations
-            # over hidden child rows) lift the primary-row restriction.
-            live_sel = self.live_all if p0["nested_scope"] else self.live
-            pending = []
-            # streaming write path: a (base, delta) generation pair
-            # serves fused-admitted plans in ONE device dispatch (the
-            # delta walk chains onto the base's running top-k;
-            # executor.execute_pack_async) — one dispatch round trip per
-            # refresh-heavy reader instead of one per segment, with
-            # byte-identical responses. Inadmissible plans fall back
-            # to the per-segment dispatches below.
-            if len(self.segments) == 2 \
-                    and getattr(self.segments[1], "delta_parent",
-                                None) is not None \
-                    and _pack_dispatch_enabled():
-                b_seg, d_seg = self.segments
-                pack = execute_pack_async(
-                    b_seg, d_seg, live_sel[b_seg.seg_id],
-                    live_sel[d_seg.seg_id],
-                    [bound_per_req[i][0] for i in idxs],
-                    [bound_per_req[i][1] for i in idxs], k,
-                    agg_desc=agg_desc,
-                    agg_params_b=agg_params[0] if agg_params else (),
-                    agg_params_d=agg_params[1] if agg_params else (),
-                    sort_spec=sort_spec, deadline=deadline,
-                    step_budget=step_budget,
-                    shard_key=(self.index_name, self.shard_id), bind=bind)
-                if pack is not None:
-                    pending.append(pack)
-            if not pending:
-                for si, seg in enumerate(self.segments):
-                    bounds = [bound_per_req[i][si] for i in idxs]
-                    pending.append(execute_segment_async(
-                        seg, live_sel[seg.seg_id], bounds, k,
-                        agg_desc=agg_desc, agg_params=agg_params[si],
-                        sort_spec=sort_spec, sort_params=sort_maps[si],
-                        deadline=deadline, step_budget=step_budget,
-                        shard_key=(self.index_name, self.shard_id),
-                        bind=bind))
-            pend.groups.append({"idxs": idxs, "p0": p0, "agg_ctx": agg_ctx,
+            # a group of one is kept with its body, a larger one under
+            # its bodies' keys in order
+            gkey = tuple(body_keys[i] for i in idxs)
+            if None in gkey:
+                gkey = None
+            first = bound[idxs[0]]
+            if gkey is None:
+                group = None
+            elif len(idxs) == 1:
+                group = first.alone
+            else:
+                group = plans.get(gkey)
+            if group is not None and not all(
+                    segment_plan_valid(seg, sp)
+                    for seg, sp in zip(self.segments, group.plans)):
+                group = None
+            hit = group is not None
+            if not hit:
+                group = self._bind_group(p0, first.bounds)
+            try:
+                pending = self._launch_group(
+                    group, p0, [bound[i].bounds for i in idxs], hit,
+                    deadline, step_budget, bind)
+            except BaseException:
+                if hit:
+                    # kept values that do not launch are built anew by
+                    # the next search
+                    plans.drop({gkey, *gkey})
+                raise
+            if hit:
+                bound_plans.hits.inc()
+            elif gkey is not None \
+                    and len(group.plans) == len(self.segments):
+                bound_plans.misses.inc()
+                if len(idxs) == 1:
+                    first.alone = group
+                else:
+                    plans.put(gkey, group)
+            else:
+                bound_plans.bypassed.inc()
+            pend.groups.append({"idxs": idxs, "p0": p0,
+                                "agg_ctx": group.agg_ctx,
                                 "pending": pending,
-                                "sort_terms": sort_terms,
+                                "sort_terms": group.sort_terms,
                                 "span_args": group_args})
         pend.group_sizes = [len(g["idxs"]) for g in pend.groups]
         pend.dispatch_count = sum(len(g["pending"]) for g in pend.groups)
         return pend
+
+    def _bind_group(self, p0: dict, bounds0: list
+                    ) -> "bound_plans.BoundGroup":
+        """What one group of structurally identical bodies needs before
+        its launches, from its first body: the aggregation context and
+        descriptors, k, the sort's spec, terms and maps, and the view
+        and script-value uploads. The executor fills in `plans`."""
+        agg_ctx = ShardAggContext(self.segments,
+                                  self._ords_for(p0["agg_specs"]))
+        agg_desc, agg_params = agg_ctx.build(p0["agg_specs"])
+        k = p0["from"] + p0["size"]
+        if k == 0 and (p0["sort_spec"][0] != "_score"
+                       or p0["rescore"] is not None):
+            # size-0 requests skip top-k entirely only on the plain
+            # score-sort path; sorted/rescored requests keep k>=1
+            k = 1
+        sort_spec = p0["sort_spec"]
+        if p0["agg_specs"]:
+            # sorted-space query views: project the filter columns
+            # onto each agg layout so the agg mask never rides a
+            # per-query permutation gather (see executor.py)
+            from .executor import ensure_agg_views
+            for seg, b in zip(self.segments, bounds0):
+                ensure_agg_views(seg, b, agg_desc)
+        sort_terms = None
+        sort_maps = [() for _ in self.segments]
+        if sort_spec[0] == "field" and sort_spec[3] == "kw":
+            sort_terms, seg_maps = self.global_ords(sort_spec[1])
+            sort_maps = [(m,) for m in seg_maps]
+        elif sort_spec[0] == "field" and sort_spec[3] == "script":
+            from ..script import compile_script
+            from .executor import ensure_script_vals
+            cs = compile_script(sort_spec[1].split("\x00", 1)[0])
+            for seg in self.segments:
+                ensure_script_vals(seg, cs.fields)
+        elif sort_spec[0] == "field" and len(sort_spec) > 4:
+            # extended spec (geo origin etc.): extras become dynamic
+            # sort_params; the static jit key keeps only the 4-tuple
+            extras = tuple(np.float32(e) for e in sort_spec[4:])
+            sort_maps = [extras for _ in self.segments]
+            sort_spec = sort_spec[:4]
+        return bound_plans.BoundGroup(agg_ctx, agg_desc, agg_params, k,
+                                      sort_spec, sort_terms, sort_maps, [])
+
+    def _launch_group(self, group: "bound_plans.BoundGroup", p0: dict,
+                      bounds: list, hit: bool, deadline: float | None,
+                      step_budget, bind: phase) -> list:
+        """Dispatch all segments async; collection happens in finish(),
+        so round trips overlap across segments AND across
+        groups/readers. `bounds` holds each body's per-segment bound
+        trees. On a `hit` every segment launches from its kept plan;
+        otherwise the executor resolves the plans and leaves them in
+        `group.plans`."""
+        # nested-scope requests (aggregations over hidden child rows)
+        # lift the primary-row restriction
+        live_sel = self.live_all if p0["nested_scope"] else self.live
+        agg_params = group.agg_params
+        pending = []
+        # streaming write path: a (base, delta) generation pair
+        # serves fused-admitted plans in ONE device dispatch (the
+        # delta walk chains onto the base's running top-k;
+        # executor.execute_pack_async) — one dispatch round trip per
+        # refresh-heavy reader instead of one per segment, with
+        # byte-identical responses. Inadmissible plans fall back
+        # to the per-segment dispatches below.
+        if self._pack_pair():
+            b_seg, d_seg = self.segments
+            pack = execute_pack_async(
+                b_seg, d_seg, live_sel[b_seg.seg_id],
+                live_sel[d_seg.seg_id],
+                [b[0] for b in bounds], [b[1] for b in bounds], group.k,
+                agg_desc=group.agg_desc,
+                agg_params_b=agg_params[0] if agg_params else (),
+                agg_params_d=agg_params[1] if agg_params else (),
+                sort_spec=group.sort_spec, deadline=deadline,
+                step_budget=step_budget,
+                shard_key=(self.index_name, self.shard_id), bind=bind)
+            if pack is not None:
+                pending.append(pack)
+        if not pending:
+            for si, seg in enumerate(self.segments):
+                pending.append(execute_segment_async(
+                    seg, live_sel[seg.seg_id], [b[si] for b in bounds],
+                    group.k, agg_desc=group.agg_desc,
+                    agg_params=agg_params[si],
+                    sort_spec=group.sort_spec,
+                    sort_params=group.sort_maps[si],
+                    deadline=deadline, step_budget=step_budget,
+                    shard_key=(self.index_name, self.shard_id),
+                    bind=bind, plan=group.plans[si] if hit else None,
+                    keep=None if hit else group.plans))
+        return pending
 
     @staticmethod
     def _release_pending_holds(pend: "_PendingMsearch") -> None:

@@ -142,8 +142,8 @@ def test_no_second_round_executor():
 # what GET /_nodes/stats/dispatch returned before the two round
 # executors became one (commit fa237f9), neither guard armed
 DISPATCH_KEYS = [
-    "adopted_batches", "batches_dispatched", "coalesced_queries",
-    "collect_lead", "collects", "eviction", "failover", "launches",
+    "adopted_batches", "batches_dispatched", "bound_plans",
+    "coalesced_queries", "collect_lead", "collects", "eviction", "failover", "launches",
     "leader", "membership", "merge", "phases", "pipeline_depth",
     "queries", "resident", "searches", "traffic", "window"]
 
@@ -157,6 +157,8 @@ def test_the_stats_keep_their_keys(node):
     assert sorted(snap["merge"]) == ["count", "hits", "mean",
                                      "shard_results", "sum"]
     assert sorted(snap["collects"]) == ["prefetched", "total"]
+    assert sorted(snap["bound_plans"]) == ["bypassed", "entries",
+                                           "evictions", "hits", "misses"]
     assert sorted(snap["collect_lead"]) == ["count", "mean", "sum"]
     assert node.nodes_stats()["nodes"][node.name]["dispatch"].keys() \
         == snap.keys()
