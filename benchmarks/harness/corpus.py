@@ -1,7 +1,17 @@
 """The yardstick: the corpus, the plain numpy reference over it, its
 low-precision control and the comparison that decides `correct`.
 
-The corpus has the record shape of the `http_logs` track of
+A configuration names its record shape (`record_shape`; absent:
+`http_logs`), and `shape` finds the shape's module by that name: this
+file for `http_logs`, `harness/shapes/<name>.py` for any other. A shape
+module has `GENERATOR_VERSION` and a `Corpus(n, seed, n_shards, params)`
+(a `Records`) with `bulk_body`, `evaluate` and, where its mixes draw
+queries, `draw`. What every shape shares is written once, here:
+`route_shards`, `Records.top`, `.buckets` and `.warm_bodies`, `answer_from`,
+`digest`, `Reference.compare` with its seven numbers, `fold`, `judge`
+and the bfloat16 control.
+
+The `http_logs` shape is the record of the `http_logs` track of
 elastic/rally-tracks (web-server log lines of the 1998 World Cup site):
 `@timestamp`, `clientip`, `request` (text with a `.raw` keyword),
 integer `status`, integer `size`. The track's 31 GB of documents are
@@ -27,7 +37,9 @@ Imports numpy only; nothing of the program and nothing it has made.
 from __future__ import annotations
 
 import calendar
+import importlib
 import json
+import sys
 import time
 
 import numpy as np
@@ -78,7 +90,83 @@ def route_shards(n_docs: int, n_shards: int) -> np.ndarray:
     return np.mod(h, n_shards)
 
 
-class Corpus:
+def apportion(weights, count: int) -> np.ndarray:
+    """`count` things shared out in proportion to `weights`, whole
+    numbers by largest remainders: the same for every seed."""
+    want = np.asarray(weights, float) * count / np.sum(weights)
+    have = np.floor(want).astype(int)
+    have[np.argsort(have - want, kind="stable")[:count - have.sum()]] += 1
+    return have
+
+
+def shape(config: dict):
+    """The module of the configuration's record shape. Any other than
+    `http_logs` is `harness/shapes/<name>.py`, found with `benchmarks/`
+    on the import path, where every entry of the harness puts it."""
+    name = config.get("record_shape", "http_logs")
+    if name == "http_logs":
+        return sys.modules[__name__]
+    return importlib.import_module("harness.shapes." + name)
+
+
+def corpus_of(config: dict, docs: int, seed: int):
+    """The configuration's corpus at `docs` documents, from its shape's
+    generator. The shape gets the `corpus` group with the configuration's
+    `similarity` beside it (a text shape scores with its `k1` and `b`)."""
+    return shape(config).Corpus(
+        docs, seed, config["number_of_shards"],
+        dict(config["corpus"], similarity=config["similarity"]))
+
+
+class Records:
+    """What every shape's corpus shares: `n` documents whose `_id` is
+    their number, `shard` (each document's shard), `cols` (one value a
+    document for each field a query can sort or bucket on), and the
+    choice of the best hits. A shape adds `bulk_body`, `evaluate` and,
+    where its mixes draw queries, `draw`."""
+
+    n: int
+    shard: np.ndarray
+    cols: dict
+
+    def top(self, spec: dict, match: np.ndarray, score: np.ndarray):
+        """The `size` best docs: by the sort field where the query has
+        one, else by score descending; ties shard ascending, then
+        document ascending, the order the configuration states."""
+        k = spec["size"]
+        cand = np.flatnonzero(match)
+        if not k or not len(cand):
+            return cand[:0]
+        if spec.get("sort"):
+            key = self.cols[spec["sort"]["field"]][cand].astype(np.float64)
+            if spec["sort"]["order"] == "desc":
+                key = -key
+        else:
+            key = -score[cand].astype(np.float64)
+        if len(cand) > k:
+            # everything that ties with the k-th best stays in
+            kth = np.partition(key, k - 1)[k - 1]
+            cand, key = cand[key <= kth], key[key <= kth]
+        return cand[np.lexsort((cand, self.shard[cand], key))][:k]
+
+    def buckets(self, spec: dict, match: np.ndarray) -> dict:
+        h = spec["histogram"]
+        slot = self.cols[h["field"]][match] // h["interval_ms"]
+        keys, counts = np.unique(slot, return_counts=True)
+        return {int(k) * h["interval_ms"]: int(c)
+                for k, c in zip(keys, counts)}
+
+    def warm_bodies(self, op: dict) -> list:
+        """The bodies that warm an operation up, one for each plan shape
+        its requests can have: a fixed body itself; every planned query
+        of a drawn operation, since the program pads a plan to a power of
+        two of its terms' posting blocks, so each query may be a plan
+        shape of its own, and the harness knows no rule of the program's
+        to tell which are."""
+        return op["bodies"] if "draw" in op else [op["body"]]
+
+
+class Corpus(Records):
     """`n` log lines drawn from `seed` with the parameters of the
     configuration's `corpus` group. Columns, one value a document:
     `@timestamp` (epoch ms), `status`, `size`, `request.raw` (an integer
@@ -191,33 +279,6 @@ class Corpus:
             score = (score + s).astype(dtype)
         return match, score
 
-    def top(self, spec: dict, match: np.ndarray, score: np.ndarray):
-        """The `size` best docs: by the sort field where the query has
-        one, else by score descending; ties shard ascending, then
-        document ascending, the order the configuration states."""
-        k = spec["size"]
-        cand = np.flatnonzero(match)
-        if not k or not len(cand):
-            return cand[:0]
-        if spec.get("sort"):
-            key = self.cols[spec["sort"]["field"]][cand].astype(np.float64)
-            if spec["sort"]["order"] == "desc":
-                key = -key
-        else:
-            key = -score[cand].astype(np.float64)
-        if len(cand) > k:
-            # everything that ties with the k-th best stays in
-            kth = np.partition(key, k - 1)[k - 1]
-            cand, key = cand[key <= kth], key[key <= kth]
-        return cand[np.lexsort((cand, self.shard[cand], key))][:k]
-
-    def buckets(self, spec: dict, match: np.ndarray) -> dict:
-        h = spec["histogram"]
-        slot = self.cols[h["field"]][match] // h["interval_ms"]
-        keys, counts = np.unique(slot, return_counts=True)
-        return {int(k) * h["interval_ms"]: int(c)
-                for k, c in zip(keys, counts)}
-
 
 def answer_from(corpus: Corpus, spec: dict, dtype) -> dict:
     """A whole answer computed in `dtype`, in the digest's form: what
@@ -257,17 +318,21 @@ COMPARED = ("total_wrong", "hits_wrong", "buckets_wrong", "order_wrong",
 
 
 class Reference:
-    """The float64 reference's answers, each worked out once: a mix's
-    operations are few and every request of one operation has the same
-    answer."""
+    """The float64 reference's answers, each worked out once: requests
+    of one query have the same answer. The newest `keep` are held (a
+    match mask and a score a document each), which is every one of a mix
+    of fixed bodies; a window of drawn queries is compared query by
+    query (`run.py:compare_all`)."""
 
-    def __init__(self, corpus: Corpus):
-        self.corpus = corpus
+    def __init__(self, corpus: Records, keep: int = 16):
+        self.corpus, self.keep = corpus, keep
         self._known: dict[str, tuple] = {}
 
     def of(self, spec: dict) -> tuple:
         key = json.dumps(spec, sort_keys=True)
         if key not in self._known:
+            if len(self._known) >= self.keep:
+                del self._known[next(iter(self._known))]
             match, score = self.corpus.evaluate(spec)
             self._known[key] = (match, score,
                                 self.corpus.top(spec, match, score))
@@ -353,6 +418,12 @@ def fold(readings: list[dict]) -> dict:
         out[name] = (max(vals, default=0.0) if name.endswith("_gap")
                      else int(sum(vals)))
     return out
+
+
+def judge(numbers: dict, limits: dict) -> bool:
+    """Whether every number compared is within the configuration's
+    limit for it."""
+    return all(numbers[k] <= limits[k] for k in COMPARED)
 
 
 def bfloat16():

@@ -2,7 +2,7 @@
 flush it, close the node. A process of its own, which holds the chip
 while it runs and ends before the run's own process touches JAX.
 
-    python benchmarks/harness/loader.py <configuration> <seed> <docs> <rehearse 0|1>
+    python benchmarks/harness/loader.py <config file> <seed> <docs> <rehearse 0|1>
 
 Exits 2, having loaded nothing, where it is not a rehearsal and JAX
 finds no TPU.
@@ -20,7 +20,7 @@ sys.path.insert(0, os.path.dirname(HERE))
 
 
 def main(argv: list[str]) -> int:
-    name, seed, docs, rehearse = argv[1], int(argv[2]), int(argv[3]), \
+    path, seed, docs, rehearse = argv[1], int(argv[2]), int(argv[3]), \
         int(argv[4])
     from harness import die_with_parent
     die_with_parent()
@@ -35,10 +35,9 @@ def main(argv: list[str]) -> int:
         print(f"loader.py: no TPU, JAX found [{platform}]", file=sys.stderr)
         return 2
     configure_compile_cache()
-    with open(os.path.join(HERE, "configs", name + ".json")) as f:
+    with open(path) as f:
         config = json.load(f)
-    corpus = C.Corpus(docs, seed, config["number_of_shards"],
-                      config["corpus"])
+    corpus = C.corpus_of(config, docs, seed)
     tag = f"[{platform} loader]"
     with S.Served(config, seed, docs,
                   log=lambda *parts: print(tag, *parts, flush=True)) as sv:

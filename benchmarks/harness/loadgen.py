@@ -9,7 +9,13 @@ general generator reads these parameters of a mix:
 
 - `operations`: each with its `name`, the REST `body` it sends to
   `POST /{index}/_search` and its `target_throughput`, requests a
-  second;
+  second. An operation with a `draw` group in place of a `body` sends
+  queries that differ from request to request: the shape's corpus draws
+  `distinct` of them (`Corpus.draw` reads the rest of the group), and
+  the operation's requests repeat them as often as rank^-`repeat_zipf`
+  says, every one at least once (`plan_queries`, which `run.py` calls
+  before the window; a planned operation carries `bodies`, `specs` and
+  `order`, the query of each of its requests);
 - `rate_scale`: every operation's rate is multiplied by it (1 is the
   source's own load);
 - `clients`: the connections the schedule is sent over. The loop is
@@ -38,7 +44,7 @@ import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from harness import die_with_parent  # noqa: E402
-from harness.corpus import digest  # noqa: E402
+from harness.corpus import apportion, digest  # noqa: E402
 
 DRAIN_S = 60.0      # an answer may come this long after the window closes
 
@@ -53,16 +59,55 @@ def due_times(n: int, seconds: float, rng) -> np.ndarray:
     return np.cumsum(gaps) - gaps[0]
 
 
+def requests_of(op: dict, mix: dict, seconds: float) -> int:
+    """round(rate x seconds) requests of an operation, one at the least."""
+    rate = op["target_throughput"] * mix.get("rate_scale", 1.0)
+    return max(1, int(round(rate * seconds)))
+
+
 def deal_operations(mix: dict, seconds: float, rng) -> list[int]:
-    """The window's requests, each the index of its operation:
-    round(rate x seconds) of each and one at the least, in an order
-    drawn from the seed."""
+    """The window's requests, each the index of its operation, in an
+    order drawn from the seed."""
     dealt = []
     for k, op in enumerate(mix["operations"]):
-        rate = op["target_throughput"] * mix.get("rate_scale", 1.0)
-        dealt += [k] * max(1, int(round(rate * seconds)))
+        dealt += [k] * requests_of(op, mix, seconds)
     rng.shuffle(dealt)
     return dealt
+
+
+def repeats(requests: int, distinct: int, zipf: float, rng) -> list[int]:
+    """Which of `distinct` queries each of `requests` requests sends:
+    every query once, the rest as rank^-`zipf` says (largest remainders,
+    so every seed repeats its head queries as often), in an order drawn
+    from the seed."""
+    have = apportion(1.0 / (np.arange(distinct) + 1.0) ** zipf,
+                     requests - distinct)
+    order = np.repeat(np.arange(distinct), have + 1)
+    rng.shuffle(order)
+    return order.tolist()
+
+
+def plan_queries(mix: dict, corpus, seed: int, seconds: float) -> dict:
+    """The mix with every drawn operation planned for one window: its
+    `bodies` and `specs` (the queries `corpus.draw` made of its `draw`
+    group) and `order` (the query of its first, second, ... request).
+    Each drawn operation draws from a stream of its own, queries first,
+    so a mix of fixed bodies sends what it sent before there were any,
+    and an operation sends the same queries at every rate that has room
+    for all of them."""
+    ops = []
+    for k, op in enumerate(mix["operations"]):
+        if "draw" in op:
+            rng = np.random.default_rng([seed, 12, k])
+            requests = requests_of(op, mix, seconds)
+            pairs = corpus.draw(op, min(op["draw"]["distinct"], requests),
+                                rng)
+            op = dict(op, bodies=[b for b, _ in pairs],
+                      specs=[s for _, s in pairs],
+                      order=repeats(requests, len(pairs),
+                                    op["draw"]["repeat_zipf"], rng))
+        ops.append(op)
+    return dict(mix, operations=ops)
 
 
 class Client:
@@ -75,9 +120,12 @@ class Client:
         self.conn.connect()
         self.records: list[dict] = []
 
-    def send(self, i: int, due: float, op: str, data: bytes) -> None:
+    def send(self, i: int, due: float, op: str, query: int | None,
+             data: bytes) -> None:
         sent = time.monotonic()
         rec = {"i": i, "op": op, "due": due, "sent": sent, "status": 0}
+        if query is not None:
+            rec["query"] = query
         try:
             self.conn.request("POST", f"/{self.spec['index']}/_search",
                               body=data,
@@ -118,9 +166,19 @@ def run_open(spec: dict) -> list[dict]:
     dealt = deal_operations(mix, spec["seconds"], rng)
     due = due_times(len(dealt), spec["seconds"], rng)
     clients = [Client(spec) for _ in range(mix["clients"])]
-    # every request is built before the clock starts
-    built = [(op["name"], json.dumps(op["body"]).encode())
+    # every request is built before the clock starts: an operation's
+    # one body, or the planned query of each of its requests in turn
+    fixed = [None if "order" in op else json.dumps(op["body"]).encode()
              for op in mix["operations"]]
+    turn = [0] * len(fixed)
+    built = []
+    for k in dealt:
+        op, query = mix["operations"][k], None
+        if fixed[k] is None:
+            query = op["order"][turn[k]]
+            turn[k] += 1
+        built.append((op["name"], query, fixed[k] or json.dumps(
+            op["bodies"][query]).encode()))
     t0 = start_time(spec)
     lock = threading.Lock()
     nxt = [0]
@@ -135,7 +193,7 @@ def run_open(spec: dict) -> list[dict]:
             wait = t0 + due[i] - time.monotonic()
             if wait > 0:
                 time.sleep(wait)
-            clients[c].send(i, t0 + float(due[i]), *built[dealt[i]])
+            clients[c].send(i, t0 + float(due[i]), *built[i])
 
     threads = [threading.Thread(target=loop, args=(c,), daemon=True)
                for c in range(len(clients))]

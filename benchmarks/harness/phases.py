@@ -110,10 +110,14 @@ def span_coverage_pct(run):
 
 
 def scheduler_wait_ms(run):
-    """`scheduler_wait`: from `DispatchBatch.dispatch()` to where the
-    job's group starts executing on whichever thread leads (the
-    coalescing window's sleep included), and from the group's end to
-    `dispatch()` returning on the caller's thread."""
+    """`scheduler_wait`, counted once a search whatever its fan-out
+    (since PR 28): the time between `DispatchBatch.dispatch()` being
+    called and returning in which the leading thread served none of the
+    batch's shard jobs (the coalescing window's sleep and a search
+    parked behind a round in flight included). The batch's one clock
+    stands still while the leader serves a reader group that holds any
+    of its jobs; that time is in `bind`, the launches, `collect`,
+    `unpack` and `fetch`."""
     return _ms_per_search(run, "scheduler_wait")
 
 
@@ -133,13 +137,16 @@ def bind_ms(run):
 def launch_ms(run):
     """`dispatch` (and `resident_dispatch`, `tiered_dispatch`,
     `tiered_aggs` where those paths run): the host's time in the jitted
-    programs' launches."""
+    programs' launches and, since PR 29, in asking for each result's
+    copy to the host at its launch (`executor._start_fetch`)."""
     return _ms_per_search(run, *LAUNCHES)
 
 
 def device_wait_ms(run):
-    """`collect`: `jax.device_get` of the result — device time, the copy
-    to the host, and the runtime waking the thread."""
+    """`collect`: `jax.device_get` of the result. The copy to the host
+    was asked for at the launch (since PR 29), so this is what is left
+    of device time, copy and the runtime waking the thread when the
+    leader gets there."""
     return _ms_per_search(run, "collect")
 
 

@@ -20,6 +20,17 @@ def latencies_ms(requests: list) -> list:
             for r in requests]
 
 
+def spec_of(mix: dict):
+    """request -> the reference's spec of the query it sent: its
+    operation's one, or that of its own planned query."""
+    ops = {op["name"]: op for op in mix["operations"]}
+
+    def spec(r: dict) -> dict:
+        op = ops[r["op"]]
+        return op["specs"][r["query"]] if "query" in r else op["spec"]
+    return spec
+
+
 def _delta(run, *path: str):
     """How far a counter of `_nodes/stats` moved over the window."""
     def dig(stats):
@@ -97,9 +108,9 @@ def column_scan_roofline(run):
     stalls."""
     if not run.trace or not run.trace["scoring_s"] or not run.peaks:
         return None
-    specs = {op["name"]: op["spec"] for op in run.mix["operations"]}
-    least = sum(search_bytes(run.config["mappings"], specs[r["op"]],
-                             run.docs) for r in _traced(run))
+    spec = spec_of(run.mix)
+    least = sum(search_bytes(run.config["mappings"], spec(r), run.docs)
+                for r in _traced(run))
     if not least:
         return None
     return 100.0 * least / run.peaks["hbm_bytes_per_s"] \

@@ -24,7 +24,6 @@ from . import corpus as C
 
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 DATA_ROOT = os.path.join(HERE, ".data")
-INDEX = "logs"
 SIDECAR = "bench_sidecar.json"
 
 
@@ -87,7 +86,7 @@ def data_dir(config: dict, seed: int, docs: int) -> str:
 
 def sidecar(config: dict, seed: int, docs: int) -> dict:
     return {"seed": seed, "docs": docs, "shards": config["number_of_shards"],
-            "generator_version": C.GENERATOR_VERSION}
+            "generator_version": C.shape(config).GENERATOR_VERSION}
 
 
 def stored(config: dict, seed: int, docs: int) -> bool:
@@ -109,7 +108,7 @@ def ensure_stored(config: dict, seed: int, docs: int, rehearse: bool) -> int:
         return 0
     return subprocess.run(
         [sys.executable, os.path.join(HERE, "harness", "loader.py"),
-         config["name"], str(seed), str(docs), str(int(rehearse))]).returncode
+         config["path"], str(seed), str(docs), str(int(rehearse))]).returncode
 
 
 class Served:
@@ -120,8 +119,8 @@ class Served:
         self.config, self.seed, self.docs = config, seed, docs
         self.keep_data, self.log = keep_data, log
         self.data_dir = data_dir(config, seed, docs)
-        self.node = self.server = self.http = None
-        self.index = INDEX
+        self.node = self.server = self.http = self.corpus = None
+        self.index = config.get("index", "logs")
 
     def _start(self) -> None:
         from elasticsearch_tpu.node import Node
@@ -146,7 +145,7 @@ class Served:
         return self
 
     def _count(self, n: int, what: str) -> None:
-        _st, r = self.http.call("GET", f"/{INDEX}/_count")
+        _st, r = self.http.call("GET", f"/{self.index}/_count")
         if r.get("count") != n:
             raise RuntimeError(f"count {r.get('count')} != {n} ({what})")
 
@@ -158,21 +157,21 @@ class Served:
         os.makedirs(self.data_dir)
         self._start()
         http = self.http
-        st, r = http.call("PUT", f"/{INDEX}", {
+        st, r = http.call("PUT", f"/{self.index}", {
             "settings": dict(self.config["index_settings"]),
             "mappings": self.config["mappings"]})
         if st != 200:
             raise RuntimeError(f"create index: {st} {r}")
         chunk = self.config["bulk_size"]
         for lo in range(0, corpus.n, chunk):
-            st, r = http.call("POST", f"/{INDEX}/_bulk", corpus.bulk_body(
+            st, r = http.call("POST", f"/{self.index}/_bulk", corpus.bulk_body(
                 lo, min(lo + chunk, corpus.n)))
             if st != 200 or r.get("errors"):
                 raise RuntimeError(f"bulk at {lo}: {st} "
                                    f"{json.dumps(r)[:300]}")
         t_bulk = time.perf_counter() - t
         for verb in ("_refresh", "_flush"):
-            st, r = http.call("POST", f"/{INDEX}/{verb}")
+            st, r = http.call("POST", f"/{self.index}/{verb}")
             if st != 200 or r["_shards"]["failed"]:
                 raise RuntimeError(f"{verb}: {st} {r}")
         self._count(corpus.n, "loaded")
@@ -190,8 +189,9 @@ class Served:
         if not stored(self.config, self.seed, self.docs):
             raise RuntimeError(f"no flushed index at {self.data_dir}")
         self._start()
+        self.corpus = corpus
         self._count(corpus.n, "reopened")
-        n_sh = len(self.node.indices[INDEX].shards)
+        n_sh = len(self.node.indices[self.index].shards)
         if n_sh != self.config["number_of_shards"]:
             raise RuntimeError(f"{n_sh} shards")
         self.log(f"open: {corpus.n} docs at {self.data_dir}, commit "
@@ -201,33 +201,38 @@ class Served:
         """Every operation of the mix at every batch width the window can
         produce (requests of one operation that overlap are coalesced,
         and the executor pads a batch to a power of two), until a round
-        compiles nothing. Width 1 goes through `_search`, the entry the
-        window uses; a wider batch can only be made on purpose through
-        `_msearch`."""
-        head = json.dumps({"index": INDEX})
+        compiles nothing. An operation is warmed by the bodies the
+        corpus's shape names for it (`warm_bodies`: a fixed body itself,
+        one body of each plan shape of a drawn operation). Width 1 goes
+        through `_search`, the entry the window uses; a wider batch can
+        only be made on purpose through `_msearch`."""
+        head = json.dumps({"index": self.index})
 
-        def send(op: dict, width: int) -> None:
+        def send(name: str, body: dict, width: int) -> None:
             if width == 1:
-                st, r = self.http.call("POST", f"/{INDEX}/_search",
-                                       op["body"])
+                st, r = self.http.call("POST", f"/{self.index}/_search",
+                                       body)
                 subs = [r]
             else:
                 st, r = self.http.call(
                     "POST", "/_msearch",
-                    (f"{head}\n{json.dumps(op['body'])}\n" * width).encode())
+                    (f"{head}\n{json.dumps(body)}\n" * width).encode())
                 subs = r.get("responses", [])
             if st != 200 or len(subs) != width or any(
                     "hits" not in s for s in subs):
-                raise RuntimeError(f"warm-up {op['name']} x{width}: {st} "
+                raise RuntimeError(f"warm-up {name} x{width}: {st} "
                                    f"{json.dumps(r)[:300]}")
 
+        warmed = [(op["name"], body) for op in mix["operations"]
+                  for body in self.corpus.warm_bodies(op)]
         for rnd in range(max_rounds):
             before = clock.compiles
             t = time.perf_counter()
-            for op in mix["operations"]:
+            for name, body in warmed:
                 for width in mix["warm_widths"]:
-                    send(op, width)
-            self.log(f"warm: round {rnd}, {clock.compiles - before} compiles,"
+                    send(name, body, width)
+            self.log(f"warm: round {rnd}, {len(warmed)} bodies, "
+                     f"{clock.compiles - before} compiles,"
                      f" {time.perf_counter() - t:.1f}s")
             if clock.compiles == before:
                 return

@@ -10,6 +10,12 @@ sorted index or postings would let a range or a term read less; the
 count is of the columnar pass the program makes today, and a later
 change that reads less shows as a higher share, up to the point where
 the function has to be counted anew.
+
+A `match` or a `phrase` on a text field reads postings and norms, not a
+column: this function counts none of that, and says so. The metric that
+reads it lists the cells it is read in (`column_scan_roofline`'s
+`workloads` in `BENCHMARK.json`); the fused engines' own operations and
+bytes are a function and a metric of their own.
 """
 
 from __future__ import annotations
@@ -25,6 +31,12 @@ def field_type(mappings: dict, field: str) -> str:
 
 
 def columns_read(spec: dict) -> list[str]:
+    for c in spec["clauses"]:
+        if "match" in c or "phrase" in c:
+            raise ValueError(
+                f"workbytes counts columnar passes only: a match or a phrase "
+                f"on [{c['field']}] reads postings, which it does not count; "
+                f"keep this cell off column_scan_roofline's workloads")
     fields = [c["field"] for c in spec["clauses"]]
     if spec.get("sort"):
         fields.append(spec["sort"]["field"])

@@ -39,7 +39,9 @@ sys.path.insert(0, ROOT)
 import numpy as np  # noqa: E402
 
 from harness import corpus as C  # noqa: E402
-from harness.readers import latencies_ms  # noqa: E402
+from harness.loadgen import plan_queries  # noqa: E402
+from harness.readers import latencies_ms, spec_of  # noqa: E402
+
 
 def read_json(*parts: str) -> dict:
     with open(os.path.join(*parts)) as f:
@@ -54,12 +56,16 @@ def find_cell(bench: dict, name: str) -> dict:
 
 
 def resolve_cell(name: str) -> tuple[dict, dict, dict, dict]:
-    """BENCHMARK.json, the cell's entry, its configuration and its
-    traffic mix with the cell's own numbers laid over it: each a file
-    found by the name in the entry."""
+    """BENCHMARK.json, the cell's entry, its configuration (the `file`
+    of its entry under `configs`; `path` is added to it, for the loader)
+    and its traffic mix with the cell's own numbers laid over it: each a
+    file found by the name in the entry."""
     bench = read_json(ROOT, "BENCHMARK.json")
     cell = find_cell(bench, name)
-    config = read_json(HERE, "configs", cell["config"] + ".json")
+    path = os.path.join(ROOT, *next(
+        c["file"] for c in bench["configs"]
+        if c["name"] == cell["config"]).split("/"))
+    config = dict(read_json(path), path=path)
     mix = read_json(HERE, "traffic", cell["traffic"] + ".json")
     own = os.path.join(HERE, "cells", cell["name"] + ".json")
     if os.path.exists(own):
@@ -99,15 +105,14 @@ def end_to_end(requests: list[dict]) -> dict:
 
 
 def compare_all(corpus, mix: dict, requests: list[dict]) -> dict:
-    """Every answer of the window against the plain reference."""
-    specs = {op["name"]: op["spec"] for op in mix["operations"]}
+    """Every answer of the window against the plain reference, each with
+    the spec of its own query, query by query: the reference then works
+    each query out once and has to keep few."""
+    spec = spec_of(mix)
     ref = C.Reference(corpus)
-    return C.fold([ref.compare(specs[r["op"]], r["digest"])
-                   for r in requests if r["ok"]])
-
-
-def judge(numbers: dict, limits: dict) -> bool:
-    return all(numbers[k] <= limits[k] for k in C.COMPARED)
+    return C.fold([ref.compare(spec(r), r["digest"]) for r in sorted(
+        (r for r in requests if r["ok"]),
+        key=lambda r: (r["op"], r.get("query", 0)))])
 
 
 @dataclasses.dataclass
@@ -254,12 +259,12 @@ def main(argv=None) -> int:
         f"seed {args.seed}, {args.seconds}s, trace {args.trace}, "
         f"compile cache {cache_dir}")
 
-    run = Run(mix=mix, peaks=peaks_all.get(device["kind"]), docs=docs,
-              config=config)
     clock = S.CompileClock()
     t = time.perf_counter()
-    corpus = C.Corpus(docs, args.seed, config["number_of_shards"],
-                      config["corpus"])
+    corpus = C.corpus_of(config, docs, args.seed)
+    mix = plan_queries(mix, corpus, args.seed, args.seconds)
+    run = Run(mix=mix, peaks=peaks_all.get(device["kind"]), docs=docs,
+              config=config)
     log(f"corpus: {docs} docs from seed {args.seed} in "
         f"{time.perf_counter() - t:.1f}s")
     with S.Served(config, args.seed, docs, log=log) as sv:
@@ -321,7 +326,7 @@ def main(argv=None) -> int:
     attempted = len(run.requests)
     failed = attempted - len(run.answered())
     limits = config["limits"]
-    correct = attempted > 0 and failed == 0 and judge(numbers, limits)
+    correct = attempted > 0 and failed == 0 and C.judge(numbers, limits)
     log(f"compare: {attempted - failed} of {attempted} answers against the "
         f"reference in {time.perf_counter() - t:.1f}s")
     for r in [r for r in run.requests if not r["ok"]][:5]:

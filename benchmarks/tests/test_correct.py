@@ -119,9 +119,10 @@ def test_an_altered_answer_is_caught(corpus, fault, op, number):
 
 @pytest.fixture
 def cell(request, monkeypatch):
-    """A cell by name. `http_logs-5shard.track-searches` is not in
-    BENCHMARK.json yet (PERF.md, Open questions): its entry is laid over
-    what the harness reads, so that the merge path is still rehearsed."""
+    """A cell by name. Both are in BENCHMARK.json (the 5-shard cell
+    since PR 28); a name that is not has its entry laid over what the
+    harness reads (`test_passages.py` does the same with a configuration
+    and a mix of its own)."""
     name = request.param
     plain = R.read_json
 

@@ -82,7 +82,9 @@ EXPECTED = {
 
 def test_the_twelve_are_the_ones_benchmark_json_added():
     bench = R.read_json(R.ROOT, "BENCHMARK.json")
-    assert [m["name"] for m in bench["per_layer"]][-12:] == list(EXPECTED)
+    # found by name: later PRs append their own entries after these
+    assert [m["name"] for m in bench["per_layer"]
+            if m["name"] in EXPECTED] == list(EXPECTED)
     assert set(SPANS) | set(WAITS) == set(PER_SEARCH)
 
 

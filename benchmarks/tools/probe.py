@@ -37,16 +37,18 @@ sys.path.insert(0, os.path.dirname(HERE))
 
 import run as R  # noqa: E402
 from harness import corpus as C  # noqa: E402
+from harness.loadgen import plan_queries  # noqa: E402
 
 
-def control_numbers(corpus: C.Corpus, mix: dict) -> dict:
+def control_numbers(corpus: C.Records, mix: dict) -> dict:
     """The numbers `correct` compares, read from the control's answers
-    to the mix's operations."""
+    to the mix's queries: each operation's one, or every planned query
+    of a drawn operation."""
     bf16 = C.bfloat16()
     ref = C.Reference(corpus)
-    return C.fold([ref.compare(op["spec"],
-                               C.answer_from(corpus, op["spec"], bf16))
-                   for op in mix["operations"]])
+    return C.fold([ref.compare(spec, C.answer_from(corpus, spec, bf16))
+                   for op in mix["operations"]
+                   for spec in op.get("specs") or [op["spec"]]])
 
 
 def main(argv=None) -> int:
@@ -67,10 +69,11 @@ def main(argv=None) -> int:
     seeds = [int(s) for s in args.seeds.split(",") if s] or [args.seed]
     if args.mode == "control":
         for seed in seeds:
-            corpus = C.Corpus(docs, seed, config["number_of_shards"],
-                              config["corpus"])
+            corpus = C.corpus_of(config, docs, seed)
             print(json.dumps({"seed": seed, "docs": docs, "control_bf16":
-                              control_numbers(corpus, mix)}), flush=True)
+                              control_numbers(corpus, plan_queries(
+                                  mix, corpus, seed, args.seconds))}),
+                  flush=True)
         return 0
     from harness import served as S
     for seed in seeds:      # each in a process of its own, before JAX here
@@ -85,19 +88,21 @@ def main(argv=None) -> int:
     clock = S.CompileClock()
     for seed in seeds:
         t = time.perf_counter()
-        corpus = C.Corpus(docs, seed, config["number_of_shards"],
-                          config["corpus"])
+        corpus = C.corpus_of(config, docs, seed)
+        planned = plan_queries(mix, corpus, seed, args.seconds)
         with S.Served(config, seed, docs, keep_data=bool(args.keep)) as sv:
             sv.open(corpus)
-            sv.warm(mix, clock)
+            sv.warm(planned, clock)
             setup = time.perf_counter() - t
             if args.mode == "sweep":
-                for op in mix["operations"]:
+                for op in planned["operations"]:
                     took = []
-                    for _ in range(8):
+                    bodies = corpus.warm_bodies(op)
+                    for i in range(8):
                         t1 = time.perf_counter()
                         _st, r = sv.http.call(
-                            "POST", f"/{sv.index}/_search", op["body"])
+                            "POST", f"/{sv.index}/_search",
+                            bodies[i % len(bodies)])
                         took.append((1e3 * (time.perf_counter() - t1),
                                      r.get("took")))
                     print(json.dumps({
@@ -106,8 +111,9 @@ def main(argv=None) -> int:
                         "took_ms": statistics.median(x for _, x in took)}),
                         flush=True)
                 for scale in [float(x) for x in args.scales.split(",")]:
-                    gen, _, _ = R.drive(sv, dict(mix, rate_scale=scale), seed,
-                                        args.seconds, 0.0)
+                    gen, _, _ = R.drive(sv, plan_queries(
+                        dict(mix, rate_scale=scale), corpus, seed,
+                        args.seconds), seed, args.seconds, 0.0)
                     reqs = gen["requests"]
                     lat = [1e3 * (r["done"] - r["due"]) for r in reqs]
                     q = max(1, len(lat) // 4)
@@ -126,14 +132,14 @@ def main(argv=None) -> int:
                         "late_ms": gen["late_ms"],
                         "compiles": clock.compiles}), flush=True)
                 continue
-            gen, _, _ = R.drive(sv, mix, seed, args.seconds, 0.0)
+            gen, _, _ = R.drive(sv, planned, seed, args.seconds, 0.0)
         print(json.dumps({
             "device": tag, "seed": seed, "docs": docs, "setup_s": setup,
             "requests": len(gen["requests"]),
             "failed": sum(not r["ok"] for r in gen["requests"]),
             "window": R.end_to_end(gen["requests"]),
-            "program": R.compare_all(corpus, mix, gen["requests"]),
-            "control_bf16": control_numbers(corpus, mix)}), flush=True)
+            "program": R.compare_all(corpus, planned, gen["requests"]),
+            "control_bf16": control_numbers(corpus, planned)}), flush=True)
     return 0
 
 
