@@ -390,8 +390,8 @@ def pack_layout_device(pf, cap: int, imps: np.ndarray) -> None:
     Raises on any device error; the caller's seam falls back to the
     host loops."""
     from .segment import (
-        BLOCK, MAX_FWD_SLOTS, TILE_SUMMARY_BUDGET, next_pow2,
-        score_tile_size,
+        BLOCK, MAX_FWD_SLOTS, TileSummary, next_pow2, score_tile_size,
+        tile_runs, tile_summary_pad,
     )
     from ..ops import build as ob
 
@@ -444,23 +444,20 @@ def pack_layout_device(pf, cap: int, imps: np.ndarray) -> None:
     pf.fwd_imps = np.asarray(fi)
 
     tile = score_tile_size(cap)
-    if cap % tile != 0 or (tile < BLOCK and tile < cap):
+    if cap % tile != 0 or (tile < BLOCK and tile < cap) or T <= 0:
         pf.tile_max = None
         _pack_positions_device(pf, cap, n_slots)
         return
-    n_tiles = cap // tile
-    if T <= 0 or T * n_tiles > TILE_SUMMARY_BUDGET:
-        pf.tile_max = None
-        _pack_positions_device(pf, cap, n_slots)
-        return
-    term_cap = next_pow2(T, floor=8)
-    tids_p = np.full(batch_cap, term_cap, dtype=np.int32)  # pad: OOB row
-    tids_p[:nnz] = tid_pp
-    tiles_p = np.zeros(batch_cap, dtype=np.int32)
-    tiles_p[:nnz] = pf.doc_ids // tile
-    tm = ob.scatter_tile_max(tids_p, tiles_p, imps_p,
-                             term_cap=term_cap, n_tiles=n_tiles)
-    pf.tile_max = np.asarray(tm)[:T].copy()
+    grid = cap // tile
+    _order, _heads, run, start, run_tiles = tile_runs(
+        tid_pp, pf.doc_ids // tile, T, grid)
+    entry_cap = next_pow2(len(run_tiles), floor=BLOCK)
+    run_p = np.full(batch_cap, entry_cap, dtype=np.int32)  # pad: OOB entry
+    run_p[:nnz] = run
+    vals = ob.scatter_tile_max(run_p, imps_p, entry_cap=entry_cap)
+    pf.tile_max = TileSummary(
+        start, run_tiles, np.asarray(vals)[:len(run_tiles)], grid
+    ).padded(T, tile_summary_pad(len(run_tiles), grid))
     _pack_positions_device(pf, cap, n_slots)
 
 

@@ -52,7 +52,6 @@ MAX_FWD_SLOTS = 256  # forward-index width limit (beyond: scatter path)
 # tiles whose bound cannot beat the running top-k threshold are skipped
 # by the fused score+top-k kernels (ops/scoring.py, ops/pallas_scoring.py).
 SCORE_TILE = 1024           # docs per pruning tile (lane-width multiple)
-TILE_SUMMARY_BUDGET = 1 << 24  # max T * n_tiles elements (64MB f32)
 
 # Lucene BM25Similarity defaults (ref: index/similarity/BM25SimilarityProvider.java)
 BM25_K1 = 1.2
@@ -106,33 +105,144 @@ def score_tile_size(cap: int) -> int:
     return math.gcd(cap, SCORE_TILE)
 
 
-def build_tile_max(fwd_tids: np.ndarray, fwd_imps: np.ndarray,
-                   n_terms: int, cap: int,
-                   tile: int | None = None) -> np.ndarray | None:
-    """[cap, L] forward index -> [T, n_tiles] per-(term, doc-tile) max
-    impact, the block-max summary consumed by the fused score+top-k
-    kernels. None when there are no terms or the summary would exceed
-    TILE_SUMMARY_BUDGET elements (the pruning win never justifies an
-    HBM column bigger than the corpus slice it prunes)."""
+@dataclass(frozen=True, eq=False)
+class TileSummary:
+    """The block-max summary of one text field: for each term, the
+    tiles it occurs in and its largest impact there, as a CSR over the
+    (term, tile) pairs that occur. Its bytes follow the postings (a
+    posting makes at most one pair), not terms x tiles, so it exists at
+    any vocabulary.
+
+    Row t is `tiles[start[t]:start[t + 1]]` (ascending, distinct) with
+    `vals` beside it; a tile that is not in the row holds no posting of
+    the term and bounds to 0, so `row > 0` is exactly "the term occurs
+    in the tile". `tiles` and `vals` are padded to at least
+    `start[-1] + grid` entries (tile = grid, value 0), so a reader may
+    take a window of `grid` entries at any row's start. `cols`, where
+    set, names the tiles of the grid a reader's rows are cut to (the
+    tiered walk's compacted chunks; a column outside the grid reads
+    0). ops/scoring.tile_max_rows reads it on the device, `rows`
+    here on the host."""
+
+    start: np.ndarray                      # int32 [T + 1]
+    tiles: np.ndarray                      # int32 [>= E + grid]
+    vals: np.ndarray                       # float32, as `tiles`
+    grid: int                              # tiles of the pack's grid
+    cols: np.ndarray | None = None         # int32 [n] tiles of the grid
+
+    @property
+    def n_tiles(self) -> int:
+        """The width a reader's rows come out at."""
+        return self.grid if self.cols is None else self.cols.shape[-1]
+
+    @property
+    def nbytes(self) -> int:
+        return self.start.nbytes + self.tiles.nbytes + self.vals.nbytes
+
+    @property
+    def entries(self) -> int:
+        """(term, tile) pairs that occur."""
+        return int(self.start[-1])
+
+    def take(self, cols) -> "TileSummary":
+        return TileSummary(self.start, self.tiles, self.vals, self.grid,
+                           cols)
+
+    def padded(self, n_terms: int, n_entries: int) -> "TileSummary":
+        """The same summary with term rows up to `n_terms` (empty rows:
+        an absent term bounds to 0 and can never un-prune a tile) and
+        `n_entries` stored entries, for shapes that several packs
+        share."""
+        start = np.concatenate([self.start, np.full(
+            max(n_terms + 1 - len(self.start), 0), self.start[-1],
+            np.int32)])
+        more = max(n_entries - len(self.tiles), 0)
+        return TileSummary(
+            start,
+            np.concatenate([self.tiles, np.full(more, self.grid, np.int32)]),
+            np.concatenate([self.vals, np.zeros(more, np.float32)]),
+            self.grid, self.cols)
+
+    def rows(self, tids: np.ndarray) -> np.ndarray:
+        """[n] term ids -> float32 [n, n_tiles] rows, on the host: what
+        the dense [T, n_tiles] array held at `tile_max[tids]`."""
+        tids = np.asarray(tids)
+        out = np.zeros((tids.shape[0], self.grid), np.float32)
+        for i, t in enumerate(tids):
+            lo, hi = int(self.start[t]), int(self.start[t + 1])
+            out[i, self.tiles[lo:hi]] = self.vals[lo:hi]
+        if self.cols is None:
+            return out
+        return np.concatenate([out, np.zeros_like(out[:, :1])], axis=1)[
+            :, np.minimum(self.cols, self.grid)]
+
+    def dense(self) -> np.ndarray:
+        """float32 [T, n_tiles]: every row (tests and small packs)."""
+        return self.rows(np.arange(len(self.start) - 1))
+
+
+def tile_runs(tids: np.ndarray, tiles: np.ndarray, n_terms: int,
+              grid: int) -> tuple[np.ndarray, np.ndarray, np.ndarray,
+                                  np.ndarray, np.ndarray]:
+    """The integer half of a TileSummary build, shared by the host and
+    the device builder: postings' (term, tile) -> (order, heads, run,
+    start, run_tiles). `order` sorts the postings by (term, tile) and
+    `heads` are the positions in that order at which an entry's run of
+    postings begins; `run[i]` is the entry posting i falls in, in the
+    postings' own order; `start` is the CSR over terms and `run_tiles`
+    each entry's tile. The float half is a max of the impacts over each
+    run, which is order-free, so both builders give the same bytes."""
+    key = tids.astype(np.int64) * grid + tiles
+    order = np.argsort(key, kind="stable")
+    key_s = key[order]
+    head = np.ones(len(key_s), bool)
+    head[1:] = key_s[1:] != key_s[:-1]
+    run = np.empty(len(key_s), np.int64)
+    run[order] = np.cumsum(head) - 1
+    keys = key_s[head]
+    start = np.zeros(n_terms + 1, np.int32)
+    np.cumsum(np.bincount(keys // grid, minlength=n_terms)[:n_terms],
+              out=start[1:])
+    return (order, np.flatnonzero(head), run, start,
+            (keys % grid).astype(np.int32))
+
+
+def tile_summary_pad(entries: int, grid: int) -> int:
+    """Stored entries of a summary with `entries` pairs: the window pad
+    (see TileSummary), rounded up so that nearby packs share shapes."""
+    return -(-(entries + grid) // 1024) * 1024
+
+
+def tile_summary(tids: np.ndarray, docs: np.ndarray, imps: np.ndarray,
+                 n_terms: int, cap: int,
+                 tile: int | None = None) -> TileSummary | None:
+    """Postings (term, doc, impact), in any order -> the block-max
+    summary consumed by the fused score+top-k kernels: the max impact
+    of each (term, doc-tile) pair that occurs. None when there are no
+    terms or the tile width is degenerate."""
     if tile is None:
         tile = score_tile_size(cap)
     # degenerate widths (below the lane width, e.g. from an odd-factor
     # cap) would build huge summaries that prune nothing useful
-    if cap % tile != 0 or (tile < BLOCK and tile < cap):
+    if cap % tile != 0 or (tile < BLOCK and tile < cap) or n_terms <= 0:
         return None
-    n_tiles = cap // tile
-    if n_terms <= 0 or n_terms * n_tiles > TILE_SUMMARY_BUDGET:
-        return None
-    out = np.zeros((n_terms, n_tiles), dtype=np.float32)
-    # one tile at a time: the transient (mask + fancy-index copies) is
-    # a [tile, L] slice, not a second full-size copy of the forward
-    # index alongside the one already resident at pack time
-    for j in range(n_tiles):
-        tids = fwd_tids[j * tile: (j + 1) * tile].ravel()
-        imps = fwd_imps[j * tile: (j + 1) * tile].ravel()
-        ok = tids >= 0
-        np.maximum.at(out[:, j], tids[ok], imps[ok])
-    return out
+    grid = cap // tile
+    order, heads, _run, start, run_tiles = tile_runs(
+        tids, docs // tile, n_terms, grid)
+    vals = (np.maximum.reduceat(imps[order], heads) if len(heads)
+            else np.zeros(0, np.float32))
+    return TileSummary(start, run_tiles, vals.astype(np.float32),
+                       grid).padded(n_terms,
+                                    tile_summary_pad(len(vals), grid))
+
+
+def build_tile_max(fwd_tids: np.ndarray, fwd_imps: np.ndarray,
+                   n_terms: int, cap: int,
+                   tile: int | None = None) -> TileSummary | None:
+    """tile_summary of a [cap, L] forward index (tid pad -1)."""
+    docs, slots = np.nonzero(fwd_tids[:cap] >= 0)
+    return tile_summary(fwd_tids[docs, slots], docs, fwd_imps[docs, slots],
+                        n_terms, cap, tile)
 
 
 def build_tile_minmax(values: np.ndarray, exists: np.ndarray, cap: int,
@@ -213,10 +323,10 @@ class PostingsField:
     # which vectorizes on the VPU with NO scatter. tid pad = -1, imp pad 0.
     fwd_tids: np.ndarray = dc_field(default=None, repr=False)    # int32 [cap, L]
     fwd_imps: np.ndarray = dc_field(default=None, repr=False)    # float32 [cap, L]
-    # block-max summary for the fused score+top-k path: tile_max[t, j] =
-    # max impact of term t among docs in tile j (SCORE_TILE-doc tiles).
-    # None when the field has no forward index or exceeds the budget.
-    tile_max: np.ndarray = dc_field(default=None, repr=False)    # f32 [T, J]
+    # block-max summary for the fused score+top-k path: the max impact
+    # of each term among the docs of each SCORE_TILE-doc tile it occurs
+    # in. None when the field has no forward index.
+    tile_max: TileSummary = dc_field(default=None, repr=False)
     # positional pack (third eager column family; device phrase/span/
     # BM25F — ops/scoring positional clause kinds). fwd_pos is forward-
     # aligned with fwd_tids: positions of the term in slot l of doc d
@@ -1017,7 +1127,9 @@ def _pack_layout_host(pf: PostingsField, cap: int,
             slot[d_slice] = j + 1
     pf.fwd_tids = fwd_tids
     pf.fwd_imps = fwd_imps
-    pf.tile_max = build_tile_max(fwd_tids, fwd_imps, T, cap)
+    pf.tile_max = tile_summary(
+        np.repeat(np.arange(T, dtype=np.int64), np.diff(pf.indptr)),
+        pf.doc_ids, imps, T, cap)
     pack_positions(pf, cap)
 
 
@@ -1116,17 +1228,17 @@ def pad_delta_shapes(seg: Segment) -> Segment:
     shape bucket — stays constant while the delta grows within a
     bucket. Capacity, forward width L, and block counts are already
     pow2; term count T was the one content-proportional shape left.
-    Padded tile_max rows carry zero impact (an absent term bounds to 0
-    and can never un-prune a tile — the PackedShards convention);
+    Padded tile_max rows are empty (an absent term bounds to 0 and can
+    never un-prune a tile — the PackedShards convention) and its stored
+    entries are bucketed the same way;
     padded block_start entries repeat the final block (zero postings).
     Mutates and returns `seg`."""
     for pf in seg.text.values():
         T = len(pf.terms)
         t_pad = next_pow2(max(T, 1), floor=8)
-        if pf.tile_max is not None and pf.tile_max.shape[0] < t_pad:
-            pad = np.zeros((t_pad - pf.tile_max.shape[0],
-                            pf.tile_max.shape[1]), np.float32)
-            pf.tile_max = np.concatenate([pf.tile_max, pad], axis=0)
+        if pf.tile_max is not None:
+            pf.tile_max = pf.tile_max.padded(
+                t_pad, next_pow2(len(pf.tile_max.tiles)))
         if pf.block_start is not None and len(pf.block_start) < t_pad + 1:
             pf.block_start = np.concatenate(
                 [pf.block_start,

@@ -173,17 +173,18 @@ def scatter_positions(docs: jnp.ndarray, cols: jnp.ndarray,
     return fp.at[docs, cols].set(deltas, mode="drop")
 
 
-@partial(jax.jit, static_argnames=("term_cap", "n_tiles"))
-def scatter_tile_max(tids: jnp.ndarray, tiles: jnp.ndarray,
-                     imps: jnp.ndarray, *, term_cap: int, n_tiles: int):
-    """build_tile_max as one scatter-max: out[t, doc // tile] =
-    max impact of t's postings in that tile. Max is order-free, so the
-    result is byte-equal to the host's np.maximum.at over the forward
-    index (same value multiset per cell, zeros elsewhere). Pads carry
-    tid = term_cap → dropped; the host slices rows [:n_terms].
+@partial(jax.jit, static_argnames=("entry_cap",))
+def scatter_tile_max(runs: jnp.ndarray, imps: jnp.ndarray, *,
+                     entry_cap: int):
+    """The float half of build_tile_max as one scatter-max: out[e] =
+    max impact of the postings whose (term, tile) run is entry e
+    (index/segment.tile_runs numbers them). Max is order-free, so the
+    result is byte-equal to the host's np.maximum.reduceat over the
+    sorted postings (same value multiset per entry, zeros in the pad).
+    Pads carry run = entry_cap → dropped; the host slices [:entries].
     """
-    out = jnp.zeros((term_cap, n_tiles), jnp.float32)
-    return out.at[tids, tiles].max(imps, mode="drop")
+    out = jnp.zeros((entry_cap,), jnp.float32)
+    return out.at[runs].max(imps, mode="drop")
 
 
 @partial(jax.jit, static_argnames=("n_tiles",))

@@ -167,10 +167,14 @@ def _scatter_kernel(cmin_ref, cmax_ref, docs_ref, vals_ref, out_ref):
                                         2)
         onehot = (local[:, :, None] == iota).astype(jnp.float32)
         # contribution[r, j] = sum_i vals[r, i] * onehot[r, i, j]
-        # (batched MXU contract over the 8 rows)
+        # (batched MXU contract over the 8 rows). HIGHEST: the MXU's
+        # default takes f32 operands in one bfloat16 pass, which cut
+        # every impact to 8 bits of mantissa (scores 3e-3 off float32
+        # BM25 on the chip, exact in interpret mode)
         contrib = jax.lax.dot_general(
             vals[:, None, :], onehot,
             dimension_numbers=(((2,), (1,)), ((0,), (0,))),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32)   # [8, 1, 128]
         out_ref[...] += contrib[:, 0, :]
 
@@ -809,7 +813,7 @@ def _bundle_pallas_walk(text_cols: dict, num_cols: dict, clauses: tuple,
     from .scoring import bundle_tile_bounds, bundle_primary_field
     cap = live.shape[0]
     field0 = bundle_primary_field(clauses)
-    n_tiles = text_cols[field0]["tile_max"].shape[1]
+    n_tiles = text_cols[field0]["tile_max"].n_tiles
     tile = cap // n_tiles
     b = msm.shape[0]
     can_match, ub = bundle_tile_bounds(clauses, cl_inputs, text_cols,
@@ -937,7 +941,7 @@ def fused_topk_bundle_pallas(text_cols: dict, num_cols: dict,
     cap = live.shape[0]
     k = min(k, cap) if init_topk is None else init_topk[0].shape[1]
     k_sel = min(k, cap)
-    n_tiles = text_cols[bundle_primary_field(clauses)]["tile_max"].shape[1]
+    n_tiles = text_cols[bundle_primary_field(clauses)]["tile_max"].n_tiles
     ck = min(k_sel, cap // n_tiles)
     cs, ci, cnt, flags, match, timed, b, btile, bp = _bundle_pallas_walk(
         text_cols, num_cols, clauses, cl_inputs, msm, boost, live,
@@ -1005,7 +1009,7 @@ def match_mask_bundle_pallas(text_cols: dict, num_cols: dict,
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
 def fused_topk_dense_pallas(fwd_tids: jax.Array, fwd_imps: jax.Array,
-                            tile_max: jax.Array, qt: jax.Array,
+                            tile_max, qt: jax.Array,
                             wq: jax.Array, live: jax.Array, k: int,
                             msm: jax.Array | None = None,
                             boost: jax.Array | None = None,

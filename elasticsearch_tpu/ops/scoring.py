@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..index.segment import BLOCK, BM25_K1
+from ..index.segment import BLOCK, BM25_K1, TileSummary
 from .topk import NEG_INF, running_topk_init, running_topk_merge
 
 
@@ -147,17 +147,45 @@ def score_terms_fused(block_docs: jax.Array, block_imps: jax.Array,
 BOUND_SLACK = 1.0 + 32 * float(jnp.finfo(jnp.float32).eps)
 
 
-def dense_tile_bounds(tile_max: jax.Array, qt: jax.Array, wq: jax.Array
+def tile_max_rows(tile_max: TileSummary, tids: jax.Array) -> jax.Array:
+    """[B] term ids -> [B, n_tiles] rows of the block-max summary: the
+    largest impact of each term in each tile, 0 where it does not occur
+    (what `tile_max[tids]` read of the dense [T, n_tiles] array). The
+    ONE reader of the stored form on the device; a term id outside the
+    dictionary reads the nearest row and the caller masks it, as
+    before. A row's entries are a window of `grid` stored entries from
+    its start, cut to its length; each tile takes the entry that names
+    it (at most one does, so the max is that entry's value, exactly)."""
+    grid = tile_max.grid
+    safe = jnp.clip(tids, 0, max(tile_max.start.shape[0] - 2, 0))
+    lo = tile_max.start[safe]
+    n = tile_max.start[safe + 1] - lo
+
+    def window(a):
+        return jax.vmap(
+            lambda s: jax.lax.dynamic_slice(a, (s,), (grid,)))(lo)
+
+    e = jnp.arange(grid, dtype=jnp.int32)
+    tiles = jnp.where(e[None, :] < n[:, None],
+                      window(tile_max.tiles), grid)           # [B, E]
+    rows = jnp.max(
+        jnp.where(tiles[:, :, None] == e[None, None, :],
+                  window(tile_max.vals)[:, :, None], 0.0), axis=1)
+    if tile_max.cols is None:
+        return rows
+    return jnp.take(rows, tile_max.cols, axis=1, mode="fill",
+                    fill_value=0.0)
+
+
+def dense_tile_bounds(tile_max: TileSummary, qt: jax.Array, wq: jax.Array
                       ) -> jax.Array:
-    """[T, J] block-max summary x [B, Q] query -> [B, J] score bounds
+    """block-max summary x [B, Q] query -> [B, J] score bounds
     (BOUND_SLACK-inflated, see above). Padded/absent terms (qt < 0)
     contribute 0, mirroring their zero-impact matches."""
     b, q_n = qt.shape
-    n_tiles = tile_max.shape[1]
-    safe = jnp.clip(qt, 0, max(tile_max.shape[0] - 1, 0))
-    ub = jnp.zeros((b, n_tiles), jnp.float32)
+    ub = jnp.zeros((b, tile_max.n_tiles), jnp.float32)
     for q in range(q_n):
-        tm = tile_max[safe[:, q]]                       # [B, J]
+        tm = tile_max_rows(tile_max, qt[:, q])          # [B, J]
         w = jnp.where(qt[:, q] >= 0, wq[:, q], 0.0)
         ub = ub + tm * w[:, None]
     return ub * jnp.float32(BOUND_SLACK)
@@ -570,6 +598,7 @@ def positional_tile_scores(kind: str, field, inp: tuple,
     return raw * pboost[:, None], freq > 0
 
 
+@jax.named_scope("tile_bounds")
 def bundle_tile_bounds(clauses: tuple, cl_inputs: tuple, text_cols: dict,
                        num_cols: dict, msm: jax.Array,
                        boost: jax.Array | None
@@ -584,9 +613,10 @@ def bundle_tile_bounds(clauses: tuple, cl_inputs: tuple, text_cols: dict,
     bounds of the scoring clauses (must + should) — a monotone upper
     bound on any doc's post-boost score — and is BOUND_SLACK-inflated
     once more on top of the per-clause inflation to absorb the extra
-    adds/muls of the multi-clause combine."""
+    adds/muls of the multi-clause combine. Its operations carry the
+    name `tile_bounds` in a device trace, apart from the walk's."""
     b = msm.shape[0]
-    n_tiles = text_cols[bundle_primary_field(clauses)]["tile_max"].shape[1]
+    n_tiles = text_cols[bundle_primary_field(clauses)]["tile_max"].n_tiles
     bound = jnp.zeros((b, n_tiles), jnp.float32)
     possible = jnp.ones((b, n_tiles), bool)
     pos_cnt = jnp.zeros((b, n_tiles), jnp.int32)
@@ -620,10 +650,9 @@ def bundle_tile_bounds(clauses: tuple, cl_inputs: tuple, text_cols: dict,
             # 1 is >= ~1e-4 at POS_CAP'd tfs).
             qt, wb, idf_sum, _slop, pboost, msm_c, boost_c = inp
             tm = text_cols[field]["tile_max"]
-            safe = jnp.clip(qt, 0, max(tm.shape[0] - 1, 0))
             pres = jnp.ones((b, n_tiles), bool)
             for i in range(qt.shape[1]):
-                pres = pres & (tm[safe[:, i]] > 0.0) \
+                pres = pres & (tile_max_rows(tm, qt[:, i]) > 0.0) \
                     & (qt[:, i] >= 0)[:, None]
             if kind.endswith(":o"):
                 ub = jnp.broadcast_to(
@@ -651,10 +680,9 @@ def bundle_tile_bounds(clauses: tuple, cl_inputs: tuple, text_cols: dict,
                 pres_t = jnp.zeros((b, n_tiles), bool)
                 for fi in range(nf):
                     tm = text_cols[field[fi]]["tile_max"]
-                    safe = jnp.clip(qt[:, fi, t], 0,
-                                    max(tm.shape[0] - 1, 0))
-                    pres_t = pres_t | ((tm[safe] > 0.0)
-                                       & (qt[:, fi, t] >= 0)[:, None])
+                    pres_t = pres_t | (
+                        (tile_max_rows(tm, qt[:, fi, t]) > 0.0)
+                        & (qt[:, fi, t] >= 0)[:, None])
                 ub = ub + jnp.where(pres_t, idf[:, t][:, None], 0.0)
                 p_any = p_any | pres_t
             ub = ub * jnp.float32(BOUND_SLACK) * pboost[:, None]
@@ -718,7 +746,7 @@ def bundle_tile_bounds_np(clauses: tuple, cl_inputs: tuple,
     correctness input."""
     b = msm.shape[0]
     field0 = bundle_primary_field(clauses)
-    n_tiles = text_tile_max[field0].shape[1]
+    n_tiles = text_tile_max[field0].n_tiles
     bound = np.zeros((b, n_tiles), np.float32)
     possible = np.ones((b, n_tiles), bool)
     pos_cnt = np.zeros((b, n_tiles), np.int32)
@@ -739,10 +767,10 @@ def bundle_tile_bounds_np(clauses: tuple, cl_inputs: tuple,
             qt, wb, idf_sum, _slop, pboost, msm_c, boost_c = (
                 np.asarray(x) for x in inp)
             tm = text_tile_max[field]
-            safe = np.clip(qt, 0, max(tm.shape[0] - 1, 0))
+            safe = np.clip(qt, 0, max(len(tm.start) - 2, 0))
             pres = np.ones((b, n_tiles), bool)
             for i in range(qt.shape[1]):
-                pres = pres & (tm[safe[:, i]] > 0.0) \
+                pres = pres & (tm.rows(safe[:, i]) > 0.0) \
                     & (qt[:, i] >= 0)[:, None]
             if kind.endswith(":o"):
                 ub = np.broadcast_to(
@@ -755,7 +783,7 @@ def bundle_tile_bounds_np(clauses: tuple, cl_inputs: tuple,
                 for i in range(qt.shape[1]):
                     w = np.where(qt[:, i] >= 0, wb[:, i],
                                  np.float32(0.0)).astype(np.float32)
-                    ub = ub + tm[safe[:, i]] * w[:, None]
+                    ub = ub + tm.rows(safe[:, i]) * w[:, None]
                 ub = ub * np.float32(BOUND_SLACK)
             ub = np.where(pres, ub, np.float32(0.0)) \
                 * pboost[:, None].astype(np.float32)
@@ -778,8 +806,8 @@ def bundle_tile_bounds_np(clauses: tuple, cl_inputs: tuple,
                 for fi in range(nf):
                     tm = text_tile_max[field[fi]]
                     safe = np.clip(qt[:, fi, t], 0,
-                                   max(tm.shape[0] - 1, 0))
-                    pres_t = pres_t | ((tm[safe] > 0.0)
+                                   max(len(tm.start) - 2, 0))
+                    pres_t = pres_t | ((tm.rows(safe) > 0.0)
                                        & (qt[:, fi, t] >= 0)[:, None])
                 ub = ub + np.where(pres_t, idf[:, t][:, None],
                                    np.float32(0.0))
@@ -797,12 +825,12 @@ def bundle_tile_bounds_np(clauses: tuple, cl_inputs: tuple,
         if kind in _DENSE_KINDS:
             qt, wq, msm_c, boost_c = (np.asarray(x) for x in inp)
             tm = text_tile_max[field]
-            safe = np.clip(qt, 0, max(tm.shape[0] - 1, 0))
+            safe = np.clip(qt, 0, max(len(tm.start) - 2, 0))
             ub = np.zeros((b, n_tiles), np.float32)
             for q in range(qt.shape[1]):
                 w = np.where(qt[:, q] >= 0, wq[:, q],
                              np.float32(0.0)).astype(np.float32)
-                ub = ub + tm[safe[:, q]] * w[:, None]
+                ub = ub + tm.rows(safe[:, q]) * w[:, None]
             ub = ub * np.float32(BOUND_SLACK)
             p = ((ub > 0.0) | (msm_c <= 0)[:, None]) \
                 & (msm_c <= 1)[:, None]
@@ -1052,7 +1080,7 @@ def match_mask_bundle_fused(text_cols: dict, num_cols: dict,
     no matching doc, so its mask rows stay zero. A `step` (see
     _stepped_tile_loop) appends the timed_out scalar to the result."""
     field0 = bundle_primary_field(clauses)
-    n_tiles = text_cols[field0]["tile_max"].shape[1]
+    n_tiles = text_cols[field0]["tile_max"].n_tiles
     cap = live.shape[0]
     tile = cap // n_tiles
     b = msm.shape[0]
@@ -1161,7 +1189,7 @@ def score_topk_bundle_fused(text_cols: dict, num_cols: dict, clauses: tuple,
     byte-for-byte. Totals/prune stats cover ONLY this walk.
     """
     field0 = bundle_primary_field(clauses)
-    n_tiles = text_cols[field0]["tile_max"].shape[1]
+    n_tiles = text_cols[field0]["tile_max"].n_tiles
     cap = live.shape[0]
     tile = cap // n_tiles
     b = msm.shape[0]
@@ -1257,7 +1285,7 @@ def score_topk_bundle_fused(text_cols: dict, num_cols: dict, clauses: tuple,
 
 
 def score_topk_dense_fused(fwd_tids: jax.Array, fwd_imps: jax.Array,
-                           tile_max: jax.Array, qt: jax.Array,
+                           tile_max: TileSummary, qt: jax.Array,
                            wq: jax.Array, live: jax.Array, k: int,
                            msm: jax.Array | None = None,
                            boost: jax.Array | None = None

@@ -45,7 +45,8 @@ from ..search.executor import (QueryBinder, finalize, eval_node,
                                eval_aggs, _agg_view_plan, _ViewMasks,
                                _bound_view_fields, _fused_plan_bundle,
                                _fused_params_ok, _bundle_pallas_reason,
-                               _bundle_pos_width, _bundle_positional,
+                               _bundle_pos_width, _bundle_fwd_width,
+                               _bundle_positional,
                                _FUSED_DENSE_KINDS, _FUSED_RANGE_KINDS,
                                _FUSED_VEC_KINDS,
                                eval_fused_topk, resolve_fused_backend,
@@ -397,6 +398,11 @@ class PackedShards:
                 # fused score+top-k op. Term rows pad with zero impact:
                 # absent terms bound to 0 and can never un-prune a tile.
                 nt = spec.text[f]["nt"]
+                # one shape for every row on every host: a posting
+                # makes at most one entry, and nb * BLOCK bounds the
+                # postings of any shard
+                ne = spec.text[f]["nb"] * BLOCK \
+                    + cap // score_tile_size(cap)
                 tms = []
                 for i in range(S):
                     tm = build_tile_max(ftids[i], fimps[i], nt, cap,
@@ -404,9 +410,10 @@ class PackedShards:
                     if tm is None:
                         tms = None
                         break
-                    tms.append(tm)
+                    tms.append(tm.padded(nt, ne))
                 if tms is not None:
-                    entry["tile_max"] = np.stack(tms)
+                    entry["tile_max"] = jax.tree_util.tree_map(
+                        lambda *xs: np.stack(xs), *tms)
             arrays["text"][f] = entry
         for f in kw_fields:
             lookup = {t: i for i, t in enumerate(self.kw_terms[f])}
@@ -1229,7 +1236,8 @@ class DistributedSearcher:
         if bundle is not None:
             ck = min(min(k, pk.cap), score_tile_size(pk.cap))
             pallas_reason = _bundle_pallas_reason(
-                bundle, (), ck, _bundle_pos_width(bundle, pk.dev["text"]))
+                bundle, (), ck, _bundle_pos_width(bundle, pk.dev["text"]),
+                _bundle_fwd_width(bundle, pk.dev["text"]))
             if pallas_reason is not None:
                 _fused_stats.record_pallas_reject(pallas_reason)
             # an SPMD program cannot wall-clock itself per host without
@@ -1444,7 +1452,7 @@ class DistributedSearcher:
         if stepped:
             f0 = next(f for _r, kd, f, _w in fused[0]
                       if kd in _FUSED_DENSE_KINDS)
-            n_tiles = pk.dev["text"][f0]["tile_max"].shape[-1]
+            n_tiles = pk.dev["text"][f0]["tile_max"].n_tiles
             chunk_tiles = max(1, -(-n_tiles // _RESIDENT_CHUNKS))
 
         gather_out = self._gather_out

@@ -70,8 +70,10 @@ from .query_dsl import (
 )
 
 _F32_MIN_WEIGHT = 1e-30  # keeps score>0 as the match signal even at boost~0
-_DENSE_GROUP_MAX = 8     # should-groups up to this many terms take the
+_DENSE_GROUP_MAX = 16    # should-groups up to this many terms take the
                          # forward-index gather path instead of scatter
+                         # (a question of ten analysed terms is a plain
+                         # match; expansions run to hundreds)
 # fused positional clause caps: n is compiled into the clause kind
 # string (phrase_pos:{n}:..., bm25f:{nf}:{nt}), so these bound the
 # distinct-kind explosion the same way _FUSED_MAX_CLAUSES bounds the
@@ -129,7 +131,7 @@ def device_arrays(segment: Segment) -> dict:
                         "lnorm": jnp.asarray(pf.lnorm)}
                        if getattr(pf, "fwd_pos", None) is not None
                        else {}),
-                    **({"tile_max": jnp.asarray(pf.tile_max)}
+                    **({"tile_max": jax.device_put(pf.tile_max)}
                        if pf.fwd_tids is not None
                        and getattr(pf, "tile_max", None) is not None
                        else {}),
@@ -175,6 +177,12 @@ def device_arrays(segment: Segment) -> dict:
                 "target": jnp.asarray(target.astype(np.int32)),
                 "is_child": jnp.asarray(segment.parent_of >= 0),
             }
+        for name, entry in dev["text"].items():
+            if "tile_max" in entry:
+                tm = segment.text[name].tile_max
+                _fused_stats.record_summary(tm.nbytes, tm.entries)
+                weakref.finalize(segment, _fused_stats.record_summary,
+                                 -tm.nbytes, -tm.entries)
         segment._device = dev  # type: ignore[attr-defined]
         segment.device_changed(rebuilt=True)
     return dev
@@ -2217,8 +2225,9 @@ from ..ops.scoring import (DENSE_CLAUSE_KINDS as _FUSED_DENSE_KINDS,
 from ..index import tiering as _tiering
 
 # compile-time unroll budget of the per-tile clause loop; plans beyond
-# it fall back rather than minting pathological programs
-_FUSED_MAX_CLAUSES = 8
+# it fall back rather than minting pathological programs (a match with
+# operator and is one must clause a term: ten for a long question)
+_FUSED_MAX_CLAUSES = 16
 
 
 def _fused_leaf_inputs(desc: tuple, params: tuple
@@ -2464,6 +2473,19 @@ def _bundle_pos_width(bundle: tuple, text_cols) -> int:
     return w
 
 
+def _bundle_fwd_width(bundle: tuple, text_cols) -> int:
+    """Widest forward index (slots a doc) among the bundle's dense
+    clause fields; text_cols as for _bundle_pos_width."""
+    w = 0
+    for _r, kd, fld, _w2 in bundle:
+        if kd in _FUSED_DENSE_KINDS:
+            c = text_cols[fld]
+            fwd = c.get("fwd_tids") if isinstance(c, dict) else c.fwd_tids
+            if fwd is not None:
+                w = max(w, fwd.shape[-1])
+    return w
+
+
 def _bundle_positional(bundle: tuple) -> bool:
     return any(isinstance(kd, str) and positional_prefix(kd)
                for _r, kd, _f, _w in bundle)
@@ -2504,6 +2526,10 @@ class _FusedScoringStats:
         self._ann_probed = 0
         self._ann_pruned = 0
         self._ann_scored = 0
+        # block-max summaries held on the device (record_summary): a
+        # gauge of what is resident, which `reset` leaves alone
+        self._summary_bytes = 0
+        self._summary_entries = 0
 
     def record_choice(self, key: tuple, backend: str, reason: str,
                       timings: dict | None = None,
@@ -2571,6 +2597,13 @@ class _FusedScoringStats:
                 self._pos_examined += float(examined)
                 self._pos_dispatches += 1
 
+    def record_summary(self, nbytes: int, entries: int) -> None:
+        """A pack's block-max summary went to the device (or, negative,
+        left it with its segment): what the stored form costs there."""
+        with self._lock:
+            self._summary_bytes += nbytes
+            self._summary_entries += entries
+
     def record_ann_prune(self, probed: int, pruned: int,
                          scored: int) -> None:
         """IVF probe counters (ops/ann.ivf_topk stats, per-(query,
@@ -2611,6 +2644,8 @@ class _FusedScoringStats:
                           "thresholded": round(self._thresholded, 3)},
                 "prune_rate": (pruned / self._examined
                                if self._examined else 0.0),
+                "summary": {"bytes": self._summary_bytes,
+                            "entries": self._summary_entries},
                 "loss_audit": {"shapes": audit, "count": len(audit)},
                 "ann": {"clusters_probed": self._ann_probed,
                         "clusters_pruned": self._ann_pruned,
@@ -2806,6 +2841,12 @@ def _pallas_coverage() -> str:
 # [tile, L*P] position ref alone approaches the VMEM budget and the
 # XLA engine (which streams the decode through HBM) wins anyway
 _POS_PALLAS_WIDTH_MAX = 4096
+# widest forward index (slots a doc) the kernel will take: its dense
+# clause compares every slot with every query term in a static unroll,
+# and at 256 slots (passages of up to 256 distinct words) the Mosaic
+# compile of any batch of two or more runs out of scoped VMEM on a v5e
+# (128 slots compile); wider packs run the XLA engine, visibly
+_FWD_PALLAS_SLOTS_MAX = 128
 
 
 def _positional_needs_xla(bundle: tuple) -> bool:
@@ -2822,7 +2863,8 @@ def _positional_needs_xla(bundle: tuple) -> bool:
 
 
 def _bundle_pallas_reason(bundle: tuple, agg_desc, ck: int,
-                          pos_width: int = 0) -> str | None:
+                          pos_width: int = 0,
+                          fwd_width: int = 0) -> str | None:
     """Why the Pallas kernel is NOT a candidate for a fused-admitted
     bundle (None = it is): reason tags feed
     nodes_stats()["fused_scoring"].admission.pallas_rejected so the
@@ -2830,7 +2872,10 @@ def _bundle_pallas_reason(bundle: tuple, agg_desc, ck: int,
     diffs. Shape reasons are computed before availability so they
     surface on every backend. pos_width is the widest positional
     field's packed L*P (0 = caller has no positional clauses or no
-    shape info — the VMEM gate is then skipped)."""
+    shape info — the VMEM gate is then skipped); fwd_width the widest
+    dense field's forward slots (_bundle_fwd_width)."""
+    if fwd_width > _FWD_PALLAS_SLOTS_MAX:
+        return "forward_width"
     if any(kd in _FUSED_VEC_KINDS for _r, kd, _f, _w in bundle):
         # the similarity-column preamble (whole-capacity MXU matmul) has
         # no kernel form yet: hybrid BM25+vector bundles run the XLA
@@ -2861,7 +2906,7 @@ def _bundle_pallas_reason(bundle: tuple, agg_desc, ck: int,
 
 
 def _bundle_pallas_ok(bundle: tuple, agg_desc, ck: int,
-                      pos_width: int = 0) -> bool:
+                      pos_width: int = 0, fwd_width: int = 0) -> bool:
     """Bundle-level Pallas candidacy: the kernel now covers the full
     bundle admission matrix — multi-text-field bundles, positional
     (phrase/span/BM25F) clause kinds, dense/numeric range filter &
@@ -2869,7 +2914,8 @@ def _bundle_pallas_ok(bundle: tuple, agg_desc, ck: int,
     grid — so candidacy reduces to availability plus the
     selection-depth and positional-VMEM caps (see _bundle_pallas_reason
     for the tags)."""
-    return _bundle_pallas_reason(bundle, agg_desc, ck, pos_width) is None
+    return _bundle_pallas_reason(bundle, agg_desc, ck, pos_width,
+                                 fwd_width) is None
 
 
 # -- persisted autotuner choices (satellite: survive restarts) --------------
@@ -3179,7 +3225,7 @@ def eval_fused_topk(seg: dict, desc: tuple, params: tuple,
                 if kd in _FUSED_RANGE_KINDS}
     if any(kd in _FUSED_VEC_KINDS for _r, kd, _f, _w in bundle):
         n_tiles = text_cols[bundle_primary_field(bundle)][
-            "tile_max"].shape[1]
+            "tile_max"].n_tiles
         cl_inputs = _vec_clause_inputs(seg, bundle, cl_inputs, n_tiles)
         # the kernel has no knn-clause form (the similarity-column
         # preamble is XLA-only); even a FORCED pallas choice demotes
@@ -3224,7 +3270,7 @@ def eval_fused_match(seg: dict, desc: tuple, params: tuple,
                 if kd in _FUSED_RANGE_KINDS}
     if any(kd in _FUSED_VEC_KINDS for _r, kd, _f, _w in bundle):
         n_tiles = text_cols[bundle_primary_field(bundle)][
-            "tile_max"].shape[1]
+            "tile_max"].n_tiles
         cl_inputs = _vec_clause_inputs(seg, bundle, cl_inputs, n_tiles)
         backend = "xla"    # no kernel form — see eval_fused_topk
     if _positional_needs_xla(bundle):
@@ -3274,7 +3320,7 @@ def _segment_body(seg: dict, params: tuple, live: jax.Array,
     if fused is not None:
         # fused transient per row — NOT the dense [*, cap]
         f0 = bundle_primary_field(fused[0])
-        n_tiles = seg["text"][f0]["tile_max"].shape[1]
+        n_tiles = seg["text"][f0]["tile_max"].n_tiles
         row_elems = _fused_row_elems(
             cap, n_tiles, k, emit_match=bool(agg_desc),
             vec_clauses=sum(kd in _FUSED_VEC_KINDS
@@ -4508,7 +4554,8 @@ def _resident_backend(segment: Segment, bundle: tuple, desc, agg_desc,
         # TUNED choices from silently losing their kernel)
         return forced
     if not _bundle_pallas_ok(bundle, agg_desc, ck,
-                             _bundle_pos_width(bundle, segment.text)):
+                             _bundle_pos_width(bundle, segment.text),
+                             _bundle_fwd_width(bundle, segment.text)):
         return "xla"                     # XLA engine either way
     tune_key = (seg_cache_key(segment), segment.capacity, desc, k_eff,
                 b_pad, bool(agg_desc))
@@ -4679,7 +4726,7 @@ def _execute_resident(segment: Segment, live, desc: tuple, params: tuple,
     k_res = min(next_pow2(max(k_eff, 1), floor=1), cap) if k_eff > 0 else 0
     fused = (bundle, backend)
     f0 = bundle_primary_field(bundle)
-    n_tiles = segment.text[f0].tile_max.shape[1]
+    n_tiles = segment.text[f0].tile_max.n_tiles
     chunk_tiles = max(1, -(-n_tiles // _RESIDENT_CHUNKS))
     n_chunks = -(-n_tiles // chunk_tiles)
     row_elems = _fused_row_elems(
@@ -4986,7 +5033,7 @@ def execute_segment_async(segment: Segment, live: np.ndarray,
             bundle = None
     if bundle is not None:
         f0 = bundle_primary_field(bundle)
-        n_tiles = segment.text[f0].tile_max.shape[1]
+        n_tiles = segment.text[f0].tile_max.n_tiles
         ck = min(k_eff, segment.capacity // n_tiles)
         fused_width = _fused_row_elems(
             segment.capacity, n_tiles, k_eff,
@@ -5071,7 +5118,8 @@ def execute_segment_async(segment: Segment, live: np.ndarray,
                         k_eff, b_pad, bool(agg_desc))
             pallas_reason = _bundle_pallas_reason(
                 fused[0], agg_desc, ck,
-                _bundle_pos_width(fused[0], segment.text))
+                _bundle_pos_width(fused[0], segment.text),
+                _bundle_fwd_width(fused[0], segment.text))
             if pallas_reason is not None:
                 _fused_stats.record_pallas_reject(pallas_reason)
 
@@ -5408,8 +5456,7 @@ def _tiered_chunk_cols(seg_res: dict, live: jax.Array, tiles_dev,
         text_cols[f] = {
             "fwd_tids": jnp.concatenate(tids_parts, axis=0),
             "fwd_imps": jnp.concatenate(imps_parts, axis=0),
-            "tile_max": jnp.take(seg_res["text"][f]["tile_max"], sane,
-                                 axis=1, mode="fill", fill_value=0.0),
+            "tile_max": seg_res["text"][f]["tile_max"].take(sane),
         }
         if f in pos_fields:
             # paged position tiles concatenate like the forward pair;
@@ -5691,7 +5738,9 @@ def _pack_resident_backend(base: Segment, delta: Segment, bundle: tuple,
         return forced
     if not _bundle_pallas_ok(bundle, agg_desc, ck,
                              max(_bundle_pos_width(bundle, base.text),
-                                 _bundle_pos_width(bundle, delta.text))):
+                                 _bundle_pos_width(bundle, delta.text)),
+                             max(_bundle_fwd_width(bundle, base.text),
+                                 _bundle_fwd_width(bundle, delta.text))):
         return "xla"
     choice = _autotune_choices.get(
         _pack_tune_key(base, delta, desc, k_eff, b_pad, bool(agg_desc)))
@@ -5758,8 +5807,8 @@ def execute_pack_async(base: Segment, delta: Segment, live_b: np.ndarray,
             or not _fused_params_ok(desc, params_d, bundle):
         return None
     f0 = bundle_primary_field(bundle)
-    n_tiles_b = base.text[f0].tile_max.shape[1]
-    n_tiles_d = delta.text[f0].tile_max.shape[1]
+    n_tiles_b = base.text[f0].tile_max.n_tiles
+    n_tiles_d = delta.text[f0].tile_max.n_tiles
     ck = max(min(k_eff, cap_b // n_tiles_b),
              min(k_eff, cap_d // n_tiles_d))
     n_vec = sum(kd in _FUSED_VEC_KINDS for _r, kd, _f, _w in bundle)
@@ -5807,7 +5856,9 @@ def execute_pack_async(base: Segment, delta: Segment, live_b: np.ndarray,
         pallas_reason = _bundle_pallas_reason(
             bundle, agg_desc, ck,
             max(_bundle_pos_width(bundle, base.text),
-                _bundle_pos_width(bundle, delta.text)))
+                _bundle_pos_width(bundle, delta.text)),
+            max(_bundle_fwd_width(bundle, base.text),
+                _bundle_fwd_width(bundle, delta.text)))
         if pallas_reason is not None:
             _fused_stats.record_pallas_reject(pallas_reason)
 
@@ -5904,8 +5955,8 @@ def _execute_pack_resident(base: Segment, delta: Segment, live_b, live_d,
              if k_eff > 0 else 0)
     fused = (bundle, backend)
     f0 = bundle_primary_field(bundle)
-    n_tiles_b = base.text[f0].tile_max.shape[1]
-    n_tiles_d = delta.text[f0].tile_max.shape[1]
+    n_tiles_b = base.text[f0].tile_max.n_tiles
+    n_tiles_d = delta.text[f0].tile_max.n_tiles
     chunk_tiles = max(1, -(-n_tiles_b // _RESIDENT_CHUNKS))
     n_chunks = -(-n_tiles_b // chunk_tiles)
     row_elems = (_fused_row_elems(cap_b, n_tiles_b, k_res,

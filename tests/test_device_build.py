@@ -66,6 +66,12 @@ def _builder(n=80, seed=0, svc=None):
 
 
 def _np_eq(a, b):
+    if dataclasses.is_dataclass(a) and dataclasses.is_dataclass(b):
+        # a column's own record (the block-max TileSummary): field by
+        # field, the same bytes
+        return type(a) is type(b) and all(
+            _np_eq(getattr(a, f.name), getattr(b, f.name))
+            for f in dataclasses.fields(a))
     if isinstance(a, np.ndarray) or isinstance(b, np.ndarray):
         if not (isinstance(a, np.ndarray) and isinstance(b, np.ndarray)):
             return False

@@ -22,6 +22,7 @@ import numpy as np
 import pytest
 
 jax = pytest.importorskip("jax")
+import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
 from elasticsearch_tpu.index.segment import build_tile_max  # noqa: E402
@@ -73,7 +74,8 @@ def _case(rng, cap=2048, slots=4, n_terms=40, b=3, q=3, tile=512,
     wq[qt < 0] = 0.0
     live = np.ones(cap, bool) if seed_live is None else seed_live
     tm = build_tile_max(fwd_tids, fwd_imps, n_terms, cap, tile=tile)
-    assert tm is not None and tm.shape == (n_terms, cap // tile)
+    assert tm is not None and tm.n_tiles == cap // tile \
+        and len(tm.start) == n_terms + 1
     return fwd_tids, fwd_imps, tm, qt, wq, live
 
 
@@ -82,7 +84,7 @@ def _assert_tri_parity(fwd_tids, fwd_imps, tm, qt, wq, live, k,
     ref_s, ref_i, ref_t = _reference_topk(fwd_tids, fwd_imps, qt, wq,
                                           live, k, msm, boost)
     args = (jnp.asarray(fwd_tids), jnp.asarray(fwd_imps),
-            jnp.asarray(tm), jnp.asarray(qt), jnp.asarray(wq),
+            jax.device_put(tm), jnp.asarray(qt), jnp.asarray(wq),
             jnp.asarray(live), min(k, fwd_tids.shape[0]))
     kw = {"msm": None if msm is None else jnp.asarray(msm),
           "boost": None if boost is None else jnp.asarray(boost)}
@@ -132,7 +134,7 @@ class TestBackendParity:
         wq[:, 0] = 1.0
         _assert_tri_parity(fwd_tids, fwd_imps, tm, qt, wq, live, k=5)
         _, _, _, pruned = (np.asarray(x) for x in score_topk_dense_fused(
-            jnp.asarray(fwd_tids), jnp.asarray(fwd_imps), jnp.asarray(tm),
+            jnp.asarray(fwd_tids), jnp.asarray(fwd_imps), jax.device_put(tm),
             jnp.asarray(qt), jnp.asarray(wq), jnp.asarray(live), 5))
         assert int(pruned[0]) == 3  # 3 of 4 tiles hard-skipped
 
@@ -345,7 +347,7 @@ class TestBundleOpsParity:
     def _check(self, rng, roles, k=10, msm_max=3):
         fwd_tids, fwd_imps, tm, _qt, _wq, live = _case(rng)
         b = 4
-        n_terms = tm.shape[0]
+        n_terms = len(tm.start) - 1
         wrapped = rng.random(len(roles)) < 0.5
         clauses, cl_inputs = _random_bundle(rng, b, n_terms, roles,
                                             wrapped)
@@ -359,7 +361,7 @@ class TestBundleOpsParity:
                          for inp in cl_inputs)
         text_cols = {"f": {"fwd_tids": jnp.asarray(fwd_tids),
                            "fwd_imps": jnp.asarray(fwd_imps),
-                           "tile_max": jnp.asarray(tm)}}
+                           "tile_max": jax.device_put(tm)}}
         got = {}
         got["xla"] = score_topk_bundle_fused(
             text_cols, {}, clauses, j_inputs, jnp.asarray(msm),
@@ -392,7 +394,7 @@ class TestBundleOpsParity:
         from elasticsearch_tpu.index.segment import build_tile_minmax
         fwd_tids, fwd_imps, tm, _qt, _wq, live = _case(rng)
         cap = fwd_tids.shape[0]
-        b, n_terms = 3, tm.shape[0]
+        b, n_terms = 3, len(tm.start) - 1
         clauses, cl_inputs = _random_bundle(
             rng, b, n_terms, ("must", "should"), [False, True])
         vals = np.arange(cap, dtype=np.int32)
@@ -414,7 +416,7 @@ class TestBundleOpsParity:
                           "tile_hi": jnp.asarray(thi)}}
         text_cols = {"f": {"fwd_tids": jnp.asarray(fwd_tids),
                            "fwd_imps": jnp.asarray(fwd_imps),
-                           "tile_max": jnp.asarray(tm)}}
+                           "tile_max": jax.device_put(tm)}}
         j_inputs = tuple(tuple(jnp.asarray(a) for a in inp)
                          for inp in cl_inputs)
         g_s, g_i, g_t, pruned, match = score_topk_bundle_fused(
@@ -456,7 +458,7 @@ def _two_field_case(rng, cap=2048, tile=512):
         tm = build_tile_max(tids, imps, n_terms, cap, tile=tile)
         return {"fwd_tids": jnp.asarray(tids),
                 "fwd_imps": jnp.asarray(imps),
-                "tile_max": jnp.asarray(tm)}, (tids, imps)
+                "tile_max": jax.device_put(tm)}, (tids, imps)
 
     f_dev, f_np = field()
     g_dev, g_np = field(slots=3)
@@ -600,7 +602,7 @@ class TestPallasFullBundleParity:
         tm = build_tile_max(fwd_tids, fwd_imps, 4, cap, tile=tile)
         text_cols = {"f": {"fwd_tids": jnp.asarray(fwd_tids),
                            "fwd_imps": jnp.asarray(fwd_imps),
-                           "tile_max": jnp.asarray(tm)}}
+                           "tile_max": jax.device_put(tm)}}
         clauses = (("should", "terms_dense", "f", False),)
         b = 2
         cl_inputs = ((jnp.zeros((b, 1), jnp.int32),
@@ -1139,6 +1141,180 @@ class TestRejectionCounters:
                        "query": {"match": {"message": "w005"}}})
         rej = ex.fused_scoring_stats()["admission"]["pallas_rejected"]
         assert rej.get("ck_cap", 0) >= 1, rej
+
+
+def _dense_summary(fwd_tids, fwd_imps, n_terms, cap, tile):
+    """What the summary held before it was a CSR: the dense
+    [terms, tiles] array, one np.maximum.at a tile."""
+    out = np.zeros((n_terms, cap // tile), np.float32)
+    for j in range(cap // tile):
+        tids = fwd_tids[j * tile:(j + 1) * tile].ravel()
+        ok = tids >= 0
+        np.maximum.at(out[:, j], tids[ok],
+                      fwd_imps[j * tile:(j + 1) * tile].ravel()[ok])
+    return out
+
+
+@jax.jit
+def _dense_bounds(dense, qt, wq):
+    """dense_tile_bounds as it read the dense array."""
+    from elasticsearch_tpu.ops.scoring import BOUND_SLACK
+    safe = jnp.clip(qt, 0, dense.shape[0] - 1)
+    ub = jnp.zeros((qt.shape[0], dense.shape[1]), jnp.float32)
+    for q in range(qt.shape[1]):
+        w = jnp.where(qt[:, q] >= 0, wq[:, q], 0.0)
+        ub = ub + dense[safe[:, q]] * w[:, None]
+    return ub * jnp.float32(BOUND_SLACK)
+
+
+class TestTileSummary:
+    """The stored form of the block-max summary (a CSR of the (term,
+    tile) pairs that occur) against the dense array it replaced."""
+
+    SHAPES = [  # cap, slots, n_terms, tile
+        (2048, 4, 40, 512), (4096, 8, 300, 256), (1024, 2, 7, 128),
+        (256, 4, 5000, 256), (8192, 16, 64, 1024), (512, 1, 3, 128)]
+
+    @pytest.mark.parametrize("cap,slots,n_terms,tile", SHAPES)
+    def test_rows_and_bounds_equal_the_dense_summary(self, rng, cap, slots,
+                                                     n_terms, tile):
+        from elasticsearch_tpu.ops.scoring import (dense_tile_bounds,
+                                                   tile_max_rows)
+        fwd_tids, fwd_imps, tm, _qt, _wq, _live = _case(
+            rng, cap=cap, slots=min(slots, n_terms), n_terms=n_terms,
+            tile=tile)
+        dense = _dense_summary(fwd_tids, fwd_imps, n_terms, cap, tile)
+        assert np.array_equal(tm.dense(), dense)
+        # at most one entry a posting, never terms x tiles
+        assert tm.entries == int((dense > 0).sum()) \
+            <= int((fwd_tids >= 0).sum())
+        assert len(tm.tiles) == len(tm.vals) >= tm.entries + tm.n_tiles
+        dev = jax.device_put(tm)
+        qt = rng.integers(-2, n_terms + 2, size=(5, 6)).astype(np.int32)
+        wq = rng.random((5, 6), dtype=np.float32) + 0.01
+        safe = np.clip(qt, 0, n_terms - 1)
+        for q in range(qt.shape[1]):
+            rows = np.asarray(jax.jit(tile_max_rows)(dev, qt[:, q]))
+            assert np.array_equal(rows, dense[safe[:, q]])
+            assert np.array_equal(tm.rows(safe[:, q]), dense[safe[:, q]])
+        # the bound the kernels are handed: bit for bit what it was
+        got = np.asarray(jax.jit(dense_tile_bounds)(
+            dev, jnp.asarray(qt), jnp.asarray(wq)))
+        assert np.array_equal(got, np.asarray(_dense_bounds(
+            jnp.asarray(dense), jnp.asarray(qt), jnp.asarray(wq))))
+        # 0 exactly where no term of the query occurs in the tile
+        present = np.zeros(got.shape, bool)
+        for q in range(qt.shape[1]):
+            present |= (dense[safe[:, q]] > 0) & (qt[:, q] >= 0)[:, None]
+        assert np.array_equal(got > 0, present)
+
+    def test_no_size_at_which_the_summary_is_not_built(self, rng):
+        """262,144 terms x 128 tiles is 2^25 cells, twice what the dense
+        form was built up to; the stored form follows the postings."""
+        cap, tile, n_terms = 16384, 128, 1 << 18
+        fwd_tids = rng.integers(0, n_terms, size=(cap, 4)).astype(np.int32)
+        fwd_tids[:, 1:][fwd_tids[:, 1:] == fwd_tids[:, :1]] = -1
+        fwd_imps = np.where(fwd_tids >= 0, rng.random(
+            (cap, 4), dtype=np.float32) + 0.1, 0).astype(np.float32)
+        tm = build_tile_max(fwd_tids, fwd_imps, n_terms, cap, tile=tile)
+        assert tm is not None and n_terms * tm.n_tiles == 1 << 25
+        assert tm.entries <= cap * 4
+        assert tm.nbytes < 4 * (n_terms + 1) + 8 * (cap * 4 + 2 * 1024)
+        qt = fwd_tids[rng.integers(0, cap, 3), 0][:, None]
+        wq = np.ones((3, 1), np.float32)
+        live = np.ones(cap, bool)
+        _assert_tri_parity(fwd_tids, fwd_imps, tm, qt, wq, live, k=5)
+
+    @pytest.mark.parametrize("order", ["term_major", "shuffled"])
+    def test_host_and_device_builders_agree(self, rng, order):
+        """The integer half is shared (tile_runs); the float half is a
+        max a run on the host and a scatter-max on the device: the same
+        bytes, whatever order the postings come in."""
+        from elasticsearch_tpu.index.segment import (tile_runs,
+                                                     tile_summary)
+        from elasticsearch_tpu.ops.build import scatter_tile_max
+        cap, tile, n_terms, nnz = 4096, 256, 500, 20000
+        tids = np.sort(rng.integers(0, n_terms, nnz))
+        docs = rng.integers(0, cap, nnz).astype(np.int32)
+        imps = rng.random(nnz, dtype=np.float32)
+        if order == "shuffled":
+            turn = rng.permutation(nnz)
+            tids, docs, imps = tids[turn], docs[turn], imps[turn]
+        host = tile_summary(tids, docs, imps, n_terms, cap, tile)
+        _o, _h, run, start, run_tiles = tile_runs(
+            tids, docs // tile, n_terms, cap // tile)
+        vals = np.asarray(scatter_tile_max(
+            jnp.asarray(run.astype(np.int32)), jnp.asarray(imps),
+            entry_cap=1 << 15))[:len(run_tiles)]
+        assert np.array_equal(start, host.start)
+        assert np.array_equal(run_tiles, host.tiles[:host.entries])
+        assert np.array_equal(vals, host.vals[:host.entries])
+        # and the forward index gives the same summary as its postings
+        dense = np.zeros((n_terms, cap // tile), np.float32)
+        np.maximum.at(dense, (tids, docs // tile), imps)
+        assert np.array_equal(host.dense(), dense)
+
+    def test_fused_is_bit_identical_to_unfused(self, rng):
+        """Scores and ids of the fused walk against the unfused program
+        (the full score matrix, then lax.top_k), bit for bit; with a
+        summary that bounds every tile at infinity nothing is pruned
+        and nothing changes."""
+        from elasticsearch_tpu.index.segment import TileSummary
+        from elasticsearch_tpu.ops.scoring import _dense_tile_scores
+        fwd_tids, fwd_imps, _tm, qt, wq, live = _case(rng, cap=4096, b=4,
+                                                      q=4, tile=256)
+        # the queries' terms (0 and 1) occur in two of the 16 tiles
+        # only, so the true summary lets the walk skip the others
+        for lo, hi in ((0, 512), (768, 1280), (1536, 4096)):
+            rare = fwd_tids[lo:hi] < 2
+            fwd_tids[lo:hi][rare] = -1
+            fwd_imps[lo:hi][rare] = 0.0
+        qt[:] = rng.integers(0, 2, size=qt.shape)
+        qt[:, 1:][qt[:, 1:] == qt[:, :1]] = -1
+        wq[qt < 0] = 0.0
+        tm = build_tile_max(fwd_tids, fwd_imps, 40, 4096, tile=256)
+        n_terms, grid = len(tm.start) - 1, tm.n_tiles
+        unbounded = TileSummary(
+            (np.arange(n_terms + 1) * grid).astype(np.int32),
+            np.tile(np.arange(grid, dtype=np.int32), n_terms + 1),
+            np.full((n_terms + 1) * grid, 3e38, np.float32), grid)
+        args = (jnp.asarray(fwd_tids), jnp.asarray(fwd_imps))
+        rest = (jnp.asarray(qt), jnp.asarray(wq), jnp.asarray(live), 10)
+
+        @jax.jit
+        def unfused(tids, imps, qt, wq, live):
+            score = _dense_tile_scores(tids, imps, qt, wq)
+            match = (score > 0) & live[None, :]
+            top_s, top_i = jax.lax.top_k(
+                jnp.where(match, score, -jnp.inf), 10)
+            return top_s, top_i, match.sum(axis=-1, dtype=jnp.int32)
+
+        ref_s, ref_i, ref_t = (np.asarray(x) for x in
+                               unfused(*args, *rest[:3]))
+        for summary, prunes in ((tm, True), (unbounded, False)):
+            g_s, g_i, g_t, pruned = (np.asarray(x) for x in
+                                     score_topk_dense_fused(
+                *args, jax.device_put(summary), *rest))
+            assert np.array_equal(g_t, ref_t)
+            for row in range(qt.shape[0]):
+                n = min(int(ref_t[row]), 10)
+                assert np.array_equal(g_i[row, :n], ref_i[row, :n])
+                assert np.array_equal(g_s[row, :n], ref_s[row, :n])
+            assert int(pruned[2]) == grid
+            assert int(pruned[0]) == (14 if prunes else 0)
+
+    def test_a_wide_forward_index_is_no_kernel_candidate(self, monkeypatch):
+        """At 256 forward slots the kernel's dense clause does not
+        compile for a batch (tests/test_tpu_compile.py has the compile);
+        the bundle runs the XLA walk and says why."""
+        from elasticsearch_tpu.search import executor as ex
+        monkeypatch.setattr(ex, "fused_pallas_ok", lambda ck: True)
+        bundle = (("should", "terms_dense", "f", False),)
+        cols = {"f": {"fwd_tids": np.zeros((8, 256), np.int32)}}
+        assert ex._bundle_fwd_width(bundle, cols) == 256
+        assert ex._bundle_pallas_reason(bundle, (), 10, 0, 256) \
+            == "forward_width"
+        assert ex._bundle_pallas_reason(bundle, (), 10, 0, 128) is None
 
 
 class TestProfilerPathRestriction:

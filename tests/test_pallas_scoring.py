@@ -57,6 +57,46 @@ class TestScatterAdd:
         np.testing.assert_allclose(got, ref, atol=1e-4)
 
 
+    def test_the_contraction_keeps_float32(self):
+        """The one-hot contraction runs on the MXU, whose default takes
+        float32 operands in one bfloat16 pass: on the chip every score
+        of the posting-scatter path came out 3e-3 off float32 BM25 (PR
+        33, PERF.md fault 17), and interpret mode, which computes in
+        float32 whatever is asked, never showed it. So the kernel has
+        to ASK for float32 passes; tests/test_tpu_compile.py compiles
+        it so for the chip."""
+        import jax
+
+        def precisions(jaxpr, out):
+            for eqn in jaxpr.eqns:
+                if eqn.primitive.name == "dot_general":
+                    out.append(eqn.params["precision"])
+                for v in eqn.params.values():
+                    for sub in v if isinstance(v, (list, tuple)) else [v]:
+                        sub = getattr(sub, "jaxpr", sub)   # closed or not
+                        sub = getattr(sub, "jaxpr", sub)
+                        if hasattr(sub, "eqns"):
+                            precisions(sub, out)
+            return out
+
+        traced = jax.make_jaxpr(
+            lambda d, v: scatter_add_pallas(d, v, 256, interpret=True))(
+            jnp.zeros((2, 256), jnp.int32), jnp.zeros((2, 256), jnp.float32))
+        highest = jax.lax.Precision.HIGHEST
+        assert precisions(traced.jaxpr, []) == [(highest, highest)]
+
+    def test_full_mantissas_survive(self, rng):
+        # impacts with all 24 bits of mantissa in use, one a doc: the
+        # kernel returns each as it went in
+        cap, n = 512, 384
+        docs = rng.permutation(cap)[:n].astype(np.int32)[None, :]
+        vals = (rng.random((1, n), dtype=np.float32) + np.float32(1.0)) \
+            * np.float32(1.0000001)
+        got = np.asarray(scatter_add_pallas(
+            jnp.asarray(docs), jnp.asarray(vals), cap, interpret=True))
+        assert np.array_equal(got[0, docs[0]], vals[0])
+
+
 class TestDenseKernel:
     def test_matches_reference_loop(self, rng):
         cap, lanes, b, q = 1024, 8, 3, 5

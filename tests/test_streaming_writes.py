@@ -686,7 +686,10 @@ class TestChainedTopkOps:
     host-side, on BOTH engines."""
 
     def _cols(self, rng, cap, T, L, n_tiles):
+        import jax
         import jax.numpy as jnp
+        from elasticsearch_tpu.index.segment import (TileSummary,
+                                                     build_tile_max)
         tids = rng.integers(-1, T, size=(cap, L)).astype(np.int32)
         imps = np.where(tids >= 0,
                         rng.random((cap, L)).astype(np.float32),
@@ -698,9 +701,24 @@ class TestChainedTopkOps:
             ii = imps[j * tile:(j + 1) * tile].ravel()
             ok = tt >= 0
             np.maximum.at(tm[:, j], tt[ok], ii[ok])
+        # the stored form, laid out here from the dense oracle and not
+        # by the program's builder: a CSR of the cells that hold a
+        # posting, padded by a window of the grid
+        at_t, at_j = np.nonzero(tm)
+        start = np.zeros(T + 1, np.int32)
+        np.cumsum(np.bincount(at_t, minlength=T), out=start[1:])
+        summary = TileSummary(
+            start,
+            np.concatenate([at_j, np.full(n_tiles, n_tiles)]).astype(
+                np.int32),
+            np.concatenate([tm[at_t, at_j],
+                            np.zeros(n_tiles, np.float32)]), n_tiles)
+        assert np.array_equal(summary.dense(), tm)
+        assert np.array_equal(
+            build_tile_max(tids, imps, T, cap, tile=tile).dense(), tm)
         return {"fwd_tids": jnp.asarray(tids),
                 "fwd_imps": jnp.asarray(imps),
-                "tile_max": jnp.asarray(tm)}
+                "tile_max": jax.device_put(summary)}
 
     def test_chained_equals_union_and_engines_agree(self):
         import jax.numpy as jnp
@@ -792,6 +810,27 @@ class TestConcatSegmentsUnit:
         assert list(fm[s:e]) == kept
         assert m.text["body"].tile_max is not None
 
+    @pytest.mark.parametrize("docs", [1, 3, 6])
+    def test_a_growing_delta_keeps_its_summarys_shapes(self, docs):
+        """The summary's three arrays are bucketed with the term rows,
+        so a delta that grows inside a bucket keeps every program's
+        shape signature; the rows read the same as before the pad."""
+        from elasticsearch_tpu.index.mapping import (ParsedDocument,
+                                                     ParsedField, TEXT)
+        b = SegmentBuilder()
+        for i in range(docs):
+            b.add(ParsedDocument(doc_id=str(i), source=b"{}", fields=[
+                ParsedField(name="body", type=TEXT,
+                            tokens=["a", f"w{i}"])]))
+        seg = b.build("d")
+        before = seg.text["body"].tile_max.dense()
+        pad_delta_shapes(seg)
+        tm = seg.text["body"].tile_max
+        assert (len(tm.start), len(tm.tiles), len(tm.vals)) \
+            == (9, 1024, 1024)
+        assert np.array_equal(tm.dense()[:before.shape[0]], before)
+        assert not tm.dense()[before.shape[0]:].any()
+
     def test_pad_delta_shapes_buckets_term_arrays(self):
         from elasticsearch_tpu.index.mapping import (ParsedDocument,
                                                      ParsedField, TEXT)
@@ -802,7 +841,10 @@ class TestConcatSegmentsUnit:
         seg = b.build("x1")
         pad_delta_shapes(seg)
         pf = seg.text["body"]
-        assert pf.tile_max.shape[0] == 8          # pow2 floor
+        assert len(pf.tile_max.start) == 9        # pow2 floor
         assert len(pf.block_start) == 9
+        # the stored entries are bucketed like the term rows
+        n = len(pf.tile_max.tiles)
+        assert n == len(pf.tile_max.vals) and n & (n - 1) == 0
         # padded rows bound to zero impact: they can never un-prune
-        assert float(pf.tile_max[3:].max()) == 0.0
+        assert float(pf.tile_max.dense()[3:].max()) == 0.0

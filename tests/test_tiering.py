@@ -36,6 +36,7 @@ import numpy as np
 import pytest
 
 jax = pytest.importorskip("jax")
+import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
 from elasticsearch_tpu.index import tiering  # noqa: E402
@@ -266,12 +267,46 @@ class TestSurvivorOracle:
                 clauses,
                 tuple(tuple(jnp.asarray(x) for x in inp)
                       for inp in cl_np),
-                {"f": {"tile_max": jnp.asarray(tm)}},
+                {"f": {"tile_max": jax.device_put(tm)}},
                 {"g": {"tile_lo": jnp.asarray(lo_hi[0]),
                        "tile_hi": jnp.asarray(lo_hi[1])}},
                 jnp.asarray(msm), jnp.asarray(boost))
             assert np.array_equal(can_h, np.asarray(can_d)), \
                 f"survivor oracle diverged on trial {trial}"
+
+
+    @pytest.mark.parametrize("chunk", [
+        [3, 0, 2], [1, 1, 3, 0], [2, 4, 4, 4], [0, 1, 2, 3, 4, 4, 4, 4]])
+    def test_a_chunks_summary_is_the_packs_cut_to_its_tiles(self, chunk):
+        """The tiered walk hands a compacted chunk the pack's summary
+        cut to the chunk's tiles (`TileSummary.take`; a pad slot names
+        the tile past the grid and reads 0): the rows are the dense
+        summary's columns, on the device and on the host."""
+        from elasticsearch_tpu.ops.scoring import (dense_tile_bounds,
+                                                   tile_max_rows)
+        rng = np.random.default_rng(11)
+        cap, slots, n_terms, tile = 4096, 4, 60, 1024
+        fwd_tids = np.argsort(rng.random((cap, n_terms)),
+                              axis=1)[:, :slots].astype(np.int32)
+        fwd_tids[rng.random((cap, slots)) < 0.5] = -1
+        fwd_tids[: cap - tile][fwd_tids[: cap - tile] < 5] = -1
+        fwd_imps = rng.random((cap, slots), dtype=np.float32)
+        fwd_imps[fwd_tids < 0] = 0.0
+        tm = build_tile_max(fwd_tids, fwd_imps, n_terms, cap, tile=tile)
+        dense = np.concatenate([tm.dense(), np.zeros((n_terms, 1),
+                                                     np.float32)], axis=1)
+        cols = np.asarray(chunk, np.int32)
+        tids = np.asarray([0, 3, 17, 59], np.int32)
+        cut = jax.jit(lambda t, c, q: tile_max_rows(t.take(c), q))(
+            jax.device_put(tm), jnp.asarray(cols), jnp.asarray(tids))
+        assert np.array_equal(np.asarray(cut), dense[tids][:, cols])
+        assert np.array_equal(tm.take(cols).rows(tids),
+                              dense[tids][:, cols])
+        assert tm.take(cols).n_tiles == len(chunk)
+        ub = jax.jit(lambda t, c, q, w: dense_tile_bounds(t.take(c), q, w))(
+            jax.device_put(tm), jnp.asarray(cols), jnp.asarray(tids)[:, None],
+            jnp.ones((4, 1), jnp.float32))
+        assert np.array_equal(np.asarray(ub) > 0, dense[tids][:, cols] > 0)
 
 
 # ---------------------------------------------------------------------------

@@ -19,6 +19,7 @@ smoke's corpus and a 64-term passage); interpret_mode / pallas_enabled
 are steered here with monkeypatch, not through an option.
 """
 
+import functools
 import json
 import os
 
@@ -30,7 +31,8 @@ import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
 import chip_smoke as cs
-from elasticsearch_tpu.index.segment import next_pow2, score_tile_size
+from elasticsearch_tpu.index.segment import (TileSummary, next_pow2,
+                                             score_tile_size)
 from elasticsearch_tpu.ops import pallas_scoring as ps
 from elasticsearch_tpu.ops import scoring
 from elasticsearch_tpu.search import executor as ex
@@ -153,7 +155,10 @@ def _at_real_size(shapes, kw, small_cap: int, sharding):
         name = jax.tree_util.keystr(path)
         shape = list(leaf.shape)
         if "tile_max" in name:
-            shape[1] = n_tiles
+            # the summary's entries follow the postings (about 8 a doc)
+            # and its term rows the dictionary, which does not grow
+            if not name.endswith(".start"):
+                shape[0] = next_pow2(CAP * 8)
         elif "tile_lo" in name or "tile_hi" in name:
             shape[0] = n_tiles
         elif "block_docs" in name or "block_imps" in name:
@@ -163,8 +168,22 @@ def _at_real_size(shapes, kw, small_cap: int, sharding):
         return jax.ShapeDtypeStruct(tuple(shape), leaf.dtype,
                                     sharding=sharding)
 
-    return (jax.tree_util.tree_map_with_path(grow, shapes),
-            {**kw, "cap": CAP})
+    def regrid(x):
+        return TileSummary(x.start, x.tiles, x.vals, n_tiles) \
+            if isinstance(x, TileSummary) else x
+
+    grown = jax.tree_util.tree_map_with_path(grow, shapes)
+    return (jax.tree_util.tree_map(
+        regrid, grown, is_leaf=lambda x: isinstance(x, TileSummary)),
+        {**kw, "cap": CAP})
+
+
+def _summary_sds(sds, n_tiles: int, lead: tuple = ()) -> TileSummary:
+    """A block-max summary of 4,096 terms as shapes: `sds(shape, dtype)`
+    makes one, `lead` is a stacked pack's leading axes."""
+    return TileSummary(sds(lead + (4097,), jnp.int32),
+                       sds(lead + (1 << 16,), jnp.int32),
+                       sds(lead + (1 << 16,), jnp.float32), n_tiles)
 
 
 def _compiled(fn, *args, **kw):
@@ -256,8 +275,8 @@ def _dense_case(one_chip, slots: int, b: int, q: int = 4):
     text_cols = {"f": {"fwd_tids": _sds(one_chip, (CAP, slots), jnp.int32),
                        "fwd_imps": _sds(one_chip, (CAP, slots),
                                         jnp.float32),
-                       "tile_max": _sds(one_chip, (4096, n_tiles),
-                                        jnp.float32)}}
+                       "tile_max": _summary_sds(
+                           functools.partial(_sds, one_chip), n_tiles)}}
     clauses = (("should", "terms_dense", "f", False),)
     cl_inputs = ((_sds(one_chip, (b, q), jnp.int32),
                   _sds(one_chip, (b, q), jnp.float32),
@@ -327,10 +346,60 @@ def test_xla_dense_engine(one_chip, cache_off, smoke_plans):
         jax.jit(scoring.score_topk_dense_fused, static_argnames=("k",)),
         _sds(one_chip, (CAP, slots), jnp.int32),
         _sds(one_chip, (CAP, slots), jnp.float32),
-        _sds(one_chip, (4096, n_tiles), jnp.float32),
+        _summary_sds(functools.partial(_sds, one_chip), n_tiles),
         _sds(one_chip, (256, 4), jnp.int32),
         _sds(one_chip, (256, 4), jnp.float32),
         _sds(one_chip, (CAP,), jnp.bool_), k=100), want=False)
+
+
+def test_bundle_kernel_does_not_compile_at_256_forward_slots(one_chip,
+                                                            cache_off):
+    """Pins WHY executor._FWD_PALLAS_SLOTS_MAX exists: over a forward
+    index of 256 slots (passages of up to 256 distinct words: the
+    `msmarco-passage-1shard` cell) the kernel's dense clause unrolls
+    256 slot compares a query term, and the compile of a batch runs out
+    of scoped VMEM. When this starts compiling, raise the gate and let
+    the autotuner time the kernel on such packs too."""
+    text_cols, clauses, cl_inputs, msm, boost, live = _dense_case(
+        one_chip, 256, 8)
+
+    def run(tc, ci, msm, boost, live):
+        return ps.fused_topk_bundle_pallas(tc, {}, clauses, ci, msm,
+                                           boost, live, 10)
+
+    with pytest.raises(jax.errors.JaxRuntimeError, match="vmem"):
+        _compiled(jax.jit(run), text_cols, cl_inputs, msm, boost, live)
+    assert ex._bundle_pallas_reason(clauses, (), 10, 0, 256) \
+        == "forward_width"
+
+
+def test_xla_walk_of_a_ten_term_conjunction_at_256_slots(one_chip,
+                                                         cache_off):
+    """What the `msmarco-passage-1shard` cell's widest plan runs: ten
+    must clauses of one term each (a `match` with operator and) over 256
+    forward slots, batch 8, on the XLA engine, with the block-max
+    summary read through its CSR."""
+    n_tiles = CAP // score_tile_size(CAP)
+    sds = functools.partial(_sds, one_chip)
+    text_cols = {"f": {"fwd_tids": sds((CAP, 256), jnp.int32),
+                       "fwd_imps": sds((CAP, 256), jnp.float32),
+                       "tile_max": _summary_sds(sds, n_tiles)}}
+    clauses = tuple(("must", "term_text", "f", False) for _ in range(10))
+    cl_inputs = tuple((sds((8, 1), jnp.int32), sds((8, 1), jnp.float32),
+                       sds((8,), jnp.int32), sds((8,), jnp.float32))
+                      for _ in range(10))
+
+    def run(tc, ci, msm, live):
+        return scoring.score_topk_bundle_fused(tc, {}, clauses, ci, msm,
+                                               None, live, 10)
+
+    compiled = _compiled(jax.jit(run), text_cols, cl_inputs,
+                         sds((8,), jnp.int32), sds((CAP,), jnp.bool_))
+    _assert_kernel(compiled, want=False)
+    # the summary's reader densifies a term's window by a one-hot max:
+    # fused into its reduce it is a few MB of transient (2.2 MB here);
+    # materialised it would be [8, 1024, 1024] f32 a clause, 32 MB
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 24
 
 
 def test_bundle_kernel_under_shard_map_on_four_chips(topo, cache_off):
@@ -354,7 +423,8 @@ def test_bundle_kernel_under_shard_map_on_four_chips(topo, cache_off):
 
     def per_device(tids, imps, tmax, qt, wq, live):
         text_cols = {"f": {"fwd_tids": tids[0], "fwd_imps": imps[0],
-                           "tile_max": tmax[0]}}
+                           "tile_max": jax.tree_util.tree_map(
+                               lambda a: a[0], tmax)}}
         ones = jnp.ones((b,), jnp.int32)
         cl_inputs = ((qt, wq, ones, ones.astype(jnp.float32)),)
         top_s, top_i, total, _pruned = ps.fused_topk_bundle_pallas(
@@ -366,12 +436,14 @@ def test_bundle_kernel_under_shard_map_on_four_chips(topo, cache_off):
     row = P("shard", None, None)
     program = jax.jit(shard_map(
         per_device, mesh=mesh,
-        in_specs=(row, row, row, P(), P(), P("shard", None)),
+        in_specs=(row, row, P("shard", None), P(), P(),
+                  P("shard", None)),
         out_specs=(P(), P(), P()), check_vma=False))
     compiled = program.lower(
         sds((4, cap, slots), jnp.int32, row),
         sds((4, cap, slots), jnp.float32, row),
-        sds((4, 4096, n_tiles), jnp.float32, row),
+        _summary_sds(lambda shape, dtype: sds(
+            shape, dtype, P("shard", None)), n_tiles, lead=(4,)),
         sds((b, q), jnp.int32, P()), sds((b, q), jnp.float32, P()),
         sds((4, cap), jnp.bool_, P("shard", None))).compile()
     text = compiled.as_text()
