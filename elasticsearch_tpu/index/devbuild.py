@@ -1,11 +1,11 @@
 """Device-parallel pack build — the host driver (ops/build.py holds
 the jitted programs).
 
-Pack build was the last single-host-thread stage of the engine: every
-refresh, compaction, mesh repack and ANN build funneled through the
-per-term Python loops of `segment._pack_layout` and the per-doc dict
-accumulation of `SegmentBuilder.build`. This module moves the heavy
-half onto the hardware as batched JAX programs:
+Pack build is a single-host-thread stage of the engine: every refresh,
+compaction, mesh repack and ANN build funnels through the host's sort
+and index stores of `SegmentBuilder.build` and `segment._pack_layout`.
+This module moves the heavy half onto the hardware as batched JAX
+programs:
 
   host   tokenizes, hashes terms (np.unique) and finalizes term dicts;
   device sorts the (term-id, doc) occurrence stream, segments it into
@@ -197,8 +197,8 @@ def build_segment(builder, seg_id: str | None = None, *,
 
 def _build_device(builder, seg_id: str):
     """Mirror of SegmentBuilder.build with text fields accumulated as
-    flat occurrence streams (the device sort's input) instead of
-    per-doc posting dicts. Every non-text column delegates to the
+    flat occurrence streams of token strings (the device sort's
+    input). Every non-text column delegates to the
     vectorized builders below (or the host statics for the rare
     multi-valued/ragged shapes), so the resulting Segment is
     byte-identical to `builder.build(seg_id)`."""
@@ -388,7 +388,7 @@ def pack_layout_device(pf, cap: int, imps: np.ndarray) -> None:
     forward index and block-max tile summary, all as scatters over
     host-computed unique target indices — byte-identical output.
     Raises on any device error; the caller's seam falls back to the
-    host loops."""
+    host form."""
     from .segment import (
         BLOCK, MAX_FWD_SLOTS, TileSummary, next_pow2, score_tile_size,
         tile_runs, tile_summary_pad,
@@ -502,20 +502,6 @@ def _padded_i32(vals: np.ndarray, batch_cap: int,
     out = np.full(batch_cap, fill, dtype=np.int32)
     out[:len(vals)] = vals
     return out
-
-
-def extract_flat_impacts_fast(pf) -> np.ndarray:
-    """Vectorized mirror of segment.extract_flat_impacts: one gather
-    over the flat block-impacts array at the same lane indices the
-    packer wrote — exact by construction (no float math)."""
-    from .segment import BLOCK
-    nnz = len(pf.doc_ids)
-    T = len(pf.terms)
-    tid_pp = np.repeat(np.arange(T, dtype=np.int64), np.diff(pf.indptr))
-    r = np.arange(nnz, dtype=np.int64) - pf.indptr[tid_pp]
-    flat = ((pf.block_start[tid_pp].astype(np.int64) + r // BLOCK) * BLOCK
-            + r % BLOCK)
-    return pf.block_imps.ravel()[flat]
 
 
 def tile_minmax_device(values: np.ndarray, exists: np.ndarray, cap: int,

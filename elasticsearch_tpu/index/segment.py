@@ -29,6 +29,7 @@ from __future__ import annotations
 
 import math
 import threading
+from array import array
 from dataclasses import dataclass, field as dc_field
 from typing import Iterable
 
@@ -712,6 +713,43 @@ class Segment:
 # ---------------------------------------------------------------------------
 
 
+class _TermNumbers(dict):
+    """term -> its number in order of first sight; looking up a term
+    that has none numbers it."""
+
+    __slots__ = ()
+
+    def __missing__(self, term: str) -> int:
+        number = self[term] = len(self)
+        return number
+
+
+class _TextTokens:
+    """One text field's tokens as `SegmentBuilder.build`'s document loop
+    leaves them: the number of every token's term in `tids`, one after
+    another in (row, position) order, and for each row that has the
+    field how many tokens it holds. Rows and positions are not stored a
+    token: `_build_postings` spreads them from `rows` and `counts`."""
+
+    __slots__ = ("numbers", "tids", "rows", "counts")
+
+    def __init__(self):
+        self.numbers = _TermNumbers()
+        self.tids = array("i")
+        self.rows: list[int] = []
+        self.counts: list[int] = []
+
+    def add(self, row: int, tokens: list[str]) -> None:
+        """Two values of one field in one row concatenate (positions run
+        on), so a row contributes one posting a term."""
+        if self.rows and self.rows[-1] == row:
+            self.counts[-1] += len(tokens)
+        else:
+            self.rows.append(row)
+            self.counts.append(len(tokens))
+        self.tids.extend(map(self.numbers.__getitem__, tokens))
+
+
 class SegmentBuilder:
     """Accumulates parsed documents, emits an immutable Segment.
 
@@ -771,8 +809,7 @@ class SegmentBuilder:
         id_map: dict[str, int] = {}
         sources: list[bytes] = []
         # field name -> accumulated data
-        text_postings: dict[str, dict[str, list[tuple[int, int]]]] = {}
-        text_doclen: dict[str, np.ndarray] = {}
+        text_tokens: dict[str, _TextTokens] = {}
         kw_values: dict[str, dict[int, str]] = {}
         num_values: dict[str, tuple[str, dict[int, float | int]]] = {}
         vec_values: dict[str, dict[int, list[float]]] = {}
@@ -787,10 +824,12 @@ class SegmentBuilder:
             # array values (text concatenates tokens BEFORE tf counting so a
             # doc contributes exactly one postings entry per term; keyword/
             # numeric keep first — multi-valued columns land round 2)
-            doc_tokens: dict[str, list[str]] = {}
             for pf in doc.fields:
                 if pf.type == TEXT:
-                    doc_tokens.setdefault(pf.name, []).extend(pf.tokens or [])
+                    toks = text_tokens.get(pf.name)
+                    if toks is None:
+                        toks = text_tokens[pf.name] = _TextTokens()
+                    toks.add(d, pf.tokens or ())
                 elif pf.type == KEYWORD:
                     col = kw_values.setdefault(pf.name, {})
                     col.setdefault(d, []).append(str(pf.value))
@@ -807,21 +846,11 @@ class SegmentBuilder:
                 else:
                     kind, col = num_values.setdefault(pf.name, (pf.type, {}))
                     col.setdefault(d, []).append(pf.value)
-            for fname, toks in doc_tokens.items():
-                postings = text_postings.setdefault(fname, {})
-                if fname not in text_doclen:
-                    text_doclen[fname] = np.zeros(cap, dtype=np.float32)
-                text_doclen[fname][d] += float(len(toks))
-                pos_local: dict[str, list[int]] = {}
-                for i, tok in enumerate(toks):
-                    pos_local.setdefault(tok, []).append(i)
-                for term, positions in pos_local.items():
-                    postings.setdefault(term, []).append((d, positions))
 
         text = {
-            name: self._build_postings(name, postings, text_doclen[name], n,
-                                       cap, self._sim_for(name))
-            for name, postings in text_postings.items()
+            name: self._build_postings(name, toks, n, cap,
+                                       self._sim_for(name))
+            for name, toks in text_tokens.items()
         }
         keywords = {
             name: self._build_keyword(name, col, cap)
@@ -889,30 +918,44 @@ class SegmentBuilder:
     # -- per-field builders ------------------------------------------------
 
     @staticmethod
-    def _build_postings(name: str, postings: dict[str, list[tuple[int, list[int]]]],
-                        doc_len: np.ndarray, n_docs: int, cap: int,
+    def _build_postings(name: str, toks: _TextTokens, n_docs: int, cap: int,
                         sim=None) -> PostingsField:
-        terms = sorted(postings)
-        term_index = {t: i for i, t in enumerate(terms)}
-        df = np.array([len(postings[t]) for t in terms], dtype=np.int32)
-        indptr = np.zeros(len(terms) + 1, dtype=np.int64)
+        """One field's postings from its tokens, all at once: the term
+        ids are the ranks of the terms in sorted order; one stable sort
+        of the tokens by term leaves them in (term, row, position) order
+        (they came in (row, position) order), so the heads of its
+        (term, row) runs are the postings, the runs' lengths the tfs and
+        the sorted positions the positional sidecar."""
+        terms = sorted(toks.numbers)
+        T = len(terms)
+        term_index = dict(zip(terms, range(T)))
+        first_seen = np.fromiter(map(toks.numbers.__getitem__, terms),
+                                 dtype=np.int64, count=T)
+        rank = np.empty(T, dtype=np.int32)
+        rank[first_seen] = np.arange(T, dtype=np.int32)
+        rows = np.asarray(toks.rows, dtype=np.int32)
+        counts = np.asarray(toks.counts, dtype=np.int64)
+        doc_len = np.zeros(cap, dtype=np.float32)
+        doc_len[rows] = counts
+        n_tok = len(toks.tids)
+        tok_row = np.repeat(rows, counts)
+        tok_pos = (np.arange(n_tok, dtype=np.int64)
+                   - np.repeat(np.cumsum(counts) - counts, counts)
+                   ).astype(np.int32)
+        tok_tid = rank[np.frombuffer(toks.tids, dtype=np.int32)]
+
+        order = np.argsort(tok_tid, kind="stable")
+        tid_s, row_s = tok_tid[order], tok_row[order]
+        head = np.ones(n_tok, dtype=bool)
+        head[1:] = (tid_s[1:] != tid_s[:-1]) | (row_s[1:] != row_s[:-1])
+        heads = np.flatnonzero(head)
+        doc_ids = row_s[heads]  # ascending inside a term: rows came in order
+        pos_indptr = np.append(heads, n_tok).astype(np.int64, copy=False)
+        tfs = np.diff(pos_indptr).astype(np.float32)
+        pos_data = tok_pos[order]
+        df = np.bincount(tid_s[heads], minlength=T).astype(np.int32)
+        indptr = np.zeros(T + 1, dtype=np.int64)
         np.cumsum(df, out=indptr[1:])
-        nnz = int(indptr[-1])
-        doc_ids = np.empty(nnz, dtype=np.int32)
-        tfs = np.empty(nnz, dtype=np.float32)
-        pos_chunks: list[list[int]] = []
-        for i, t in enumerate(terms):
-            plist = postings[t]  # already in doc order (docs added in order)
-            s = indptr[i]
-            for j, (d, positions) in enumerate(plist):
-                doc_ids[s + j] = d
-                tfs[s + j] = len(positions)
-                pos_chunks.append(positions)
-        pos_indptr = np.zeros(nnz + 1, dtype=np.int64)
-        np.cumsum([len(c) for c in pos_chunks], out=pos_indptr[1:])
-        pos_data = (np.concatenate([np.asarray(c, dtype=np.int32)
-                                    for c in pos_chunks])
-                    if pos_chunks else np.empty(0, dtype=np.int32))
 
         doc_count = int(np.count_nonzero(doc_len[:n_docs])) or n_docs
         total_len = float(doc_len.sum())
@@ -1004,52 +1047,30 @@ def _flat_impacts(pf: PostingsField, sim=None) -> np.ndarray:
     if sim is None:
         from .similarity import DEFAULT_SIMILARITY
         sim = DEFAULT_SIMILARITY
-    from .similarity import FieldStats
+    return sim.field_impacts(
+        pf.tfs.astype(np.float64), pf.doc_len[pf.doc_ids].astype(np.float64),
+        pf.indptr, pf.df, doc_count=float(pf.doc_count),
+        avg_len=float(pf.avg_len), total_len=float(pf.doc_len.sum()))
+
+
+def _block_lanes(pf: PostingsField) -> np.ndarray:
+    """[nnz] int64: where each posting of the CSR lies in the flattened
+    block arrays. A term's blocks are contiguous and all but its last
+    are full, so posting r of term t is lane `block_start[t] * BLOCK +
+    r` (block `block_start[t] + r // BLOCK`, lane `r % BLOCK`)."""
     T = len(pf.terms)
-    total_len = float(pf.doc_len.sum())
-    ttf_all = np.zeros(T, dtype=np.float64)
-    np.add.at(ttf_all,
-              np.repeat(np.arange(T), np.diff(pf.indptr)),
-              pf.tfs.astype(np.float64))
-    out = np.zeros(len(pf.doc_ids), dtype=np.float32)
-    for t in range(T):
-        s, e = int(pf.indptr[t]), int(pf.indptr[t + 1])
-        if s == e:
-            continue
-        docs = pf.doc_ids[s:e]
-        tf = pf.tfs[s:e].astype(np.float64)
-        st = FieldStats(df=float(pf.df[t]), ttf=float(ttf_all[t]),
-                        doc_count=float(pf.doc_count),
-                        avg_len=float(pf.avg_len), total_len=total_len)
-        out[s:e] = sim.impacts(tf, pf.doc_len[docs].astype(np.float64), st)
-    return out
+    return (np.arange(len(pf.doc_ids), dtype=np.int64)
+            + np.repeat(pf.block_start[:T].astype(np.int64) * BLOCK
+                        - pf.indptr[:T], np.diff(pf.indptr)))
 
 
 def extract_flat_impacts(pf: PostingsField) -> np.ndarray:
     """Recover the [nnz] CSR-order impacts from the packed block arrays
-    — the inverse of _pack_layout's block fill, exact by construction
-    (blocks are contiguous BLOCK-lane slices of each term's posting
-    run). The streaming compaction reads impacts back through this so a
+    — the inverse of _pack_layout's block fill, one gather at the lanes
+    the packer wrote, exact by construction (no float math). The
+    streaming compaction reads impacts back through this so a
     compacted base scores byte-identically to the packs it folded."""
-    from . import devbuild
-    if devbuild.enabled():
-        try:
-            # vectorized exact gather (no float math) — the compaction
-            # feed of the device-parallel build path
-            return devbuild.extract_flat_impacts_fast(pf)
-        except Exception as e:
-            devbuild.on_fallback("extract_impacts", e)
-    nnz = len(pf.doc_ids)
-    out = np.empty(nnz, dtype=np.float32)
-    T = len(pf.terms)
-    for t in range(T):
-        s, e = int(pf.indptr[t]), int(pf.indptr[t + 1])
-        b0 = int(pf.block_start[t])
-        for off in range(0, e - s, BLOCK):
-            blk = b0 + off // BLOCK
-            ln = min(BLOCK, e - s - off)
-            out[s + off: s + off + ln] = pf.block_imps[blk, :ln]
-    return out
+    return pf.block_imps.ravel()[_block_lanes(pf)]
 
 
 def _pack_layout(pf: PostingsField, cap: int, imps: np.ndarray) -> None:
@@ -1061,7 +1082,7 @@ def _pack_layout(pf: PostingsField, cap: int, imps: np.ndarray) -> None:
     (compaction) all land here — so the device-parallel builder
     (index/devbuild.py) hooks in here: when enabled, the layout pass
     runs as exact device scatters (byte-identical output), and ANY
-    device error falls back to the host loops below."""
+    device error falls back to the host form below."""
     from . import devbuild
     if devbuild.enabled():
         try:
@@ -1074,76 +1095,58 @@ def _pack_layout(pf: PostingsField, cap: int, imps: np.ndarray) -> None:
 
 def _pack_layout_host(pf: PostingsField, cap: int,
                       imps: np.ndarray) -> None:
-    """Host reference implementation of the layout pass (per-term
-    Python loops) — the fallback, and the identity oracle the device
-    path is tested against."""
+    """The layout pass on the host, as index stores over all postings at
+    once — the default, the device path's fallback, and the identity
+    oracle it is tested against (tests/pack_build_oracle.py holds the
+    loops this has to match byte for byte)."""
     T = len(pf.terms)
-    n_blocks_per_term = (np.diff(pf.indptr) + BLOCK - 1) // BLOCK
+    counts = np.diff(pf.indptr)
     block_start = np.zeros(T + 1, dtype=np.int32)
-    np.cumsum(n_blocks_per_term, out=block_start[1:])
-    nb = int(block_start[-1])
-    nb_pad = next_pow2(nb, floor=1)
-    block_docs = np.full((nb_pad, BLOCK), cap, dtype=np.int32)  # cap = dropped
-    block_imps = np.zeros((nb_pad, BLOCK), dtype=np.float32)
-    for t in range(T):
-        s, e = int(pf.indptr[t]), int(pf.indptr[t + 1])
-        docs = pf.doc_ids[s:e]
-        imp = imps[s:e]
-        b0 = int(block_start[t])
-        for off in range(0, e - s, BLOCK):
-            blk = b0 + off // BLOCK
-            ln = min(BLOCK, e - s - off)
-            block_docs[blk, :ln] = docs[off:off + ln]
-            block_imps[blk, :ln] = imp[off:off + ln]
-    pf.block_docs = block_docs
-    pf.block_imps = block_imps
+    np.cumsum((counts + BLOCK - 1) // BLOCK, out=block_start[1:])
+    nb_pad = next_pow2(int(block_start[-1]), floor=1)
     pf.block_start = block_start
+    lanes = _block_lanes(pf)
+    imps32 = np.asarray(imps, dtype=np.float32)
+    block_docs = np.full(nb_pad * BLOCK, cap, dtype=np.int32)  # cap = dropped
+    block_docs[lanes] = pf.doc_ids
+    block_imps = np.zeros(nb_pad * BLOCK, dtype=np.float32)
+    block_imps[lanes] = imps32
+    pf.block_docs = block_docs.reshape(nb_pad, BLOCK)
+    pf.block_imps = block_imps.reshape(nb_pad, BLOCK)
 
     # forward (doc-major) layout from the same impacts. One doc with
     # thousands of unique terms would inflate the dense [cap, L]
     # arrays for the whole segment, so past MAX_FWD_SLOTS the field
     # skips the forward index and queries take the scatter path.
-    lengths = np.zeros(cap, dtype=np.int64)
-    np.add.at(lengths, pf.doc_ids, 1)
+    lengths = np.bincount(pf.doc_ids, minlength=cap)
     L = next_pow2(int(lengths.max(initial=1)), floor=8)
     if L > MAX_FWD_SLOTS:
         pf.fwd_tids = None
         pf.fwd_imps = None
         return
-    fwd_tids = np.full((cap, L), -1, dtype=np.int32)
-    fwd_imps = np.zeros((cap, L), dtype=np.float32)
-    slot = np.zeros(cap, dtype=np.int64)
-    for t in range(T):
-        s, e = int(pf.indptr[t]), int(pf.indptr[t + 1])
-        docs = pf.doc_ids[s:e]
-        b0 = int(block_start[t])
-        for off in range(0, e - s, BLOCK):
-            blk = b0 + off // BLOCK
-            ln = min(BLOCK, e - s - off)
-            d_slice = docs[off:off + ln]
-            j = slot[d_slice]
-            fwd_tids[d_slice, j] = t
-            fwd_imps[d_slice, j] = block_imps[blk, :ln]
-            slot[d_slice] = j + 1
-    pf.fwd_tids = fwd_tids
-    pf.fwd_imps = fwd_imps
-    pf.tile_max = tile_summary(
-        np.repeat(np.arange(T, dtype=np.int64), np.diff(pf.indptr)),
-        pf.doc_ids, imps, T, cap)
+    tids = np.repeat(np.arange(T, dtype=np.int64), counts)
+    cells = pf.doc_ids.astype(np.int64) * L + forward_slot_ranks(pf.doc_ids)
+    fwd_tids = np.full(cap * L, -1, dtype=np.int32)
+    fwd_tids[cells] = tids
+    fwd_imps = np.zeros(cap * L, dtype=np.float32)
+    fwd_imps[cells] = imps32
+    pf.fwd_tids = fwd_tids.reshape(cap, L)
+    pf.fwd_imps = fwd_imps.reshape(cap, L)
+    pf.tile_max = tile_summary(tids, pf.doc_ids, imps, T, cap)
     pack_positions(pf, cap)
 
 
 def forward_slot_ranks(doc_ids: np.ndarray) -> np.ndarray:
     """Per-posting forward-index slot, CSR order — the rank of each
-    posting among its doc's postings in term-major order, exactly the
-    slot counter _pack_layout_host's forward fill assigns (and the
-    device builder's ops/build.forward_slots). Lets the positional
-    pack land each posting's positions in the slot its (tid, impact)
-    pair occupies."""
+    posting among its doc's postings in term-major order (the device
+    builder's ops/build.forward_slots gives the same). The forward fill
+    puts a posting's (tid, impact) pair there and the positional pack
+    its positions."""
     nnz = len(doc_ids)
     order = np.argsort(doc_ids, kind="stable")
     sorted_docs = doc_ids[order]
-    first = np.searchsorted(sorted_docs, sorted_docs, side="left")
+    per_doc = np.bincount(sorted_docs)
+    first = (np.cumsum(per_doc) - per_doc)[sorted_docs]
     out = np.empty(nnz, dtype=np.int64)
     out[order] = np.arange(nnz, dtype=np.int64) - first
     return out

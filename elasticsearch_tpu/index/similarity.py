@@ -73,6 +73,41 @@ class Similarity:
         """Per-posting score contribution. tf, dl: float64 [n]."""
         raise NotImplementedError
 
+    def field_impacts(self, tf: np.ndarray, dl: np.ndarray,
+                      indptr: np.ndarray, df: np.ndarray, *,
+                      doc_count: float, avg_len: float,
+                      total_len: float) -> np.ndarray:
+        """float32 [nnz] impacts of all postings of one field, which the
+        pack build bakes in. tf, dl: float64 [nnz], term-major; term t's
+        postings are `indptr[t]:indptr[t + 1]` and `df[t]` its document
+        frequency. Here one `impacts` call a term, whose float bits are
+        the similarity's as they always were; a similarity whose
+        per-term constants are scalars spread over the postings
+        overrides it with array code that keeps those bits."""
+        T = len(indptr) - 1
+        ttf = np.bincount(np.repeat(np.arange(T), np.diff(indptr)),
+                          weights=tf, minlength=T)
+        out = np.zeros(len(tf), dtype=np.float32)
+        for t in range(T):
+            s, e = int(indptr[t]), int(indptr[t + 1])
+            if s == e:
+                continue
+            st = FieldStats(df=float(df[t]), ttf=float(ttf[t]),
+                            doc_count=doc_count, avg_len=avg_len,
+                            total_len=total_len)
+            out[s:e] = self.impacts(tf[s:e], dl[s:e], st)
+        return out
+
+    def _spread(self, term_scalar, indptr: np.ndarray, df: np.ndarray,
+                doc_count: float) -> np.ndarray:
+        """float64 [nnz]: `term_scalar(df, doc_count)`, computed as the
+        Python scalar it is in `impacts`, once for each distinct df, and
+        repeated over each term's postings."""
+        distinct, inverse = np.unique(df, return_inverse=True)
+        values = np.array([term_scalar(float(d), doc_count)
+                           for d in distinct.tolist()], dtype=np.float64)
+        return np.repeat(values[inverse], np.diff(indptr))
+
     def df_scale(self, df_local: float, n_local: float,
                  df_global: float, n_global: float) -> float:
         """Multiplier turning a locally-idf'd impact into the global-stats
@@ -104,6 +139,23 @@ class BM25Similarity(Similarity):
         k_d = self.k1 * (1.0 - self.b + self.b * dl / st.avg_len)
         return self.finish(idf * tf * (self.k1 + 1.0) / (tf + k_d))
 
+    def field_impacts(self, tf, dl, indptr, df, *, doc_count, avg_len,
+                      total_len):
+        # `impacts` over all postings at once: each statement is one of
+        # its operations on the same operands (a sum's and a product's
+        # sides may swap, the bits do not), in place, because at millions
+        # of postings a fresh temporary costs more than the arithmetic
+        k_d = self.b * dl
+        k_d /= avg_len
+        k_d += 1.0 - self.b
+        k_d *= self.k1
+        k_d += tf
+        imp = self._spread(self.idf, indptr, df, doc_count)
+        imp *= tf
+        imp *= self.k1 + 1.0
+        imp /= k_d
+        return np.maximum(imp, _IMPACT_FLOOR, out=imp).astype(np.float32)
+
     def df_scale(self, df_local, n_local, df_global, n_global):
         lo = self.idf(df_local, n_local)
         if lo <= 0 or n_global <= 0:
@@ -131,6 +183,14 @@ class ClassicSimilarity(Similarity):
         idf = self.idf(st.df, st.doc_count)
         norm = 1.0 / np.sqrt(np.maximum(dl, 1.0))
         return self.finish(np.sqrt(tf) * (idf * idf) * norm)
+
+    def field_impacts(self, tf, dl, indptr, df, *, doc_count, avg_len,
+                      total_len):
+        # `impacts` over all postings at once, in its order of operations
+        idf2 = self._spread(lambda d, n: self.idf(d, n) * self.idf(d, n),
+                            indptr, df, doc_count)
+        norm = 1.0 / np.sqrt(np.maximum(dl, 1.0))
+        return self.finish(np.sqrt(tf) * idf2 * norm).astype(np.float32)
 
     def df_scale(self, df_local, n_local, df_global, n_global):
         lo = self.idf(df_local, n_local)

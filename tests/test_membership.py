@@ -348,7 +348,7 @@ HOSTS = ["a", "b", "c"]
 
 FD_SETTINGS = Settings({
     "mesh.ping_interval": "-1",
-    "mesh.ping_timeout": "500ms",
+    "mesh.ping_timeout": "5s",
     "mesh.ping_retries": 3,
     "mesh.exec_backoff": "10ms",
 })
