@@ -131,6 +131,12 @@ def score_terms_fused(block_docs: jax.Array, block_imps: jax.Array,
 # <= the query's current k-th best, which came from LOWER doc ids
 # (tiles run in doc order) — and lax.top_k breaks ties toward the lower
 # index, so a tied pruned doc would have lost anyway.
+#
+# The XLA top-k walk (score_topk_bundle_fused) scores a step of
+# walk_step_tiles tiles an iteration on a large pack: it hard-skips a
+# step whose tiles all skip, and extracts candidates where a scored doc
+# beats its query's k-th best, which is the threshold rule read from
+# the scores instead of the bounds (the same tie argument holds).
 # ---------------------------------------------------------------------------
 
 
@@ -1035,7 +1041,9 @@ def bundle_tile_match(clauses: tuple, cl_inputs: tuple, text_tiles: dict,
 
 def _stepped_tile_loop(n_tiles: int, body, st0, step):
     """fori(0, n_tiles, body, st0), optionally chunked with a per-chunk
-    step check. Returns (state, timed_out bool scalar | None)."""
+    step check. Returns (state, timed_out bool scalar | None). A body
+    that walks several tiles an iteration passes its iteration count
+    and a chunk size in iterations."""
     if step is None:
         return jax.lax.fori_loop(0, n_tiles, body, st0), None
     chunk_tiles, ck0, check = step
@@ -1154,11 +1162,33 @@ def match_mask_bundle_fused(text_cols: dict, num_cols: dict,
     return out if timed is None else out + (timed,)
 
 
+# most tiles one iteration of the XLA top-k walk scores: an iteration
+# costs the chip 15-20 us whatever it scores (a conditional, the slices,
+# the candidate merge), so a walk of 256 one-tile steps was mostly that
+WALK_STEP_MAX = 16
+
+
+def walk_step_tiles(n_tiles: int, chunk_tiles: int | None = None) -> int:
+    """Tiles one step of score_topk_bundle_fused scores, from shapes
+    alone: the largest power of two up to min(WALK_STEP_MAX,
+    n_tiles // 16) that divides n_tiles and, under a resident `step`,
+    its chunk_tiles (a chunk then ends on a step). 16 at 256 tiles, 4
+    at 64, 1 for packs of 16 tiles or fewer. The breaker's estimate
+    (search/executor._fused_row_elems) sizes from the same number."""
+    most = min(WALK_STEP_MAX, n_tiles // 16)
+    g = 1
+    while (2 * g <= most and n_tiles % (2 * g) == 0
+           and (chunk_tiles is None or chunk_tiles % (2 * g) == 0)):
+        g *= 2
+    return g
+
+
 def score_topk_bundle_fused(text_cols: dict, num_cols: dict, clauses: tuple,
                             cl_inputs: tuple, msm: jax.Array,
                             boost: jax.Array | None, live: jax.Array,
                             k: int, emit_match: bool = False,
-                            step=None, init_topk=None, idx_offset: int = 0):
+                            step=None, init_topk=None, idx_offset: int = 0,
+                            step_tiles: int | None = None):
     """Fused block-max-WAND score + top-k over a bool clause bundle.
 
     Returns (top_scores [B, k], top_idx [B, k], total [B] int32,
@@ -1187,6 +1217,17 @@ def score_topk_bundle_fused(text_cols: dict, num_cols: dict, clauses: tuple,
     win ties — the (segment order, doc id) tie rule), and the merged
     result equals a per-segment top-k union truncated host-side,
     byte-for-byte. Totals/prune stats cover ONLY this walk.
+
+    The walk takes `step_tiles` tiles a step (walk_step_tiles of the
+    pack's shapes where None). Bounds stay per tile: a step is skipped
+    when none of its tiles can match for any query, and the prune stats
+    count tiles as a walk of one tile a step does (a skipped step's
+    tiles are hard skips; a scored step's tile whose bound beats no
+    query's k-th best at the step's start is thresholded). A scored
+    step extracts candidates only where some match scores strictly
+    above its query's k-th best: passages come in increasing order, so
+    a later one that ties the k-th best loses the tie anyway, and the
+    merge would leave the state as it is.
     """
     field0 = bundle_primary_field(clauses)
     n_tiles = text_cols[field0]["tile_max"].n_tiles
@@ -1194,9 +1235,14 @@ def score_topk_bundle_fused(text_cols: dict, num_cols: dict, clauses: tuple,
     tile = cap // n_tiles
     b = msm.shape[0]
     k = min(k, cap) if init_topk is None else init_topk[0].shape[1]
-    ck = min(k, tile)
+    g = step_tiles or walk_step_tiles(
+        n_tiles, None if step is None else step[0])
+    slab = g * tile
+    ck = min(k, slab)
     can_match, ub = bundle_tile_bounds(clauses, cl_inputs, text_cols,
                                        num_cols, msm, boost)
+    can_match = can_match.reshape(b, n_tiles // g, g)
+    ub = ub.reshape(b, n_tiles // g, g)
     text_fields = bundle_text_fields(clauses)
     pos_fields = bundle_pos_fields(clauses)
     num_fields = tuple(dict.fromkeys(
@@ -1205,52 +1251,54 @@ def score_topk_bundle_fused(text_cols: dict, num_cols: dict, clauses: tuple,
                     if kd in _VEC_KINDS)
 
     def body(j, st):
-        lo = j * tile
-        can_j = jax.lax.dynamic_slice_in_dim(can_match, j, 1, axis=1)[:, 0]
-        ub_j = jax.lax.dynamic_slice_in_dim(ub, j, 1, axis=1)[:, 0]
+        lo = j * slab
+        can_j = jax.lax.dynamic_index_in_dim(can_match, j, axis=1,
+                                             keepdims=False)
+        ub_j = jax.lax.dynamic_index_in_dim(ub, j, axis=1, keepdims=False)
 
         def hard_skip(st):
-            return st[:3] + (st[3] + jnp.array([1, 0, 1], jnp.int32),) \
+            return st[:3] + (st[3] + jnp.array([g, 0, g], jnp.int32),) \
                 + st[4:]
 
-        def score_tile(st):
+        def score_step(st):
             top_s, top_i, total, pruned = st[:4]
             text_tiles = {
                 f: (jax.lax.dynamic_slice(
                         text_cols[f]["fwd_tids"], (lo, 0),
-                        (tile, text_cols[f]["fwd_tids"].shape[1])),
+                        (slab, text_cols[f]["fwd_tids"].shape[1])),
                     jax.lax.dynamic_slice(
                         text_cols[f]["fwd_imps"], (lo, 0),
-                        (tile, text_cols[f]["fwd_imps"].shape[1])))
+                        (slab, text_cols[f]["fwd_imps"].shape[1])))
                 for f in text_fields}
             pos_tiles = {
                 f: (jax.lax.dynamic_slice(
                         text_cols[f]["fwd_pos"], (lo, 0),
-                        (tile, text_cols[f]["fwd_pos"].shape[1])),
+                        (slab, text_cols[f]["fwd_pos"].shape[1])),
                     jax.lax.dynamic_slice(text_cols[f]["k1ln"], (lo,),
-                                          (tile,)),
+                                          (slab,)),
                     jax.lax.dynamic_slice(text_cols[f]["lnorm"], (lo,),
-                                          (tile,)))
+                                          (slab,)))
                 for f in pos_fields}
             num_tiles = {
                 f: (jax.lax.dynamic_slice(num_cols[f]["values"], (lo,),
-                                          (tile,)),
+                                          (slab,)),
                     jax.lax.dynamic_slice(num_cols[f]["exists"], (lo,),
-                                          (tile,)))
+                                          (slab,)))
                 for f in num_fields}
             vec_tiles = {
                 i: (jax.lax.dynamic_slice(cl_inputs[i][0], (0, lo),
-                                          (b, tile)),
+                                          (b, slab)),
                     jax.lax.dynamic_slice(cl_inputs[i][1], (lo,),
-                                          (tile,)))
+                                          (slab,)))
                 for i in vec_idx}
-            t_live = jax.lax.dynamic_slice(live, (lo,), (tile,))
+            t_live = jax.lax.dynamic_slice(live, (lo,), (slab,))
             score, match = bundle_tile_eval(clauses, cl_inputs, text_tiles,
                                             num_tiles, msm, boost, t_live,
                                             vec_tiles=vec_tiles,
                                             pos_tiles=pos_tiles)
             total = total + match.sum(axis=-1, dtype=jnp.int32)
-            can_top = can_j & (ub_j > top_s[:, -1])
+            kth = top_s[:, -1]
+            bound_beats = jnp.any(can_j & (ub_j > kth[:, None]), axis=0)
 
             def merge(args):
                 ts, ti = args
@@ -1259,19 +1307,19 @@ def score_topk_bundle_fused(text_cols: dict, num_cols: dict, clauses: tuple,
                 return running_topk_merge(ts, ti, c_s,
                                           c_loc + lo + idx_offset)
 
-            any_top = jnp.any(can_top)
-            top_s, top_i = jax.lax.cond(any_top, merge, lambda a: a,
+            beats = jnp.any(match & (score > kth[:, None]))
+            top_s, top_i = jax.lax.cond(beats, merge, lambda a: a,
                                         (top_s, top_i))
-            pruned = pruned + jnp.where(
-                any_top, jnp.array([0, 0, 1], jnp.int32),
-                jnp.array([0, 1, 1], jnp.int32))
+            thresholded = g - bound_beats.sum(dtype=jnp.int32)
+            pruned = pruned + jnp.stack(
+                [jnp.int32(0), thresholded, jnp.int32(g)])
             out = (top_s, top_i, total, pruned)
             if emit_match:
                 out = out + (jax.lax.dynamic_update_slice(
                     st[4], match, (0, lo)),)
             return out
 
-        return jax.lax.cond(jnp.any(can_j), score_tile, hard_skip, st)
+        return jax.lax.cond(jnp.any(can_j), score_step, hard_skip, st)
 
     top_s0, top_i0 = (running_topk_init(b, k) if init_topk is None
                       else init_topk)
@@ -1279,7 +1327,9 @@ def score_topk_bundle_fused(text_cols: dict, num_cols: dict, clauses: tuple,
            jnp.zeros((3,), jnp.int32))
     if emit_match:
         st0 = st0 + (jnp.zeros((b, cap), bool),)
-    st, timed = _stepped_tile_loop(n_tiles, body, st0, step)
+    if step is not None:
+        step = (step[0] // g,) + tuple(step[1:])
+    st, timed = _stepped_tile_loop(n_tiles // g, body, st0, step)
     out = st if emit_match else st[:4]
     return out if timed is None else out + (timed,)
 

@@ -54,13 +54,15 @@ from ..search.executor import (QueryBinder, finalize, eval_node,
                                autotune_persist_key, seg_cache_key,
                                _fused_stats,
                                _resident_step, _split_deadline,
-                               _RESIDENT_CHUNKS, _collect, _launch,
+                               _resident_chunk_tiles, _fused_walk,
+                               _walk_steps, _collect, _launch,
                                _start_fetch)
 from ..search import bound_plans
 from ..search.query_dsl import QueryParser
 from ..search.aggregations import (parse_aggs, ShardAggContext, AggSpec,
                                    merge_shard_partials, finalize_partials,
                                    shard_partials)
+from ..ops.scoring import bundle_primary_field
 from ..ops.topk import top_k_by_field, top_k_hits
 from ..search.controller import shards_header
 from ..search.shard_searcher import filter_source
@@ -1504,10 +1506,18 @@ class DistributedSearcher:
         stepped = (fused is not None and deadline is not None
                    and (allow_stepped if allow_stepped is not None
                         else _mesh_stepped_enabled()))
+        walks = ()
+        if fused is not None:
+            n_tiles = pk.dev["text"][bundle_primary_field(bundle)][
+                "tile_max"].n_tiles
+            walks = (_fused_walk(
+                bundle, fused[1], n_tiles, min(k, pk.cap),
+                _resident_chunk_tiles(n_tiles) if stepped else None),)
         st = {"run": self._compiled(desc, agg_desc, k, B // R, fused,
                                     stepped=stepped, sort=sort),
               "params": params, "agg_params": agg_params,
               "stepped": stepped, "fused": fused, "reject": reject,
+              "walks": walks,
               "agg_specs": agg_specs, "sort": sort,
               # captured NOW: a later _build_aggs (another group's
               # dispatch before this one collects) must not clobber it
@@ -1591,7 +1601,8 @@ class DistributedSearcher:
             # per dispatch
             _fused_stats.record_prune(
                 *(float(x) for x in prune[0]),
-                positional=_bundle_positional(st["fused"][0]))
+                positional=_bundle_positional(st["fused"][0]),
+                steps=_walk_steps(prune[0][2], st["walks"]))
 
         per_query_partials = [None] * B
         if agg_specs:
@@ -1745,10 +1756,9 @@ class DistributedSearcher:
         cap = pk.cap
         chunk_tiles = 1
         if stepped:
-            f0 = next(f for _r, kd, f, _w in fused[0]
-                      if kd in _FUSED_DENSE_KINDS)
-            n_tiles = pk.dev["text"][f0]["tile_max"].n_tiles
-            chunk_tiles = max(1, -(-n_tiles // _RESIDENT_CHUNKS))
+            chunk_tiles = _resident_chunk_tiles(
+                pk.dev["text"][bundle_primary_field(fused[0])][
+                    "tile_max"].n_tiles)
 
         gather_out = self._gather_out
         in_specs = (P("shard"), P("shard"), P("shard", "replica"),

@@ -42,7 +42,8 @@ from ..ops.scoring import (score_term, score_terms_fused,
                            BOUND_SLACK, positional_prefix, clause_fields,
                            bundle_text_fields, bundle_pos_fields,
                            positional_tile_scores, phrase_kind, span_kind,
-                           bm25f_kind, parse_positional_kind)
+                           bm25f_kind, parse_positional_kind,
+                           walk_step_tiles)
 from ..ops.knn import knn_score_column, SIMILARITIES as _KNN_SIMILARITIES
 from ..ops.pallas_scoring import (pallas_enabled, interpret_mode,
                                   score_term_pallas,
@@ -2434,19 +2435,60 @@ def _fused_params_ok(desc: tuple, params: tuple, bundle: tuple) -> bool:
 def _fused_row_elems(cap: int, n_tiles: int, k: int,
                      emit_match: bool = False,
                      vec_clauses: int = 0,
-                     pos_width: int = 0) -> int:
-    """Per-row transient of a fused dispatch in elements — one [*, tile]
-    scoring slab plus the [*, n_tiles*ck] candidate strip, plus the
+                     pos_width: int = 0,
+                     chunk_tiles: int | None = None) -> int:
+    """Per-row transient of a fused dispatch in elements — one [*, slab]
+    scoring slab (a step of the walk: walk_step_tiles of the shapes and
+    of a resident step's chunk_tiles, times the tile; one tile where
+    k == 0, whose mask-only walk steps a tile at a time) plus the
+    [*, steps*ck] candidate strip (ck = min(k, slab)), plus the
     [*, cap] bool match mask in emit-match (fused+aggs) mode, plus one
     [*, cap] similarity column per knn clause (the in-program vector
-    preamble), plus the decoded [*, tile, n*P] i32 position slab of the
+    preamble), plus the decoded [*, slab, n*P] i32 position slab of the
     widest positional clause (pos_width = its n * P; the per-clause
     decodes are sequential, so the widest bounds the live transient).
     The breaker estimate (execute_segment_async) and the chunking
     decision (_segment_body) MUST size from this one definition."""
-    tile = cap // n_tiles
-    return tile + n_tiles * min(k, tile) + (cap if emit_match else 0) \
-        + vec_clauses * cap + pos_width * tile
+    g = walk_step_tiles(n_tiles, chunk_tiles) if k > 0 else 1
+    slab = g * (cap // n_tiles)
+    return slab + (n_tiles // g) * min(k, slab) \
+        + (cap if emit_match else 0) + vec_clauses * cap + pos_width * slab
+
+
+def _fused_engine(bundle: tuple, backend: str) -> str:
+    """The engine a fused plan runs on: the kernel has no knn-clause
+    form (the similarity-column preamble is XLA-only) and no positional
+    form on the chip (_positional_needs_xla), so such a bundle runs the
+    XLA walk even where the kernel was chosen or forced — results are
+    identical either way, crashing is not."""
+    if any(kd in _FUSED_VEC_KINDS for _r, kd, _f, _w in bundle) \
+            or _positional_needs_xla(bundle):
+        return "xla"
+    return backend
+
+
+def _fused_walk(bundle: tuple, backend: str, n_tiles: int, k: int,
+                chunk_tiles: int | None = None) -> tuple[int, int]:
+    """(tiles, tiles a step) of the fused walk a launched plan makes,
+    for `fused_scoring.steps`: the XLA top-k walk steps as
+    ops/scoring.walk_step_tiles says, the mask-only walk (k == 0) and
+    the kernel a tile at a time."""
+    stepped = k > 0 and _fused_engine(bundle, backend) == "xla"
+    return n_tiles, walk_step_tiles(n_tiles, chunk_tiles) if stepped else 1
+
+
+def _walk_steps(examined: float, walks: tuple) -> tuple[float, float]:
+    """(steps, tiles) that the stepped walks among `walks` ((tiles,
+    tiles a step) of each walk of one launch) took, out of the
+    `examined` tiles the device counted for them all: every repetition
+    of the launch's walks (a batch's lax.map chunks, a mesh's shards)
+    repeats them alike."""
+    tiles = sum(n for n, _g in walks)
+    if not tiles:
+        return 0.0, 0.0
+    reps = float(examined) / tiles
+    return (reps * sum(n / g for n, g in walks if g > 1),
+            reps * sum(n for n, g in walks if g > 1))
 
 
 def _bundle_pos_width(bundle: tuple, text_cols) -> int:
@@ -2503,6 +2545,11 @@ class _FusedScoringStats:
         self._thresholded = 0.0
         self._examined = 0.0
         self._dispatches = 0
+        # launches whose walk took several tiles a step, those steps and
+        # their tiles (a part of `examined`: _walk_steps)
+        self._stepped_walks = 0
+        self._steps = 0.0
+        self._stepped_tiles = 0.0
         self._admitted = 0
         self._rejected: dict[str, int] = {}
         # positional (phrase/span/BM25F) observability: queries whose
@@ -2585,12 +2632,19 @@ class _FusedScoringStats:
             self._knn[reason] = self._knn.get(reason, 0) + 1
 
     def record_prune(self, hard: float, thresholded: float,
-                     examined: float, positional: bool = False) -> None:
+                     examined: float, positional: bool = False,
+                     steps: tuple[float, float] = (0.0, 0.0)) -> None:
+        """One launch's tile counters; `steps` = (steps, tiles) of its
+        walks that took several tiles a step (_walk_steps)."""
         with self._lock:
             self._hard += float(hard)
             self._thresholded += float(thresholded)
             self._examined += float(examined)
             self._dispatches += 1
+            if steps[1]:
+                self._stepped_walks += 1
+                self._steps += float(steps[0])
+                self._stepped_tiles += float(steps[1])
             if positional:
                 self._pos_hard += float(hard)
                 self._pos_thresholded += float(thresholded)
@@ -2642,6 +2696,9 @@ class _FusedScoringStats:
                 "tiles": {"examined": round(self._examined, 3),
                           "hard_skipped": round(self._hard, 3),
                           "thresholded": round(self._thresholded, 3)},
+                "steps": {"walks": self._stepped_walks,
+                          "steps": round(self._steps, 3),
+                          "stepped_tiles": round(self._stepped_tiles, 3)},
                 "prune_rate": (pruned / self._examined
                                if self._examined else 0.0),
                 "summary": {"bytes": self._summary_bytes,
@@ -2680,6 +2737,8 @@ class _FusedScoringStats:
             self._choices.clear()
             self._hard = self._thresholded = self._examined = 0.0
             self._dispatches = 0
+            self._stepped_walks = 0
+            self._steps = self._stepped_tiles = 0.0
             self._admitted = 0
             self._rejected.clear()
             self._pallas_rejected.clear()
@@ -3229,13 +3288,7 @@ def eval_fused_topk(seg: dict, desc: tuple, params: tuple,
         n_tiles = text_cols[bundle_primary_field(bundle)][
             "tile_max"].n_tiles
         cl_inputs = _vec_clause_inputs(seg, bundle, cl_inputs, n_tiles)
-        # the kernel has no knn-clause form (the similarity-column
-        # preamble is XLA-only); even a FORCED pallas choice demotes
-        # here — results are identical either way, crashing is not
-        backend = "xla"
-    if _positional_needs_xla(bundle):
-        backend = "xla"
-    if backend == "pallas":
+    if _fused_engine(bundle, backend) == "pallas":
         out = fused_topk_bundle_pallas(
             text_cols, num_cols, bundle, cl_inputs, msm, boost, live, k,
             emit_match=emit_match, step=step, interpret=interpret_mode(),
@@ -3274,10 +3327,7 @@ def eval_fused_match(seg: dict, desc: tuple, params: tuple,
         n_tiles = text_cols[bundle_primary_field(bundle)][
             "tile_max"].n_tiles
         cl_inputs = _vec_clause_inputs(seg, bundle, cl_inputs, n_tiles)
-        backend = "xla"    # no kernel form — see eval_fused_topk
-    if _positional_needs_xla(bundle):
-        backend = "xla"
-    if backend == "pallas":
+    if _fused_engine(bundle, backend) == "pallas":
         out = match_mask_bundle_pallas(
             text_cols, num_cols, bundle, cl_inputs, msm, boost, live,
             emit_match=emit_match, step=step, interpret=interpret_mode())
@@ -3327,7 +3377,8 @@ def _segment_body(seg: dict, params: tuple, live: jax.Array,
             cap, n_tiles, k, emit_match=bool(agg_desc),
             vec_clauses=sum(kd in _FUSED_VEC_KINDS
                             for _r, kd, _f, _w in fused[0]),
-            pos_width=_bundle_pos_width(fused[0], seg["text"]))
+            pos_width=_bundle_pos_width(fused[0], seg["text"]),
+            chunk_tiles=None if step is None else step[0])
     else:
         row_elems = cap
     # a resident stepped body never B-chunks: the step state (deadline
@@ -4403,6 +4454,11 @@ def _pack_program_packed(seg_b: dict, seg_d: dict, wire,
 _RESIDENT_CHUNKS = 8
 
 
+def _resident_chunk_tiles(n_tiles: int) -> int:
+    """Tiles a chunk of a stepped walk over n_tiles."""
+    return max(1, -(-n_tiles // _RESIDENT_CHUNKS))
+
+
 def _step_poll(hi, lo, delay_left, per_chunk, timed):
     """Host half of the device-side deadline check, invoked once per
     tile-loop chunk via io_callback. `hi + lo` reconstructs the f64
@@ -4729,11 +4785,14 @@ def _execute_resident(segment: Segment, live, desc: tuple, params: tuple,
     fused = (bundle, backend)
     f0 = bundle_primary_field(bundle)
     n_tiles = segment.text[f0].tile_max.n_tiles
-    chunk_tiles = max(1, -(-n_tiles // _RESIDENT_CHUNKS))
+    chunk_tiles = _resident_chunk_tiles(n_tiles)
     n_chunks = -(-n_tiles // chunk_tiles)
     row_elems = _fused_row_elems(
         cap, n_tiles, k_res, emit_match=bool(agg_desc),
-        pos_width=_bundle_pos_width(bundle, segment.text))
+        vec_clauses=sum(kd in _FUSED_VEC_KINDS
+                        for _r, kd, _f, _w in bundle),
+        pos_width=_bundle_pos_width(bundle, segment.text),
+        chunk_tiles=chunk_tiles)
     from ..utils.breaker import breaker_service
     req_breaker = breaker_service().breaker("request")
     # the stepped body never B-chunks (the step state rides ONE loop),
@@ -4823,6 +4882,8 @@ def _execute_resident(segment: Segment, live, desc: tuple, params: tuple,
     # BEFORE any further accounting can raise — no exit may leak the
     # out_bytes reservation (PR 4's invariant)
     layout = {**layout, **fetch, "resident": True, "shard_key": shard_key,
+              "walks": (_fused_walk(bundle, backend, n_tiles, k_res,
+                                    chunk_tiles),),
               "_breaker_hold": _gc_backstop(buf, req_hold),
               "_span_args": _span_args(bind)}
     # residency-bytes accounting (fielddata breaker, held until the
@@ -5161,6 +5222,9 @@ def execute_segment_async(segment: Segment, live: np.ndarray,
             dev, params, live_dev, live_views, agg_params, sort_params,
             desc, agg_desc, segment.capacity, k_eff, sort_spec,
             fused=fused)
+        if fused is not None:
+            layout = {**layout, "walks": (
+                _fused_walk(fused[0], fused[1], n_tiles, k_eff),)}
     except BaseException:
         req_hold.release()
         raise
@@ -5233,7 +5297,8 @@ def _unpack_tiered(host, layout, n_real: int):
     sk = float(layout.get("skipped_tiles", 0))
     _fused_stats.record_prune(
         hard + sk, thr, examined + sk,
-        positional=bool(layout.get("fused_positional")))
+        positional=bool(layout.get("fused_positional")),
+        steps=_walk_steps(examined, layout.get("walks", ())))
     # agg leaves round-trip through f32 on the packed-wire path;
     # mirror that here so reduce-side inputs are byte-identical
     agg_leaves = [np.asarray(leaf)[:n_real].astype(np.float32)
@@ -5277,7 +5342,8 @@ def _unpack_wire(wire, layout, n_real: int):
         hard, thr, examined = prune.sum(axis=0)
         _fused_stats.record_prune(
             hard, thr, examined,
-            positional=bool(layout.get("fused_positional")))
+            positional=bool(layout.get("fused_positional")),
+            steps=_walk_steps(examined, layout.get("walks", ())))
     agg_leaves = []
     for shape in layout["agg_shapes"]:
         size = int(np.prod(shape[1:])) if len(shape) > 1 else 1
@@ -5716,6 +5782,7 @@ def _execute_tiered(segment: Segment, live, desc: tuple, params: tuple,
         "fused_positional": _bundle_positional(bundle),
         "tiered": True,
         "skipped_tiles": skipped,
+        "walks": (_fused_walk(bundle, backend, ct, k_run),),
         "_breaker_hold": _gc_backstop(out_leaves[0] if out_leaves
                                       else None, req_hold),
         "_span_args": _span_args(bind),
@@ -5903,6 +5970,8 @@ def execute_pack_async(base: Segment, delta: Segment, live_b: np.ndarray,
     out_bytes = min(est, int(getattr(buf, "nbytes", 0)) or est)
     req_hold.shrink(out_bytes)
     layout = {**layout, **fetch,
+              "walks": (_fused_walk(bundle, fused[1], n_tiles_b, k_eff),
+                        _fused_walk(bundle, fused[1], n_tiles_d, k_eff)),
               "_breaker_hold": _gc_backstop(buf, req_hold),
               "_span_args": _span_args(bind)}
     return buf, layout, n_real
@@ -5959,14 +6028,19 @@ def _execute_pack_resident(base: Segment, delta: Segment, live_b, live_d,
     f0 = bundle_primary_field(bundle)
     n_tiles_b = base.text[f0].tile_max.n_tiles
     n_tiles_d = delta.text[f0].tile_max.n_tiles
-    chunk_tiles = max(1, -(-n_tiles_b // _RESIDENT_CHUNKS))
+    chunk_tiles = _resident_chunk_tiles(n_tiles_b)
     n_chunks = -(-n_tiles_b // chunk_tiles)
+    n_vec = sum(kd in _FUSED_VEC_KINDS for _r, kd, _f, _w in bundle)
+    # the step's chunks ride the base walk only (_pack_body)
     row_elems = (_fused_row_elems(cap_b, n_tiles_b, k_res,
                                   emit_match=bool(agg_desc),
+                                  vec_clauses=n_vec,
                                   pos_width=_bundle_pos_width(
-                                      bundle, base.text))
+                                      bundle, base.text),
+                                  chunk_tiles=chunk_tiles)
                  + _fused_row_elems(cap_d, n_tiles_d, k_res,
                                     emit_match=bool(agg_desc),
+                                    vec_clauses=n_vec,
                                     pos_width=_bundle_pos_width(
                                         bundle, delta.text)))
     from ..utils.breaker import breaker_service
@@ -6039,6 +6113,9 @@ def _execute_pack_resident(base: Segment, delta: Segment, live_b, live_d,
     out_bytes = min(est, int(getattr(buf, "nbytes", 0)) or est)
     req_hold.shrink(out_bytes)
     layout = {**layout, **fetch, "resident": True, "shard_key": shard_key,
+              "walks": (_fused_walk(bundle, backend, n_tiles_b, k_res,
+                                    chunk_tiles),
+                        _fused_walk(bundle, backend, n_tiles_d, k_res)),
               "_breaker_hold": _gc_backstop(buf, req_hold),
               "_span_args": _span_args(bind)}
     code_bytes = 0
@@ -6089,7 +6166,8 @@ def _unpack_pack(wire, layout, n_real: int):
     hard, thr, examined = prune.sum(axis=0)
     _fused_stats.record_prune(
         hard, thr, examined,
-        positional=bool(layout.get("fused_positional")))
+        positional=bool(layout.get("fused_positional")),
+        steps=_walk_steps(examined, layout.get("walks", ())))
     f_off = k + 3
     agg_leaves = []
     for shape in layout["agg_shapes"]:

@@ -1363,3 +1363,412 @@ class TestProfilerPathRestriction:
                            {"path": "traces"})
         finally:
             node.close()
+
+
+# ---------------------------------------------------------------------------
+# Several tiles a step (ops/scoring.walk_step_tiles): the XLA walk of a
+# large pack scores a slab of G tiles an iteration, with bounds per tile
+# and candidates extracted only where a passage beats a query's k-th best.
+# Every answer is the one-tile walk's, bit for bit.
+# ---------------------------------------------------------------------------
+
+STEP_TILE = 128            # the narrowest tile build_tile_max takes
+FREQUENT = 16              # terms 0..15 occur in most passages
+RARE = 8                   # the vocabulary's last terms: a few tiles each
+
+
+@pytest.mark.parametrize("n_tiles,chunk_tiles,want", [
+    (1, None, 1), (2, None, 1), (4, None, 1), (8, None, 1), (16, None, 1),
+    (32, None, 2), (48, None, 2), (64, None, 4), (128, None, 8),
+    (256, None, 16), (2048, None, 16), (256, 32, 16), (256, 8, 8),
+    (64, 8, 4), (64, 2, 2), (256, 3, 1)])
+def test_walk_step_tiles_follows_the_shapes(n_tiles, chunk_tiles, want):
+    from elasticsearch_tpu.ops.scoring import walk_step_tiles
+    assert walk_step_tiles(n_tiles, chunk_tiles) == want
+
+
+def _passage_pack(rng, n_tiles, slots=24, vocab=200, tile=STEP_TILE):
+    """A forward index of distinct term ids a passage: each frequent
+    term in 90% of passages, the other slots from the middle of the
+    vocabulary (a fifth of them empty), and each rare term in 30
+    passages of two tiles, so that a query of it skips most steps."""
+    cap = n_tiles * tile
+    tids = np.full((cap, slots), -1, np.int32)
+    freq = rng.random((cap, FREQUENT)) < 0.9
+    tids[:, :FREQUENT] = np.where(freq, np.arange(FREQUENT), -1)
+    mid = vocab - RARE - FREQUENT
+    other = np.argsort(rng.random((cap, mid)), axis=1)[
+        :, :slots - FREQUENT] + FREQUENT
+    other[rng.random(other.shape) < 0.2] = -1
+    tids[:, FREQUENT:] = other
+    for r in range(vocab - RARE, vocab):
+        for t in rng.choice(n_tiles, 2, replace=False):
+            docs = t * tile + rng.choice(tile, 30, replace=False)
+            tids[docs, -1] = r
+    imps = np.where(tids >= 0, rng.random((cap, slots), dtype=np.float32)
+                    + 0.1, 0.0).astype(np.float32)
+    return tids, imps, build_tile_max(tids, imps, vocab, cap, tile=tile)
+
+
+def _match_bundle(rng, op, terms, b, vocab=200):
+    """A `match` of `terms` terms the way the binder gives it: one dense
+    should clause under `or`, a must clause a term under `and` (mostly
+    frequent terms, so that conjunctions match; every other row, the
+    first among them, brings one rare term, so that most steps skip)."""
+    rows = []
+    for row in range(b):
+        if op == "or":
+            pick = rng.choice(vocab, terms, replace=False)
+        else:
+            pick = rng.choice(FREQUENT, terms - 1, replace=False).tolist()
+            pick.append(next(t for t in range(FREQUENT) if t not in pick)
+                        if row % 2 else vocab - 1 - row % RARE)
+        rows.append(np.asarray(pick, np.int32))
+    qt = np.stack(rows)
+    wq = (rng.random(qt.shape, dtype=np.float32) + 0.5).astype(np.float32)
+    ones_i, ones_f = np.ones(b, np.int32), np.ones(b, np.float32)
+    if op == "or":
+        return ((("should", "terms_dense", "f", False),),
+                ((qt, wq, ones_i, ones_f),), ones_i)
+    return (tuple(("must", "term_text", "f", False) for _ in range(terms)),
+            tuple((qt[:, i:i + 1], wq[:, i:i + 1], ones_i, ones_f)
+                  for i in range(terms)),
+            np.zeros(b, np.int32))
+
+
+def _step_args(tids, imps, tm, clauses, cl_inputs, msm, live, k):
+    text_cols = {"f": {"fwd_tids": jnp.asarray(tids),
+                       "fwd_imps": jnp.asarray(imps),
+                       "tile_max": jax.device_put(tm)}}
+    return (text_cols, {}, clauses,
+            tuple(tuple(jnp.asarray(a) for a in inp) for inp in cl_inputs),
+            jnp.asarray(msm), None, jnp.asarray(live), k)
+
+
+def _walked(args, step_tiles=None, **kw):
+    return [np.asarray(x) for x in score_topk_bundle_fused(
+        *args, step_tiles=step_tiles, **kw)]
+
+
+def _assert_same_walk(stepped, one):
+    """Scores, ids, totals (and the match mask) bit for bit."""
+    for a, b in zip(stepped[:3], one[:3]):
+        assert a.dtype == b.dtype and np.array_equal(a, b)
+    if len(stepped) > 4:
+        assert np.array_equal(stepped[4], one[4])
+
+
+STEP_CASES = [(n, op, m, b, emit)
+              for n, batches in ((256, (1, 2, 4, 8)), (64, (8, 4, 2, 1)))
+              for op in ("or", "and")
+              for m, b, emit in zip((3, 6, 10, 16), batches,
+                                    (False, True, False, True))]
+
+
+@pytest.mark.parametrize("n_tiles,op,terms,b,emit", STEP_CASES)
+def test_a_stepped_walk_is_the_one_tile_walk(n_tiles, op, terms, b, emit):
+    from elasticsearch_tpu.ops.scoring import walk_step_tiles
+    rng = np.random.default_rng([n_tiles, terms, b])
+    tids, imps, tm = _passage_pack(rng, n_tiles)
+    clauses, cl_inputs, msm = _match_bundle(rng, op, terms, b)
+    live = rng.random(tids.shape[0]) > 0.05
+    args = _step_args(tids, imps, tm, clauses, cl_inputs, msm, live, 10)
+    g = walk_step_tiles(n_tiles)
+    assert g == {256: 16, 64: 4}[n_tiles]
+    stepped = _walked(args, emit_match=emit)
+    one = _walked(args, 1, emit_match=emit)
+    _assert_same_walk(stepped, one)
+    # the plain reference: totals and the mask exact, each hit's score
+    ref_s, _ref_i, ref_t, ref_m = _np_bundle_reference(
+        clauses, cl_inputs, {"f": (tids, imps)}, {}, msm,
+        np.ones(b, np.float32), live, 10)
+    s, i, t = stepped[:3]
+    assert np.array_equal(t, ref_t) and t.max() > 0
+    if emit:
+        assert np.array_equal(stepped[4], ref_m)
+    for row in range(b):
+        n = min(int(ref_t[row]), 10)
+        np.testing.assert_allclose(s[row, :n], ref_s[row, :n], rtol=1e-6)
+        assert np.isneginf(s[row, n:]).all()
+        assert ref_m[row, i[row, :n]].all()
+    # counters count tiles; a skip is a whole step's
+    hard, thr, examined = stepped[3]
+    assert examined == one[3][2] == n_tiles
+    assert hard % g == 0 and hard <= one[3][0]
+
+
+def _tied_pack(n_tiles, tile=STEP_TILE, late=(3, 50, 100, 101, 150, 255)):
+    """Every passage holds term 0 at 0.5; two passages in each of the
+    `late` tiles hold term 1 at 0.25 too. Sums are exact, so a `match`
+    of both terms scores 0.75 there and 0.5 elsewhere: twelve passages
+    tie for the top, and ten of them fit in k."""
+    cap = n_tiles * tile
+    tids = np.full((cap, 2), -1, np.int32)
+    imps = np.zeros((cap, 2), np.float32)
+    tids[:, 0], imps[:, 0] = 0, 0.5
+    for t in late:
+        if t < n_tiles:
+            for d in (t * tile + 7, t * tile + 90):
+                tids[d, 1], imps[d, 1] = 1, 0.25
+    return tids, imps, build_tile_max(tids, imps, 2, cap, tile=tile)
+
+
+@pytest.mark.parametrize("op", ["or", "and"])
+def test_a_tie_at_the_kth_place_resolves_as_the_one_tile_walk(op):
+    tids, imps, tm = _tied_pack(256)
+    b = 2
+    qt = np.asarray([[0, 1], [1, 0]], np.int32)
+    wq = np.ones((b, 2), np.float32)
+    ones_i, ones_f = np.ones(b, np.int32), np.ones(b, np.float32)
+    if op == "or":
+        clauses = (("should", "terms_dense", "f", False),)
+        cl_inputs = ((qt, wq, ones_i, ones_f),)
+        msm = ones_i
+    else:
+        clauses = (("must", "term_text", "f", False),) * 2
+        cl_inputs = tuple((qt[:, i:i + 1], wq[:, i:i + 1], ones_i, ones_f)
+                          for i in range(2))
+        msm = np.zeros(b, np.int32)
+    live = np.ones(tids.shape[0], bool)
+    args = _step_args(tids, imps, tm, clauses, cl_inputs, msm, live, 10)
+    stepped, one = _walked(args, emit_match=True), _walked(args, 1,
+                                                           emit_match=True)
+    _assert_same_walk(stepped, one)
+    ref_s, ref_i, ref_t, _m = _np_bundle_reference(
+        clauses, cl_inputs, {"f": (tids, imps)}, {}, msm, ones_f, live, 10)
+    assert np.array_equal(stepped[0], ref_s)
+    assert np.array_equal(stepped[1], ref_i)
+    assert np.array_equal(stepped[2], ref_t)
+    top = np.sort(np.concatenate([t * STEP_TILE + np.asarray([7, 90])
+                                  for t in (3, 50, 100, 101, 150)]))
+    assert (stepped[1][:, :10] == top).all() and (stepped[0] == 0.75).all()
+
+
+@pytest.mark.parametrize("delta_tiles", [4, 64])
+def test_a_base_to_delta_chain_is_the_one_tile_chain(delta_tiles):
+    """Base (256 tiles) then delta with the base's top-k as its opening
+    state and its ids past the base: the pack program's chain. The
+    delta's passages tie with the base's and lose to them."""
+    from elasticsearch_tpu.ops.topk import running_topk_init
+    rng = np.random.default_rng(delta_tiles)
+    b, k = 4, 10
+    tb, ib, mb = _tied_pack(256, late=(9, 200))
+    td, idd, md = _tied_pack(delta_tiles, late=(0, 1, 2, 3))
+    qt = np.asarray([[0, 1]] * b, np.int32)
+    wq = np.ones((b, 2), np.float32)
+    # eighths: every product and sum is exact, so the reference agrees
+    # to the bit
+    wq[1:] = rng.integers(4, 13, (b - 1, 2)) / 8
+    ones_i, ones_f = np.ones(b, np.int32), np.ones(b, np.float32)
+    clauses = (("should", "terms_dense", "f", False),)
+    cl_inputs = ((qt, wq, ones_i, ones_f),)
+
+    def chain(step_tiles):
+        base = _walked(_step_args(tb, ib, mb, clauses, cl_inputs, ones_i,
+                                  np.ones(tb.shape[0], bool), k),
+                       step_tiles, init_topk=running_topk_init(b, k))
+        delta = _walked(_step_args(td, idd, md, clauses, cl_inputs, ones_i,
+                                   np.ones(td.shape[0], bool), k),
+                        step_tiles, init_topk=(jnp.asarray(base[0]),
+                                               jnp.asarray(base[1])),
+                        idx_offset=tb.shape[0])
+        return base, delta
+
+    (base_s, delta_s), (base_1, delta_1) = chain(None), chain(1)
+    _assert_same_walk(base_s, base_1)
+    _assert_same_walk(delta_s, delta_1)
+    both = (np.concatenate([tb, td]), np.concatenate([ib, idd]))
+    ref_s, ref_i, ref_t, _m = _np_bundle_reference(
+        clauses, cl_inputs, {"f": both}, {}, ones_i, ones_f,
+        np.ones(both[0].shape[0], bool), k)
+    assert np.array_equal(delta_s[0], ref_s)
+    assert np.array_equal(delta_s[1], ref_i)
+    assert np.array_equal(base_s[2] + delta_s[2], ref_t)
+    # the row of weights 1: four base passages, then six of the delta's
+    # eight, which tie with each other and lose to the base's
+    assert (delta_s[1][0, :4] < tb.shape[0]).all()
+    assert (delta_s[1][0, 4:] >= tb.shape[0]).all()
+
+
+@pytest.mark.parametrize("fires", [False, True])
+def test_a_resident_step_ends_where_the_one_tile_walk_ends(fires):
+    """A stepped walk at 256 tiles in the resident loop's chunks (G must
+    divide them, so a chunk ends on a step): the same answers and the
+    same timed-out verdict as one tile a step, for a check that never
+    fires and for one that fires after the first chunk."""
+    from elasticsearch_tpu.ops.scoring import walk_step_tiles
+    from elasticsearch_tpu.search import executor as ex
+    rng = np.random.default_rng(11)
+    tids, imps, tm = _passage_pack(rng, 256)
+    clauses, cl_inputs, msm = _match_bundle(rng, "or", 6, 4)
+    live = np.ones(tids.shape[0], bool)
+    args = _step_args(tids, imps, tm, clauses, cl_inputs, msm, live, 10)
+    chunk = ex._resident_chunk_tiles(256)
+    assert (ex._RESIDENT_CHUNKS, chunk) == (8, 32)
+    assert walk_step_tiles(256, chunk) == 16
+
+    def check(c, st):
+        return (jnp.asarray(c >= 1) if fires else jnp.bool_(False)), st
+
+    stepped = _walked(args, step=(chunk, 0, check))
+    one = _walked(args, 1, step=(chunk, 0, check))
+    _assert_same_walk(stepped, one)
+    assert bool(stepped[-1]) == bool(one[-1]) == fires
+    assert stepped[3][2] == one[3][2] == (chunk if fires else 256)
+
+
+def _pack_of_words(n_docs, seed=5):
+    """A segment of `n_docs` short texts (four or five of 60 words; the
+    tile 128 wide where the test narrows it) and its live mask."""
+    from elasticsearch_tpu.index.mapping import MapperService
+    from elasticsearch_tpu.index.segment import SegmentBuilder
+    rng = random.Random(seed)
+    vocab = [f"w{i:03d}" for i in range(60)]
+    svc = MapperService(mapping={"properties": {
+        "message": {"type": "text"}}})
+    builder = SegmentBuilder()
+    for i in range(n_docs):
+        builder.add(svc.parse(str(i), {"message": " ".join(
+            rng.choices(vocab, k=4 + i % 2))}))
+    seg = builder.build("steps")
+    live = np.zeros(seg.capacity, bool)
+    live[: seg.num_docs] = True
+    return svc, seg, live
+
+
+@pytest.mark.parametrize("body", [
+    {"match": {"message": "w001 w002 w003"}},
+    {"match_phrase": {"message": "w004 w005"}}])
+def test_the_breaker_and_the_chunking_size_a_step(monkeypatch, body):
+    """At 256 tiles the one estimate of a fused dispatch's transient is
+    the step's slab (16 tiles) and its strip (16 steps of min(k, slab)
+    candidates), and a positional clause's decoded slab is the step's
+    too; the breaker's estimate (execute_segment_async) and the
+    chunking decision (_segment_body, traced anew here) read the same
+    number for a batch of 8."""
+    from elasticsearch_tpu.index import segment as segment_mod
+    from elasticsearch_tpu.search import executor as ex
+    from elasticsearch_tpu.search.query_dsl import QueryParser
+    monkeypatch.setattr(segment_mod, "SCORE_TILE", STEP_TILE)
+    svc, seg, live = _pack_of_words(16_500)
+    assert seg.text["message"].tile_max.n_tiles == 256
+    sized = []
+    real = ex._fused_row_elems
+
+    def recorded(*a, **kw):
+        sized.append(real(*a, **kw))
+        return sized[-1]
+
+    monkeypatch.setattr(ex, "_fused_row_elems", recorded)
+    monkeypatch.setattr(ex, "_out_layout_cache", {})
+    binder = ex.QueryBinder(seg, svc)
+    bounds = [binder.bind(QueryParser(svc).parse(body)) for _ in range(8)]
+    ex._fused_stats.reset()
+    (_ts, _tk, _ti, tt, _tm), _ = ex.execute_segment(seg, live, bounds, 10)
+    assert ex.fused_scoring_stats()["admission"]["admitted"] == 1
+    pf = seg.text["message"]
+    # a phrase of two terms decodes P positions a term and passage
+    pos = 2 * (pf.fwd_pos.shape[1] // pf.fwd_tids.shape[1]) \
+        if "match_phrase" in body else 0
+    slab = 16 * STEP_TILE
+    assert len(sized) >= 2 and set(sized) == {slab + 16 * 10 + pos * slab}
+    assert ex._chunk_b(8, sized[0]) == 8 and int(tt[0]) > 0
+
+
+def test_the_steps_are_counted_in_node_stats(tmp_path, monkeypatch):
+    """A served `match` over a pack of 256 tiles walks 16 tiles a step,
+    and `_nodes/stats` -> `fused_scoring.steps` says so: its tiles are
+    every tile the search examined. A pack of 4 tiles walks one a step
+    and adds nothing there."""
+    import http.client
+    import json
+    from elasticsearch_tpu.index import segment as segment_mod
+    from elasticsearch_tpu.node import Node
+    from elasticsearch_tpu.rest.server import RestServer
+    monkeypatch.setattr(segment_mod, "SCORE_TILE", STEP_TILE)
+    node = Node({"path.data": str(tmp_path / "data"),
+                 "index.number_of_replicas": 0})
+    server = RestServer(node, "127.0.0.1", 0).start()
+    conn = http.client.HTTPConnection(server.host, server.port, timeout=120)
+
+    def call(method, path, body=None):
+        data = body if isinstance(body, (bytes, type(None))) \
+            else json.dumps(body).encode()
+        conn.request(method, path, body=data,
+                     headers={"Content-Type": "application/json"})
+        r = conn.getresponse()
+        raw = r.read()
+        assert r.status == 200, (method, path, raw[:300])
+        return json.loads(raw)
+
+    def fused():
+        return next(iter(call("GET", "/_nodes/stats")["nodes"].values())
+                    )["fused_scoring"]
+
+    rng = random.Random(3)
+    vocab = [f"w{i:03d}" for i in range(60)]
+    try:
+        for index, docs in (("big", 16_500), ("small", 400)):
+            call("PUT", f"/{index}", {
+                "settings": {"index.number_of_shards": 1,
+                             "index.number_of_replicas": 0},
+                "mappings": {"properties": {"message": {"type": "text"}}}})
+            lines = []
+            for i in range(docs):
+                lines += [json.dumps({"index": {"_id": str(i)}}),
+                          json.dumps({"message": " ".join(
+                              rng.choices(vocab, k=5))})]
+            assert not call("POST", f"/{index}/_bulk",
+                            ("\n".join(lines) + "\n").encode())["errors"]
+            call("POST", f"/{index}/_refresh")
+        grids = {index: seg.text["message"].tile_max.n_tiles
+                 for index in ("big", "small")
+                 for seg in node.indices[index].shards[0]
+                 .acquire_searcher().segments}
+        assert grids == {"big": 256, "small": 4}
+        for index, stepped in (("big", True), ("small", False)):
+            before = fused()
+            r = call("POST", f"/{index}/_search",
+                     {"query": {"match": {"message": "w001 w002"}}})
+            total = r["hits"]["total"]
+            assert (total["value"] if isinstance(total, dict) else total)
+            after = fused()
+            examined = after["tiles"]["examined"] \
+                - before["tiles"]["examined"]
+            steps = {key: after["steps"][key] - before["steps"][key]
+                     for key in ("walks", "steps", "stepped_tiles")}
+            assert examined == grids[index]
+            if stepped:
+                assert steps == {"walks": 1, "steps": 16,
+                                 "stepped_tiles": examined}
+            else:
+                assert steps == {"walks": 0, "steps": 0,
+                                 "stepped_tiles": 0}
+    finally:
+        conn.close()
+        server.stop()
+        node.close()
+
+
+@pytest.mark.parametrize("step_tiles,thresholded", [(None, 240), (1, 255)])
+def test_thresholded_counts_the_tiles_of_a_scored_step(step_tiles,
+                                                       thresholded):
+    """Tile 0 outscores every later tile: once the k-th best is 1.0 no
+    later tile's bound (0.5) beats it. One tile a step, that is every
+    tile after the first; 16 a step, every tile after the first step,
+    whose tiles were judged against the k-th best at its start."""
+    cap = 256 * STEP_TILE
+    tids = np.zeros((cap, 1), np.int32)
+    imps = np.full((cap, 1), 0.5, np.float32)
+    imps[:STEP_TILE] = 1.0
+    tm = build_tile_max(tids, imps, 1, cap, tile=STEP_TILE)
+    b = 2
+    ones_i, ones_f = np.ones(b, np.int32), np.ones(b, np.float32)
+    args = _step_args(tids, imps, tm, (("should", "terms_dense", "f",
+                                         False),),
+                      ((np.zeros((b, 1), np.int32),
+                        np.ones((b, 1), np.float32), ones_i, ones_f),),
+                      ones_i, np.ones(cap, bool), 3)
+    top_s, top_i, total, pruned = _walked(args, step_tiles)
+    assert (top_i == [0, 1, 2]).all() and (total == cap).all()
+    assert pruned.tolist() == [0, thresholded, 256]
