@@ -66,6 +66,16 @@ them waited from the one `dispatch()` call to its return. Searches of
 two batches coalesced into one reader call are each served by it:
 both batches' clocks stop.
 
+The wait is recorded in three parts that tile it, from the same clock
+readings, beside `phases` (`scheduler_wait: {parked, queued, woken}`):
+`parked`, the batch pending while no thread holds the leader (from its
+call, or the last leader's release if later, to the next acquisition:
+what a caller sleeping out the poll pays); `woken`, from its round's
+`_done.set()` to `dispatch()` resuming (the caller's wake-up); and
+`queued`, the rest (the round in flight ahead of it, the coalescing
+window, other reader groups of its own round). While a trace runs,
+each sleep of a caller on its batch is a `wait:scheduler` span.
+
 Stats surface under `nodes_stats()["dispatch"]` (lanes/window/tenant
 counters under `["dispatch"]["traffic"]`): `searches` coordinated and
 the shard jobs run for them (`queries`), the `leader` timer (rounds led,
@@ -208,6 +218,10 @@ class MembershipStats:
 failover_stats = FailoverStats()
 eviction_stats = EvictionStats()
 membership_stats = MembershipStats()
+# the three parts of `scheduler_wait` (module doc), once a search like
+# the wait; process-wide like the phase timer they tile
+WAIT_PARTS = ("parked", "queued", "woken")
+_wait_parts = {name: MeanMetric() for name in WAIT_PARTS}
 # serializes the install/reset pair: two nodes racing init/close could
 # otherwise interleave the reads and rebinds and strand one node's
 # counters installed under the other's ownership check
@@ -308,7 +322,7 @@ class DispatchStats:
         from ..utils import race_guard, trace_guard
         from . import bound_plans
         from ..parallel import mesh_reader
-        from .executor import collect_counts, collect_lead, launch_counts
+        from .executor import collect_counts, collect_parts, launch_counts
         from .resident import resident_stats
         wb = self._window_batches.count
         wc = self._window_coalesced.count
@@ -340,11 +354,16 @@ class DispatchStats:
             # Process-wide, so read them as deltas
             "phases": profiler.phase_stats(),
             "launches": launch_counts(),
-            # collects, those whose launch had started the copy to the
-            # host, and the host's seconds between a launch's return
-            # and its collect (what the copy could hide behind)
+            # the parts of two of those phases, beside them and never
+            # inside (each part is time its phase already counts):
+            # `scheduler_wait` as parked, queued and woken (module
+            # doc), `collect` as compute and copy (executor._collect)
+            "scheduler_wait": {name: m.snapshot()
+                               for name, m in _wait_parts.items()},
+            "collect_parts": collect_parts(),
+            # collects, and those whose launch had started the copy to
+            # the host
             "collects": collect_counts(),
-            "collect_lead": collect_lead(),
             # what the readers kept of the bodies they had served, once
             # a reader call's group: launched from kept plans (hits),
             # built and kept (misses), not kept (bypassed); evictions
@@ -441,6 +460,18 @@ class DispatchBatch:
         # reader groups and starts the clock again where it lets go
         self.t_handed: float | None = None
         self.waited_s = 0.0
+        # the wait's parts (module doc): `t_entry` is the reading at
+        # dispatch()'s call (None: run() was called without it),
+        # `parked_s` what DispatchScheduler._lead charged the batch at
+        # each acquisition of the leader, `t_done` the reading at its
+        # `_done.set()`
+        self.t_entry: float | None = None
+        self.parked_s = 0.0
+        self.t_done: float | None = None
+        # the three, in seconds, as dispatch() recorded them
+        self.parts: dict[str, float] = {}
+        # `request=` / `requests=` of its `wait:scheduler` spans
+        self.span_args: dict = {}
 
     def submit(self, reader, body: dict, with_partials: bool = False,
                deadline: float | None = None,
@@ -456,17 +487,26 @@ class DispatchBatch:
         job.result(), never by dispatch() itself. Records the batch's
         `scheduler_wait` once for each search it holds (the jobs of one
         search carry one request id; in-process callers' jobs, which
-        carry none, are one search)."""
+        carry none, are one search), and so its parts."""
         if not self.jobs:
             self._done.set()
             return
-        searches = len({j.request for j in self.jobs})
+        requests = list(dict.fromkeys(j.request for j in self.jobs))
+        searches = len(requests)
         self._scheduler.stats.searches.inc(searches)
-        self.t_handed = time.perf_counter()
+        self.span_args = profiler.request_args(requests)
+        self.t_handed = self.t_entry = time.perf_counter()
         self._scheduler.run(self)
-        wait = self.waited_s + time.perf_counter() - self.t_handed
+        now = time.perf_counter()
+        wait = self.waited_s + now - self.t_handed
+        woken = now - self.t_done
+        self.parts = {"parked": self.parked_s,
+                      "queued": wait - self.parked_s - woken,
+                      "woken": woken}
         for _ in range(searches):
             profiler.waited("scheduler_wait", wait)
+            for name, seconds in self.parts.items():
+                _wait_parts[name].inc(seconds)
 
 
 class DispatchScheduler:
@@ -480,6 +520,9 @@ class DispatchScheduler:
         # and the drain's dispatch/collect by design; waiters are exactly
         # the batches the drain is executing, parked on batch._done
         self._leader = threading.Lock()
+        # the perf_counter reading at the last release of `_leader`,
+        # written by its holder: from it a pending batch is parked
+        self._released = time.perf_counter()
         self._pending: list[DispatchBatch] = race_guard.guarded_list(
             self._mx, "dispatch.DispatchScheduler._pending")
         self._window_default = float(window_ms)
@@ -520,18 +563,7 @@ class DispatchScheduler:
         if self._traffic is not None:
             self._traffic.note_lane_depth(batch.lane, lane_depth)
             self._traffic.window.observe_arrival()
-        if self._leader.acquire(blocking=False):
-            try:
-                w = self.window_ms()
-                if w > 0:
-                    # hold the door for concurrent REST traffic that
-                    # would otherwise just miss this drain (static: the
-                    # operator asked; adaptive: the controller predicts
-                    # another arrival inside the window)
-                    time.sleep(w / 1000.0)
-                self._drain(windowed=w > 0, until=batch)
-            finally:
-                self._leader.release()
+        self._lead(batch, windowed=True)
         # a leader was mid-flight: it adopts this batch in a coming
         # round. Wait on COMPLETION, not on the leader lock — with
         # priority lanes the leader may keep draining a deep bulk
@@ -542,13 +574,45 @@ class DispatchScheduler:
         # batch): the first retry comes fast, then the poll backs off
         # so a deep backlog of waiting callers is not a wakeup storm.
         poll_s = 0.001
-        while not batch._done.wait(timeout=poll_s):
-            if self._leader.acquire(blocking=False):
-                try:
-                    self._drain(windowed=False, until=batch)
-                finally:
-                    self._leader.release()
+        while not self._sleep(batch, poll_s):
+            self._lead(batch, windowed=False)
             poll_s = 0.05
+
+    def _lead(self, until: DispatchBatch, windowed: bool) -> None:
+        """Drain as the leader where no thread leads. At the
+        acquisition every pending batch is charged the time it was
+        `parked`: since its call or the last release, whichever is
+        later. `windowed`: the first try of a caller, which may hold
+        the door (the coalescing window)."""
+        if self._leader.acquire(blocking=False):
+            try:
+                acquired = time.perf_counter()
+                with self._mx:
+                    for b in self._pending:
+                        if b.t_entry is not None:
+                            b.parked_s += max(0.0, acquired - max(
+                                b.t_entry, self._released))
+                w = self.window_ms() if windowed else 0.0
+                if w > 0:
+                    # hold the door for concurrent REST traffic that
+                    # would otherwise just miss this drain (static: the
+                    # operator asked; adaptive: the controller predicts
+                    # another arrival inside the window)
+                    time.sleep(w / 1000.0)
+                self._drain(windowed=w > 0, until=until)
+            finally:
+                self._released = time.perf_counter()
+                self._leader.release()
+
+    @staticmethod
+    def _sleep(batch: DispatchBatch, timeout: float) -> bool:
+        """One sleep of a caller on its batch's completion, drawn as a
+        `wait:scheduler` span while a trace runs (none where the batch
+        is already done: its caller led the round)."""
+        if batch._done.is_set():
+            return True
+        with profiler.enclosing("wait:scheduler", **batch.span_args):
+            return batch._done.wait(timeout=timeout)
 
     def _lane_quota(self, lane: str) -> int | None:
         if lane == "interactive":
@@ -613,6 +677,7 @@ class DispatchScheduler:
                 self._execute([j for b in round_ for j in b.jobs])
             finally:
                 for b in round_:
+                    b.t_done = time.perf_counter()
                     b._done.set()
 
     # -- execution ---------------------------------------------------------

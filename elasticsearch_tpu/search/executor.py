@@ -58,7 +58,7 @@ from ..utils.errors import (QueryParsingError, SearchParseError,
                             SearchTimeoutError)
 from ..utils.metrics import (MeanMetric as _MeanMetric,
                              MetricsRegistry as _MetricsRegistry)
-from ..utils.profiler import phase as _phase
+from ..utils.profiler import enclosing as _enclosing, phase as _phase
 from ..utils import trace_guard as _trace_guard
 from . import resident as _resident
 from .bound_plans import note_volatile as _note_volatile
@@ -2768,23 +2768,26 @@ def launch_counts() -> dict:
 
 
 # collects, and those whose launch had already asked for the result's
-# device-to-host copy (`_start_fetch`); and the host's seconds between
-# a launch's return and the start of its collect, which is what that
-# copy could hide behind. Process-wide, read as deltas through
-# `_nodes/stats/dispatch` -> "collects", "collect_lead" (beside
-# "launches", never inside "phases": neither is a tile of a search)
+# device-to-host copy (`_start_fetch`); and the `collect` phase in two
+# parts that tile it (`_collect`), weighted like the phase: `compute`
+# up to the program's end, `copy` the rest. Process-wide, read as
+# deltas through `_nodes/stats/dispatch` -> "collects", "collect_parts"
+# (beside "launches", never inside "phases": the first is no time, the
+# second time `collect` counts already)
 _collects = _MetricsRegistry()
 for _name in ("total", "prefetched"):
     _collects.counter(_name)
-_collect_lead = _MeanMetric()
+_collect_compute = _MeanMetric()
+_collect_copy = _MeanMetric()
 
 
 def collect_counts() -> dict:
     return _collects.snapshot()
 
 
-def collect_lead() -> dict:
-    return _collect_lead.snapshot()
+def collect_parts() -> dict:
+    return {"compute": _collect_compute.snapshot(),
+            "copy": _collect_copy.snapshot()}
 
 
 def _start_fetch(buf) -> dict:
@@ -2794,14 +2797,14 @@ def _start_fetch(buf) -> dict:
     `_collect`'s device_get finds the bytes there or in flight. Called
     right after the program call, inside its `_launch` block; `buf` may
     be a tree of arrays (the mesh program's outputs). Returns the
-    per-launch fields of the layout that `_collect` counts by."""
+    per-launch field of the layout that `_collect` counts by."""
     try:
         for leaf in jax.tree_util.tree_leaves(buf):
             leaf.copy_to_host_async()
         prefetched = True
     except (AttributeError, RuntimeError):
         prefetched = False
-    return {"_prefetched": prefetched, "_launched": _time.perf_counter()}
+    return {"_prefetched": prefetched}
 
 
 def _span_args(bind) -> dict:
@@ -5246,23 +5249,36 @@ def _collect(out, layout, leaf):
     wake-up when the launching thread gets here: the whole round trip
     only where the collect follows its launch at once. Counted in
     `collects` (`prefetched` where the layout says the launch started
-    the copy) and, by the host's time since the launch returned, in
-    `collect_lead`. `unpack` begins by releasing the breaker hold: the
+    the copy) and timed in two parts that sum to the phase, each
+    weighted like it (`collect_parts`): `compute`, up to the return of
+    `block_until_ready` (what is left of the program, and the runtime
+    waking the thread), and `copy`, the rest (what is left of the copy,
+    and the conversion to host arrays); spans `collect:compute` and
+    `collect:copy` while a trace runs. A device error raised at either
+    leaves through the same `finally`, its time all `compute` where the
+    program failed. `unpack` begins by releasing the breaker hold: the
     transient device accumulators are dead once the result is on host —
     release NOW instead of waiting for GC. Released on the error exit
-    too (a failed device_get must not pin breaker bytes until
-    collection of the GC backstop)."""
+    too (a failed collect must not pin breaker bytes until collection
+    of the GC backstop)."""
     _collects.counter("total").inc()
     if layout.get("_prefetched"):
         _collects.counter("prefetched").inc()
-    launched = layout.get("_launched")
-    if launched is not None:
-        _collect_lead.inc(_time.perf_counter() - launched)
+    compute = None
     try:
         with _trace_guard.trap():
-            return jax.device_get(out)
+            with _enclosing("collect:compute", **leaf.args):
+                jax.block_until_ready(out)
+            compute = leaf.lap()
+            with _enclosing("collect:copy", **leaf.args):
+                return jax.device_get(out)
     finally:
-        leaf.switch("unpack")
+        whole = leaf.switch("unpack")
+        if compute is None:
+            compute = whole
+        for _ in range(leaf.weight):
+            _collect_compute.inc(compute)
+            _collect_copy.inc(whole - compute)
         hold = layout.get("_breaker_hold")
         if hold is not None:
             hold.release()

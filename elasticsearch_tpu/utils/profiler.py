@@ -21,9 +21,13 @@ fan-out of five, alone on its reader calls or sharing them.
 Spans are LEAVES: no `query_phase:` span may lie inside another on its
 thread (the benchmark's trace reduction gives each device gap to the
 `query_phase:` span covering most of it, and an enclosing span would
-win every gap). A span that encloses others is named outside the
-prefix (`enclosing`). Never enter a phase inside a jitted function: its
-body runs only while tracing (graftlint's trace-purity pass flags it).
+win every gap). A span that encloses others, or one drawn inside a
+leaf or around a sleep for a person at a trace viewer, is named outside
+the prefix (`enclosing`). A leaf or a wait that is split into parts
+keeps its parts' timers beside the phases, never among them: the
+phases must sum to the searches' time once. Never enter a phase inside
+a jitted function: its body runs only while tracing (graftlint's
+trace-purity pass flags it).
 """
 
 from __future__ import annotations
@@ -153,7 +157,10 @@ class phase:
     name (the reader's `bind` block is paused by the executor around
     each launch; its two spans lie either side of `dispatch`).
     `switch(name)` ends the phase and begins the next leaf of the same
-    block (`collect` then `unpack`), each counted under its own name."""
+    block (`collect` then `unpack`), each counted under its own name,
+    and returns the seconds of the phase it ended; `lap()` reads the
+    seconds so far without ending it, so that a caller can split the
+    leaf into parts that sum to it (the executor's collect)."""
 
     __slots__ = ("name", "weight", "args", "seconds", "_t0", "_span")
 
@@ -184,11 +191,18 @@ class phase:
                 self._span.__exit__(None, None, None)
                 self._span = None
 
-    def switch(self, name: str) -> None:
+    def lap(self) -> float:
+        if self._t0 is None:
+            return self.seconds
+        return self.seconds + time.perf_counter() - self._t0
+
+    def switch(self, name: str) -> float:
         self.__exit__()
+        ended = self.seconds
         self.name = name
         self.seconds = 0.0
         self.resume()
+        return ended
 
     def __exit__(self, *exc) -> None:
         timer = _phases.mean(self.name)     # before the last reading
@@ -206,15 +220,16 @@ def waited(name: str, seconds: float) -> None:
 
 
 class enclosing:
-    """A span around other spans, for a person at a trace viewer
-    (`request:search`, `request:round`, `request:merge`): `name` lies
-    outside the `query_phase:` prefix, so the trace reduction drops it,
-    and it feeds no phase timer: what it covers is no tile of a search
-    (the leaves inside or around it are). `timer`, where given, is the
-    owner's MeanMetric and always gets the block's seconds once (the
-    scheduler's `leader`, the node's `merge`: shown beside `phases`,
-    never inside it). With no trace active and no timer, two clock
-    readings."""
+    """A span for a person at a trace viewer, around other spans
+    (`request:search`, `request:round`, `request:merge`), inside a leaf
+    (`collect:compute`, `collect:copy`) or around a caller's sleep
+    (`wait:scheduler`): `name` lies outside the `query_phase:` prefix,
+    so the trace reduction drops it, and it feeds no phase timer: what
+    it covers is no tile of a search, or a part of one that a phase
+    already counts. `timer`, where given, is the owner's MeanMetric and
+    always gets the block's seconds once (the scheduler's `leader`, the
+    node's `merge`: shown beside `phases`, never inside it). With no
+    trace active and no timer, it reads no clock."""
 
     __slots__ = ("_span", "_timer", "_t0")
 
@@ -226,15 +241,16 @@ class enclosing:
     def __enter__(self) -> "enclosing":
         if self._span is not None:
             self._span.__enter__()
-        self._t0 = time.perf_counter()
+        if self._timer is not None:
+            self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> None:
-        seconds = time.perf_counter() - self._t0
+        end = time.perf_counter() if self._timer is not None else 0.0
         if self._span is not None:
             self._span.__exit__(None, None, None)
         if self._timer is not None:
-            self._timer.inc(seconds)
+            self._timer.inc(end - self._t0)
 
 
 def phase_stats() -> dict:

@@ -3,8 +3,9 @@
 that a caller who launches several programs before collecting the first
 finds the bytes on the host. What that must not change: the answers,
 the breaker holds, the phases. What says that it engaged:
-`_nodes/stats/dispatch` -> `collects`, `collect_lead`, and the
-benchmark's `prefetched_collect_pct`.
+`_nodes/stats/dispatch` -> `collects`, `collect_parts` (the collect's
+time up to the program's end and after it), and the benchmark's
+`prefetched_collect_pct`.
 """
 
 import copy
@@ -24,6 +25,7 @@ from elasticsearch_tpu.index.mapping import MapperService
 from elasticsearch_tpu.node import Node
 from elasticsearch_tpu.rest.server import RestServer
 from elasticsearch_tpu.search import executor, shard_searcher
+from elasticsearch_tpu.utils import profiler
 from elasticsearch_tpu.utils.breaker import breaker_service
 from elasticsearch_tpu.utils.errors import SearchTimeoutError
 from elasticsearch_tpu.utils.settings import Settings
@@ -105,9 +107,13 @@ def test_a_cold_launch_asks_for_the_copy_once_and_before_its_collect(
     assert events == [("fetch", id(out))]
     assert executor.launch_counts()["unfused"] == launches + 1
     assert layout["_prefetched"] is True
-    assert layout["_launched"] <= time.perf_counter()
+    parts = executor.collect_parts()
     executor.collect_segment_result(out, layout, n_real)
     assert events == [("fetch", id(out)), ("collect", id(out))]
+    # the one collect is timed once in each of its two parts
+    after = executor.collect_parts()
+    assert [after[p]["count"] - parts[p]["count"]
+            for p in ("compute", "copy")] == [1, 1]
 
 
 @pytest.mark.parametrize("plan", PLANS)
@@ -141,15 +147,19 @@ class _RefusedCopy:
                          ids=["no_such_method", "refused"])
 def test_a_copy_that_cannot_start_is_counted_as_not_prefetched(buf):
     fields = executor._start_fetch(buf)
-    assert fields["_prefetched"] is False and fields["_launched"] > 0
-    before, lead = executor.collect_counts(), executor.collect_lead()
-    leaf = types.SimpleNamespace(switch=lambda name: None)
-    assert executor._collect(np.arange(3), fields, leaf).tolist() \
-        == [0, 1, 2]
+    assert fields == {"_prefetched": False}
+    before, parts = executor.collect_counts(), executor.collect_parts()
+    with profiler.phase("collect", weight=2) as leaf:
+        assert executor._collect(np.arange(3), fields, leaf).tolist() \
+            == [0, 1, 2]
+        assert leaf.name == "unpack"
     after = executor.collect_counts()
     assert after["total"] == before["total"] + 1
     assert after["prefetched"] == before["prefetched"]
-    assert executor.collect_lead()["count"] == lead["count"] + 1
+    # weighted like the phase: two searches waited through it
+    moved = {p: executor.collect_parts()[p]["count"] - parts[p]["count"]
+             for p in ("compute", "copy")}
+    assert moved == {"compute": 2, "copy": 2}
 
 
 def test_the_pack_launch_asks_for_the_copy_too():
@@ -212,6 +222,40 @@ def test_a_pend_abandoned_by_its_deadline_releases_every_hold(
         "hits"]["total"] == 40
 
 
+def test_a_device_error_at_the_program_s_end_releases_every_hold(
+        reader, monkeypatch):
+    """The collect waits for the program (`block_until_ready`) before
+    it copies: a device error raised there leaves `finish` with its own
+    type, through the same exit, which lets go of every hold."""
+    req = breaker_service().breaker("request")
+    used = req.used
+    bodies = [copy.deepcopy(PLANS[p]) for p in ("top_k", "sorted")]
+    pend = reader.msearch_submit(bodies)
+    layouts = [lay for g in pend.groups for _o, lay, _n in g["pending"]]
+    holds = [lay["_breaker_hold"] for lay in layouts]
+    assert len(holds) == 2 and all(h.bytes > 0 for h in holds)
+
+    def fails(x):
+        raise jax.errors.JaxRuntimeError(
+            "INTERNAL: the program failed on the device")
+
+    parts = executor.collect_parts()
+    with monkeypatch.context() as mp:
+        mp.setattr(jax, "block_until_ready", fails)
+        with pytest.raises(jax.errors.JaxRuntimeError):
+            pend.finish()
+    assert [h.bytes for h in holds] == [0, 0]
+    assert req.used == used
+    # the collect that failed is counted once for each of the reader
+    # call's two bodies, like its phase, and its time is all `compute`
+    after = executor.collect_parts()
+    assert [after[p]["count"] - parts[p]["count"]
+            for p in ("compute", "copy")] == [2, 2]
+    assert after["copy"]["sum"] == parts["copy"]["sum"]
+    assert reader.msearch([copy.deepcopy(PLANS["top_k"])])[0][
+        "hits"]["total"] == 40
+
+
 # -- what says that it engaged -------------------------------------------------
 
 class Served:
@@ -269,12 +313,17 @@ def moved(before: dict, after: dict) -> dict:
         "total": after["collects"]["total"] - before["collects"]["total"],
         "prefetched": after["collects"]["prefetched"]
         - before["collects"]["prefetched"],
-        "lead_count": after["collect_lead"]["count"]
-        - before["collect_lead"]["count"],
-        "lead_sum": after["collect_lead"]["sum"]
-        - before["collect_lead"]["sum"],
+        "compute_count": after["collect_parts"]["compute"]["count"]
+        - before["collect_parts"]["compute"]["count"],
+        "copy_count": after["collect_parts"]["copy"]["count"]
+        - before["collect_parts"]["copy"]["count"],
+        "parts_sum": sum(after["collect_parts"][p]["sum"]
+                         - before["collect_parts"][p]["sum"]
+                         for p in ("compute", "copy")),
         "collect_phase": after["phases"]["collect"]["count"]
-        - before["phases"]["collect"]["count"],
+        - before["phases"].get("collect", {"count": 0})["count"],
+        "collect_sum": after["phases"]["collect"]["sum"]
+        - before["phases"].get("collect", {"sum": 0.0})["sum"],
     }
 
 
@@ -292,13 +341,17 @@ def test_over_rest_every_collect_found_its_copy_started(served):
     wall = time.perf_counter() - t0
     m = moved(before, served.dispatch_stats())
     assert m["launches"] == n * served.shards
-    assert m["total"] == m["prefetched"] == m["lead_count"] == m["launches"]
+    assert m["total"] == m["prefetched"] == m["compute_count"] \
+        == m["copy_count"] == m["launches"]
     assert m["collect_phase"] == m["total"]
-    # a launch's lead is host time of its own round, on one thread
-    assert 0 < m["lead_sum"] < wall * served.shards
-    # beside `phases`, never inside: they are no tiles of a search
+    # the two parts tile the phase: host time of the searches' own
+    # rounds, on one thread
+    assert m["parts_sum"] == pytest.approx(m["collect_sum"], rel=1e-9)
+    assert 0 < m["parts_sum"] < wall
+    # beside `phases`, never inside: `collects` is no time, and the
+    # parts are time `collect` counts already
     phases = served.dispatch_stats()["phases"]
-    assert "collects" not in phases and "collect_lead" not in phases
+    assert not {"collects", "collect_parts", "compute", "copy"} & set(phases)
 
 
 def test_an_msearch_batch_collects_what_it_launched(served):
@@ -309,7 +362,11 @@ def test_an_msearch_batch_collects_what_it_launched(served):
     assert [s["status"] for s in r["responses"]] == [200] * len(PLANS)
     m = moved(before, served.dispatch_stats())
     assert m["launches"] == len(PLANS) * served.shards
-    assert m["total"] == m["prefetched"] == m["lead_count"] == m["launches"]
+    assert m["total"] == m["prefetched"] == m["launches"]
+    # weighted like the phase: the reader call serves every body
+    assert m["compute_count"] == m["copy_count"] == m["collect_phase"] \
+        == m["total"] * len(PLANS)
+    assert m["parts_sum"] == pytest.approx(m["collect_sum"], rel=1e-9)
 
 
 # -- the benchmark's reader ---------------------------------------------------

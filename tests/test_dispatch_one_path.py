@@ -143,9 +143,10 @@ def test_no_second_round_executor():
 # executors became one (commit fa237f9), neither guard armed
 DISPATCH_KEYS = [
     "adopted_batches", "batches_dispatched", "bound_plans",
-    "coalesced_queries", "collect_lead", "collects", "eviction", "failover", "launches",
-    "leader", "membership", "merge", "mesh", "phases", "pipeline_depth",
-    "queries", "resident", "searches", "traffic", "window"]
+    "coalesced_queries", "collect_parts", "collects", "eviction", "failover",
+    "launches", "leader", "membership", "merge", "mesh", "phases",
+    "pipeline_depth", "queries", "resident", "scheduler_wait", "searches",
+    "traffic", "window"]
 
 
 def test_the_stats_keep_their_keys(node):
@@ -159,7 +160,11 @@ def test_the_stats_keep_their_keys(node):
     assert sorted(snap["collects"]) == ["prefetched", "total"]
     assert sorted(snap["bound_plans"]) == ["bypassed", "entries",
                                            "evictions", "hits", "misses"]
-    assert sorted(snap["collect_lead"]) == ["count", "mean", "sum"]
+    assert sorted(snap["collect_parts"]) == ["compute", "copy"]
+    assert sorted(snap["scheduler_wait"]) == ["parked", "queued", "woken"]
+    for part in [*snap["collect_parts"].values(),
+                 *snap["scheduler_wait"].values()]:
+        assert sorted(part) == ["count", "mean", "sum"]
     assert sorted(snap["mesh"]) == ["fallbacks", "refresh", "searches"]
     assert node.nodes_stats()["nodes"][node.name]["dispatch"].keys() \
         == snap.keys()

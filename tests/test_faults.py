@@ -368,6 +368,48 @@ class TestReplicaFailover:
         assert failover_stats.retries.count == retries + 1
         assert failover_stats.succeeded.count == succeeded + 1
 
+    def test_a_device_error_at_the_program_s_end_fails_over(
+            self, mesh_node, monkeypatch):
+        """The collect waits for the program (`block_until_ready`)
+        before it copies: a device error raised there reaches the
+        failover as one raised by the copy did, and the failed collect
+        is counted, all of its time `compute`."""
+        import jax
+        from elasticsearch_tpu.parallel.mesh import build_mesh
+        from elasticsearch_tpu.parallel.distributed import (
+            PackedShards, DistributedSearcher)
+        from elasticsearch_tpu.search import executor
+        from elasticsearch_tpu.search.dispatch import failover_stats
+        dist = DistributedSearcher(PackedShards.from_node_index(
+            mesh_node, "m", build_mesh(4, 2)))
+        body = {"query": {"match": {"message": "quick"}}, "size": 10}
+        want = dist.msearch_submit([dict(body)]).finish()
+        retries = failover_stats.retries.count
+        succeeded = failover_stats.succeeded.count
+        req = breaker_service().breaker("request")
+        base = req.used
+        real, calls = jax.block_until_ready, []
+
+        def fails_once(x):
+            calls.append(x)
+            if len(calls) == 1:
+                raise jax.errors.JaxRuntimeError(
+                    "INTERNAL: the program failed on the device")
+            return real(x)
+
+        monkeypatch.setattr(jax, "block_until_ready", fails_once)
+        parts = executor.collect_parts()
+        got = dist.msearch_submit([dict(body)]).finish()
+        assert calls and [_answer(r) for r in got] \
+            == [_answer(r) for r in want]
+        assert failover_stats.retries.count == retries + 1
+        assert failover_stats.succeeded.count == succeeded + 1
+        assert req.used == base
+        after = executor.collect_parts()
+        assert [after[p]["count"] - parts[p]["count"]
+                for p in ("compute", "copy")] == [1, 1]
+        assert after["copy"]["sum"] == parts["copy"]["sum"]
+
     def test_mesh_straggler_delay_fires_at_collect(self, mesh_node):
         from elasticsearch_tpu.parallel.mesh import build_mesh
         from elasticsearch_tpu.parallel.distributed import (

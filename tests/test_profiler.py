@@ -175,7 +175,7 @@ def test_an_in_process_search_gets_its_id_at_the_node(served):
 
 def host_spans(trace_dir: str) -> dict:
     """{host line: [(name, start_ns, end_ns, {argument: value})]} of the
-    trace's `query_phase:` and `request:` spans."""
+    trace's `query_phase:`, `request:`, `collect:` and `wait:` spans."""
     import jax
     path = sorted(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
                             recursive=True))[-1]
@@ -187,7 +187,8 @@ def host_spans(trace_dir: str) -> dict:
             spans = [(e.name, e.start_ns, e.start_ns + e.duration_ns,
                       dict(e.stats))
                      for e in line.events
-                     if e.name.startswith(("query_phase:", "request:"))]
+                     if e.name.startswith(("query_phase:", "request:",
+                                           "collect:", "wait:"))]
             if spans:
                 lines[i] = sorted(spans, key=lambda s: s[1])
     return lines
@@ -248,6 +249,28 @@ def test_no_phase_span_encloses_another_on_its_thread(traced_search):
             if name in ("request:search", "request:round"):
                 assert any(start <= s[1] and s[2] <= end for s in leaves)
     assert phases == len(SPANS) + 2
+
+
+def test_a_traced_search_draws_its_collect_in_two_parts(traced_search):
+    """`collect:compute` then `collect:copy`, outside the prefix, inside
+    the one `query_phase:collect` of their thread and under its id; and
+    no `wait:scheduler`: the search led its own round and never slept."""
+    drawn = 0
+    for line in traced_search.values():
+        collect = [s for s in line if s[0] == "query_phase:collect"]
+        parts = [s for s in line if s[0].startswith("collect:")]
+        if not collect:
+            assert not parts
+            continue
+        (_name, start, end, args), = collect
+        assert [s[0] for s in parts] == ["collect:compute", "collect:copy"]
+        assert all(start <= s[1] and s[2] <= end and s[3] == args
+                   for s in parts)
+        assert parts[0][2] <= parts[1][1]
+        drawn += len(parts)
+    assert drawn == 2
+    assert not [s for line in traced_search.values() for s in line
+                if s[0].startswith("wait:")]
 
 
 def test_the_enclosing_spans_say_what_they_enclosed(traced_search):
