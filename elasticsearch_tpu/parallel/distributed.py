@@ -30,6 +30,7 @@ bucket arrays reduce exactly across shards.
 from __future__ import annotations
 
 import json
+import pickle
 import time
 import weakref
 from functools import partial
@@ -59,7 +60,7 @@ from ..search.executor import (QueryBinder, finalize, eval_node,
                                _resident_step, _split_deadline,
                                _resident_chunk_tiles, _fused_walk,
                                _walk_steps, _collect, _launch,
-                               _start_fetch)
+                               _start_fetch, plan_switches)
 from ..search import bound_plans
 from ..search.query_dsl import QueryParser
 from ..search.aggregations import (parse_aggs, ShardAggContext, AggSpec,
@@ -78,6 +79,8 @@ from ..utils.profiler import phase, request_args
 # way, so they never retry, never count toward device health, and
 # surface unchanged
 _PARSE_ERRORS = (SearchParseError, QueryParsingError)
+_HETEROGENEOUS = ("distributed msearch requires structurally identical "
+                  "queries (split heterogeneous batches)")
 
 # bodies answered by one program over an index's chips, whichever mesh
 # reader served them (`GET /_nodes/stats/dispatch` -> `mesh.searches`)
@@ -1119,23 +1122,55 @@ class DistributedSearcher:
                body_keys: list) -> None:
         """Launch this pack's programs for `pend`'s bodies, one a
         signature group, without collecting; each launch joins `pend`
-        as generation `gen`. A group this searcher has served before
-        launches from its kept plan (the parse, the bind on every shard,
-        the program and its parameters, placed on the devices), unless
-        its deadline asks for the stepped program form, which a kept
-        plan is not."""
+        as generation `gen`. A body is kept on this searcher
+        (search/bound_plans.py): its parse, its bound trees on every
+        shard's view, its group key, and with it its group of one and
+        the last group of several it came first in, ready to launch
+        with their parameters placed on the devices. A body served
+        alone before, or such a group served again, launches from its
+        key; another group of bodies each kept is assembled from their
+        bound trees (`_assemble`: no parse or bind); a body not kept is
+        parsed and bound, and only it. Not kept: a body without a key
+        or whose parse or bind may not be, and the stepped program form
+        a deadline asks for."""
         bodies = pend.bodies
-        self._plans.open((self.packed.mappers.version,))
+        plans = self._plans
+        plans.open((self.packed.mappers.version, plan_switches()))
+        bound: list = []
+        keys: list = []
+        fresh: set = set()
+        for i, body in enumerate(bodies):
+            key = body_keys[i]
+            bb = plans.get(key) if key is not None else None
+            if bb is None:
+                with bound_plans.keep_scope() as scope:
+                    # a kept parse outlives the caller's dict: it is
+                    # made from a copy (the key has shown the body to
+                    # be plain JSON values)
+                    bb = self._bind_body(pickle.loads(pickle.dumps(
+                        body, -1)) if key is not None else body)
+                if key is not None and scope.keep:
+                    plans.put(key, bb)
+                else:
+                    key = None
+                fresh.add(i)
+            bound.append(bb)
+            keys.append(key)
         groups: dict = {}
-        for i, b in enumerate(bodies):
-            groups.setdefault(self._group_key(b, body_keys[i]),
-                              []).append(i)
+        for i, bb in enumerate(bound):
+            groups.setdefault(bb.sig, []).append(i)
         stepped = pend.deadline is not None and _mesh_stepped_enabled()
         for gk, idxs in groups.items():
             span_args = bind.args = request_args(pend.ids[i] for i in idxs)
             group = [bodies[i] for i in idxs]
-            gkey = tuple(body_keys[i] for i in idxs)
-            st = None if None in gkey else self._plans.get(gkey)
+            gkeys = tuple(keys[i] for i in idxs)
+            kept = None not in gkeys
+            first = bound[idxs[0]]
+            st = None
+            if kept and len(idxs) == 1:
+                st = first.alone
+            elif kept and first.led is not None and first.led[0] == gkeys:
+                st = first.led[1]
             if st is not None and not (stepped and st["fused"] is not None):
                 bound_plans.hits.inc()
                 from ..search import resident
@@ -1143,38 +1178,67 @@ class DistributedSearcher:
                     # the kept plan holds its pinned program: reused
                     resident.stats.resident_hits.inc()
             else:
-                with bound_plans.keep_scope() as scope:
-                    st = self.prepare(group, deadline=pend.deadline,
-                                      sort=gk[-1], place=True)
-                if None in gkey or not scope.keep or st["stepped"]:
+                st = self._assemble([bound[i] for i in idxs],
+                                    deadline=pend.deadline, sort=gk[-1],
+                                    place=True)
+                if not kept or st["stepped"]:
                     bound_plans.bypassed.inc()
                 else:
                     bound_plans.misses.inc()
-                    self._plans.put(gkey, st)
-            out = self._dispatch_uniform(group, deadline=pend.deadline,
-                                         st=st, bind=bind)
+                    if fresh.isdisjoint(idxs):
+                        bound_plans.assembled.inc()
+                    if len(idxs) == 1:
+                        first.alone = st
+                    else:
+                        plans.lead(gkeys[0], first, (gkeys, st))
+            try:
+                out = self._dispatch_uniform(group, deadline=pend.deadline,
+                                             st=st, bind=bind)
+            except BaseException:
+                if kept:
+                    # kept values that do not launch are built anew by
+                    # the next search
+                    plans.drop(gkeys)
+                raise
             pend.parts.append((gen, self, idxs, out, span_args))
 
-    def _group_key(self, body: dict, key: str | None) -> tuple:
-        """What groups a body with others into one program on this
-        pack: its plan's signature, aggregations, k and sort (last; a
-        sort the program cannot reduce by is the score's). Kept under
-        the body's key where the parse and bind may be."""
-        entry = self._plans.get(key) if key is not None else None
-        if entry is not None:
-            return entry
+    def _bind_body(self, body: dict) -> bound_plans.BoundBody:
+        """Parse one body (its query and its aggregations) and bind its
+        query on every shard's view: one plan shape on every shard (the
+        views are the union of the shards' fields). Its `sig` is
+        `_group_key`'s."""
         pk = self.packed
-        with bound_plans.keep_scope() as scope:
+        query = QueryParser(pk.mappers).parse(body.get("query"))
+        bounds = [QueryBinder(view, pk.mappers).bind(query)  # type: ignore
+                  for view in pk.bind_views]
+        sig = bounds[0].signature()
+        if any(bnd.signature() != sig for bnd in bounds[1:]):
+            raise SearchParseError(_HETEROGENEOUS)
+        agg_specs = parse_aggs(body.get("aggs") or body.get("aggregations"))
+        for spec in agg_specs:
+            fm = pk.mappers.field(spec.field)
+            if spec.kind in ("terms", "cardinality", "value_count") and \
+                    fm is not None and fm.type == "text" and \
+                    pk.mappers.field(f"{spec.field}.keyword") is not None:
+                spec.field = f"{spec.field}.keyword"
+        return bound_plans.BoundBody(
+            {"query": query, "agg_specs": agg_specs}, bounds,
+            self._group_key(body, sig))
+
+    def _group_key(self, body: dict, sig: tuple | None = None) -> tuple:
+        """What groups a body with others into one program on this
+        pack: its plan's signature (`sig`, else bound on the first
+        shard's view), aggregations, k and sort (last; a sort the
+        program cannot reduce by is the score's)."""
+        pk = self.packed
+        if sig is None:
             sig = QueryBinder(pk.bind_views[0], pk.mappers).bind(
                 QueryParser(pk.mappers).parse(body.get("query"))
             ).signature()
         aggs = json.dumps(body.get("aggs") or body.get("aggregations") or {},
                           sort_keys=True, default=str)
         size = int(body.get("size", 10)) + int(body.get("from", 0))
-        entry = (sig, aggs, size, mesh_sort(body, pk.mappers) or ("_score",))
-        if key is not None and scope.keep:
-            self._plans.put(key, entry)
-        return entry
+        return (sig, aggs, size, mesh_sort(body, pk.mappers) or ("_score",))
 
     def _rewrite_knn(self, bodies: list[dict]) -> list[dict]:
         """Top-level `knn` sections rewrite onto the knn SCORING CLAUSE
@@ -1232,7 +1296,7 @@ class DistributedSearcher:
         raw results leave to the caller's merge (score order)."""
         groups: dict[tuple, list[int]] = {}
         for i, b in enumerate(bodies):
-            groups.setdefault(self._group_key(b, None)[:3], []).append(i)
+            groups.setdefault(self._group_key(b)[:3], []).append(i)
         return groups
 
     def _raw_uniform(self, bodies: list[dict],
@@ -1390,52 +1454,45 @@ class DistributedSearcher:
                 deadline: float | None = None,
                 allow_stepped: bool | None = None,
                 sort: tuple = ("_score",), place: bool = False) -> dict:
-        """Everything of a dispatch but the launch: parse, bind on every
-        shard's view, finalize, the aggregation context, the fused
-        admission, the compiled program, and the pack they are for. The
-        result launches with `launch` as often as wanted: `submit` keeps
-        it. `sort` is ("_score",) or ("field", name,
-        descending, "num"): a numeric field's values, reduced across the
-        shards by (value, shard, doc). `place` puts the parameters on the
-        mesh's devices now, in the program's layout, so that a kept
-        dispatch uploads nothing."""
+        """Everything of a dispatch but the launch: parse and bind on
+        every shard's view (`_bind_body`), then `_assemble`. The result
+        launches with `launch` as often as wanted. `sort` is ("_score",)
+        or ("field", name, descending, "num"): a numeric field's values,
+        reduced across the shards by (value, shard, doc). `place` puts
+        the parameters on the mesh's devices now, in the program's
+        layout, so that a kept dispatch uploads nothing."""
+        return self._assemble([self._bind_body(b) for b in bodies],
+                              replica, deadline, allow_stepped, sort, place)
+
+    def _assemble(self, bound: list, replica: int = 0,
+                  deadline: float | None = None,
+                  allow_stepped: bool | None = None,
+                  sort: tuple = ("_score",), place: bool = False) -> dict:
+        """A dispatch from bound bodies (`_bind_body`'s, of one group):
+        finalize, the aggregation context of the first body's
+        aggregations, the fused admission, the compiled program, and
+        the pack they are for."""
         pk = self.packed
-        n = len(bodies)
-        parser = QueryParser(pk.mappers)
-        queries = [parser.parse(b.get("query")) for b in bodies]
-        sizes = [int(b.get("size", 10)) + int(b.get("from", 0))
-                 for b in bodies]
+        n = len(bound)
         # the window over all shards; a shard gives at most its rows
-        k_all = next_pow2(max(max(sizes), 1), floor=1)
+        k_all = next_pow2(max(max(bb.sig[2] for bb in bound), 1), floor=1)
         k = min(k_all, pk.cap)
-        agg_specs = parse_aggs(bodies[0].get("aggs")
-                               or bodies[0].get("aggregations"))
-        for spec in agg_specs:
-            fm = pk.mappers.field(spec.field)
-            if spec.kind in ("terms", "cardinality", "value_count") and \
-                    fm is not None and fm.type == "text" and \
-                    pk.mappers.field(f"{spec.field}.keyword") is not None:
-                spec.field = f"{spec.field}.keyword"
+        agg_specs = bound[0].parsed["agg_specs"]
 
         # pad the batch to a power of two (the compiled programs are
         # keyed on log-many batch sizes, as the single-chip executor's)
         # that is a replica-axis multiple
         R = self.n_replicas
         B = ((next_pow2(max(n, 1), floor=1) + R - 1) // R) * R
-        queries = queries + [queries[0]] * (B - n)
+        rows = [bb.bounds for bb in bound] + [bound[0].bounds] * (B - n)
 
-        # bind per (shard, query) against the UNION views; ONE finalize
-        # over the flattened batch guarantees identical desc across shards
-        flat_bounds = []
-        for view in pk.bind_views:
-            binder = QueryBinder(view, pk.mappers)  # type: ignore[arg-type]
-            flat_bounds.extend(binder.bind(q) for q in queries)
-        sig0 = flat_bounds[0].signature()
-        for bnd in flat_bounds[1:]:
-            if bnd.signature() != sig0:
-                raise SearchParseError(
-                    "distributed msearch requires structurally identical "
-                    "queries (split heterogeneous batches)")
+        # the bodies' bound trees on the UNION views, per (shard, query);
+        # ONE finalize over the flattened batch guarantees identical desc
+        # across shards
+        sig0 = bound[0].sig[0]
+        if any(bb.sig[0] != sig0 for bb in bound[1:]):
+            raise SearchParseError(_HETEROGENEOUS)
+        flat_bounds = [r[v] for v in range(len(pk.bind_views)) for r in rows]
         desc, flat_params = finalize(flat_bounds)  # leaves [S_local*B, ...]
         params = jax.tree_util.tree_map(
             lambda a: a.reshape(len(pk.bind_views), B, *a.shape[1:]),

@@ -26,11 +26,17 @@ deleted row, the shards' readers serve the index until a force-merge
 and the view is packed anew then (timed under `mesh.refresh`): the
 index's refresh does it (`Placement.refresh`), else the next search. A
 `MeshReader` lives as long as those readers and holds the searchers of
-that point in time: what they make of a body, and of a group of bodies,
-is kept on them like a `ShardReader`'s (search/bound_plans.py: the
-parse, the bind on every shard, the program and its parameters, placed
-on the devices), so a body served before goes from its key to the
-launch.
+that point in time: what they make of a body is kept on them
+(search/bound_plans.py): its parse, its bound trees on every shard's
+view, its group key, its group of one and the last group of several
+bodies it came first in, with their parameters placed on the devices,
+so a body served alone before, or such a group served again, goes from
+its key to the launch. Another group of several bodies, each kept, is
+assembled from them: it skips the parse and the bind on the shard
+views, and does the finalize of its batch, the fused admission, the
+program's lookup and the upload of its parameters
+(`bound_plans.assembled`, counted among the misses); a body not kept is
+parsed and bound, and only it.
 
 What the program cannot run (`refusal`), or a program that fails (a
 shard's fault, a plan it cannot build), is served by the shards' own

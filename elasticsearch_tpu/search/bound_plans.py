@@ -12,16 +12,30 @@ the reader alone is computed once and kept on the reader:
     packed wire parameters as the device array the upload returned,
     the fused decision, the breaker estimate, the output layout).
 
+A mesh reader's searcher (`parallel/distributed.py`,
+`DistributedSearcher.submit`) keeps only bodies (`BoundBody` too): the
+parsed query and aggregations, the bound trees on each of the pack's
+shard views, the group key (plan signature, aggregations, k, sort),
+and with them its group of one (`alone`) and the last group of several
+bodies it came first in (`led`), each ready to launch with its
+parameters placed on the devices. Another group of bodies, each kept,
+is assembled from their bound trees: one finalize over the batch, the
+fused admission and the program's lookup, one upload, and no parse or
+bind; only a body not kept is parsed and bound.
+
 Keyed by the bodies' canonical JSON (`index/cache.canonical_key`, the
 shard request cache's; that cache keeps results, this one keeps none:
 the program runs on the device for every search). One `BoundPlans`
-holds a body under its key, its group of one with it, and a larger
-group under the tuple of its bodies' keys in order, with
-least-recently-used eviction at `CAPACITY`. What else the kept values
-read is the cache's stamp (the mapping's version and the executor's
-switches, `open`): a change empties it. What the launch reads of the
-device's column tree is checked per launch against the segment's
-`device_epoch` (`executor.segment_plan_valid`).
+holds a body under its key, its group of one with it, and (a shard
+reader) a larger group under the tuple of its bodies' keys in order,
+with least-recently-used eviction at `CAPACITY`; a mesh reader's kept
+body holds besides at most one `alone` and one `led` launch state, so
+at most two groups' parameters on the devices a body, and counts as two
+entries while it holds a `led`. What else the kept values read is the cache's stamp
+(the mapping's version and the executor's switches, `open`): a change
+empties it. What the launch reads of the device's column tree is
+checked per launch against the segment's `device_epoch`
+(`executor.segment_plan_valid`).
 
 Not kept, because the parse or the bind is no function of the body and
 the reader, or does what a kept copy would not repeat: a body that
@@ -31,7 +45,10 @@ something (`note_volatile`, called from where either happens).
 `GET /_nodes/stats/dispatch` -> `bound_plans` counts, once a reader
 call's group: `hits` (launched from what was kept), `misses` (built and
 kept), `bypassed` (bodies and groups that leave the grouped cold path
-or may not be kept), `evictions`, and the `entries` live readers hold.
+or may not be kept), `evictions`, and the `entries` live readers hold;
+and `assembled`, the misses of a mesh reader that were assembled from
+kept bodies alone, without a parse or a bind (each still uploads its
+parameters, so it stays a miss).
 """
 
 from __future__ import annotations
@@ -51,6 +68,7 @@ _counts = MetricsRegistry()
 hits = _counts.counter("hits")
 misses = _counts.counter("misses")
 bypassed = _counts.counter("bypassed")
+assembled = _counts.counter("assembled")
 _evictions = _counts.counter("evictions")
 # a gauge: what live readers hold
 _held = _counts.counter("entries")
@@ -73,15 +91,20 @@ def body_key(body) -> str | None:
 
 @dataclass(slots=True)
 class BoundBody:
-    """One body, parsed and bound against every segment of the reader.
-    `parsed` is shared by every search with this body: read-only.
-    `alone` is the body's `BoundGroup` where it was served as a group
-    of one, which is how a single `_search` arrives."""
+    """One body, parsed and bound against every segment of the reader
+    (a mesh reader: every shard's view, `sig` its group key). `parsed`
+    is shared by every search with this body: read-only. `alone` is
+    the body's `BoundGroup` (a mesh reader's launch state) where it was
+    served as a group of one, which is how a single `_search` arrives.
+    `led` is a mesh reader's (the group's body keys in order, its
+    launch state) of the last group of several bodies this one came
+    first in."""
 
     parsed: dict
     bounds: list
     sig: tuple | None
-    alone: "BoundGroup | None" = None
+    alone: "BoundGroup | dict | None" = None
+    led: tuple | None = None
 
 
 @dataclass(slots=True)
@@ -119,7 +142,7 @@ class BoundPlans:
             return
         with self._lock:
             if stamp != self._stamp:
-                _held.dec(len(self._entries))
+                _held.dec(self._weights())
                 self._entries.clear()
                 self._stamp = stamp
 
@@ -132,22 +155,34 @@ class BoundPlans:
 
     def put(self, key, entry) -> None:
         with self._lock:
-            if key not in self._entries:
-                _held.inc()
+            old = self._entries.get(key)
+            _held.inc(_weight(entry) - (0 if old is None else _weight(old)))
             self._entries[key] = entry
             self._entries.move_to_end(key)
             while len(self._entries) > CAPACITY:
-                self._entries.popitem(last=False)
+                _key, gone = self._entries.popitem(last=False)
                 _evictions.inc()
-                _held.dec()
+                _held.dec(_weight(gone))
+
+    def lead(self, key, body: BoundBody, led: tuple) -> None:
+        """Keep `led` on the body kept under `key` (a mesh reader's group
+        of several, `BoundBody.led`): one entry more while it is held."""
+        with self._lock:
+            if self._entries.get(key) is body and body.led is None:
+                _held.inc()
+            body.led = led
 
     def drop(self, keys) -> None:
         """After a launch from kept values raised: they go, so that the
         next search builds them anew."""
         with self._lock:
             for key in keys:
-                if self._entries.pop(key, None) is not None:
-                    _held.dec()
+                gone = self._entries.pop(key, None)
+                if gone is not None:
+                    _held.dec(_weight(gone))
+
+    def _weights(self) -> int:
+        return sum(map(_weight, self._entries.values()))
 
     def __len__(self) -> int:
         with self._lock:
@@ -157,9 +192,15 @@ class BoundPlans:
         # the reader is gone (a refresh replaced it): its entries leave
         # the process-wide gauge
         try:
-            _held.dec(len(self._entries))
+            _held.dec(self._weights())
         except Exception:  # noqa: BLE001 — interpreter shutdown
             pass
+
+
+def _weight(entry) -> int:
+    """The entries one kept value counts for: a body with the group of
+    several it led two, anything else one."""
+    return 1 if getattr(entry, "led", None) is None else 2
 
 
 # -- what may not be kept ----------------------------------------------------

@@ -6,8 +6,9 @@ the same bodies goes from its key to the launches. On the `http_logs`
 deployments of `tests/test_fan_out_http_logs.py` (4,096 docs, one shard
 and five), over REST and on the readers themselves. An index of five
 shards is one reader where one program serves them
-(`parallel/mesh_reader.py`, which keeps its plans the same way), and
-five shard readers for a body that program refuses.
+(`parallel/mesh_reader.py`, which keeps its bodies the same way and
+assembles a group of several from them), and five shard readers for a
+body that program refuses.
 
 What that must not change: the answers (after a refresh, a delete, a
 mapping update and a reopening too), the breaker holds, the per-launch
@@ -54,12 +55,6 @@ def answer(response: dict) -> dict:
     return {k: v for k, v in response.items() if k != "took"}
 
 
-def kept_a_body(served) -> int:
-    """The entries a reader keeps for a new body served alone: a shard
-    reader one, a mesh reader two (see above)."""
-    return 1 if served.readers == served.shards else 2
-
-
 def readers(served, index: str = "logs") -> list:
     return [eng.acquire_searcher() for _sid, eng in
             sorted(served.node.indices[index].shards.items())]
@@ -87,11 +82,10 @@ def test_a_new_body_is_a_miss_and_then_a_hit_and_is_kept_once(served):
     before = counted(served)
     first = served.search(body)
     after_first = counted(served)
-    # one entry a shard reader: the body, and with it its group of one;
-    # two a mesh reader: what groups the body, and the group's plan
+    # one entry a reader: the body, and with it its group of one
     assert moved(before, after_first) == {
         "hits": 0, "misses": n, "bypassed": 0, "evictions": 0,
-        "entries": n * kept_a_body(served)}
+        "entries": n}
     assert answer(served.search(body)) == answer(first)
     assert moved(after_first, counted(served)) == {
         "hits": n, "misses": 0, "bypassed": 0, "evictions": 0,
@@ -171,6 +165,44 @@ def test_a_stored_script_is_read_anew_by_every_search(served):
         == [h["fields"]["v"][0] for h in two["hits"]["hits"]]
 
 
+@pytest.mark.parametrize("agg", ["avg", "sum", "stats"])
+def test_an_aggregation_over_a_stored_script_follows_the_script(served, agg):
+    """An aggregation the one program over several shards reduces may
+    name a stored script: its parse reads the registry, so no reader
+    keeps the body, and the answer follows the script."""
+    from elasticsearch_tpu.script.service import ScriptService
+    svc = ScriptService.instance()
+    body = {"query": {"range": {"size": {"gte": 23456}}}, "size": 0,
+            "aggs": {"s": {agg: {"script": {"id": "plans_agg_test"}}}}}
+    n = served.readers
+    meshed = 2 if n < served.shards else 0
+    try:
+        svc.put_stored("plans_agg_test", "doc['size'].value + 1")
+        served.search(body)
+        before = counted(served)
+        mesh0 = served.dispatch_stats()["mesh"]["searches"]
+        one = served.search(body)["aggregations"]["s"]
+        svc.put_stored("plans_agg_test", "doc['size'].value + 2")
+        two = served.search(body)["aggregations"]["s"]
+        assert moved(before, counted(served)) == {
+            "hits": 0, "misses": 0, "bypassed": 2 * n, "evictions": 0,
+            "entries": 0}
+        assert served.dispatch_stats()["mesh"]["searches"] - mesh0 == meshed
+    finally:
+        svc.delete_stored("plans_agg_test")
+    docs = served.reference.corpus.cols["size"]
+    count = int((docs >= 23456).sum())
+    assert count > 0
+    step = {"avg": 1, "sum": count}
+    if agg == "stats":
+        assert two["count"] == one["count"] == count
+        for key in ("min", "max", "avg"):
+            assert two[key] == pytest.approx(one[key] + 1)
+        assert two["sum"] == pytest.approx(one["sum"] + count)
+    else:
+        assert two["value"] == pytest.approx(one["value"] + step[agg])
+
+
 def test_two_bodies_that_differ_in_one_constant_are_two_entries(served):
     n = served.readers
     bodies = [{"query": {"range": {"size": {"gte": gte}}}, "size": 5}
@@ -179,7 +211,7 @@ def test_two_bodies_that_differ_in_one_constant_are_two_entries(served):
     totals = [served.search(b)["hits"]["total"] for b in bodies]
     assert moved(before, counted(served)) == {
         "hits": 0, "misses": 2 * n, "bypassed": 0, "evictions": 0,
-        "entries": 2 * n * kept_a_body(served)}
+        "entries": 2 * n}
     again = [served.search(b)["hits"]["total"] for b in bodies]
     assert again == totals
     docs = served.reference.corpus.cols["size"]

@@ -161,8 +161,9 @@ def test_the_stats_keep_their_keys(node):
     assert sorted(snap["merge"]) == ["count", "hits", "mean",
                                      "shard_results", "sum"]
     assert sorted(snap["collects"]) == ["prefetched", "total"]
-    assert sorted(snap["bound_plans"]) == ["bypassed", "entries",
-                                           "evictions", "hits", "misses"]
+    assert sorted(snap["bound_plans"]) == ["assembled", "bypassed",
+                                           "entries", "evictions", "hits",
+                                           "misses"]
     assert sorted(snap["collect_parts"]) == ["compute", "copy"]
     assert sorted(snap["scheduler_wait"]) == ["parked", "queued", "woken"]
     for part in [*snap["collect_parts"].values(),

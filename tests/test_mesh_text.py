@@ -16,6 +16,10 @@ program over the four devices that runs the fused tile walk on each
 (`launches.fused_xla`), and its tiles are counted for each shard row
 (`fused_scoring.mesh_tiles`) as they are in all (`fused_scoring.tiles`),
 with the most a row scored in each round (`fused_scoring.mesh_rounds`).
+Then what the mesh reader keeps (`search/bound_plans.py`): one entry a
+body, a group of kept bodies assembled without a parse or a bind, the
+boosts checked for every group, and what empties or bypasses the kept
+entries.
 """
 
 import http.client
@@ -222,3 +226,268 @@ def test_the_one_device_index_counts_its_rows_too(one_device):
     assert sum(t["examined"] for t in rows.values()) == moved > 0
     assert after["fused_scoring"]["mesh_rounds"]["launches"] \
         - before["fused_scoring"]["mesh_rounds"]["launches"] == 1
+
+
+# -- what the mesh reader keeps: its bodies ---------------------------------
+
+def _searcher(sv):
+    """The mesh searcher of the index's current views."""
+    svc = sv.node.indices[INDEX]
+    return svc.placement.reader(
+        [eng.acquire_searcher() for _sid, eng in sorted(svc.shards.items())]
+    ).searchers[0]
+
+
+def _fresh(searcher):
+    """A searcher over the same pack that has kept nothing (it shares
+    the compiled programs)."""
+    fresh = searcher.over(searcher.packed)
+    assert fresh is not None and len(fresh._plans) == 0
+    return fresh
+
+
+def _answer(r: dict) -> dict:
+    return {k: v for k, v in r.items() if k != "took"}
+
+
+def _boosted(body: dict, boost: float) -> dict:
+    """The body's `match` with a boost: another body of the same plan
+    shape (a boost is a parameter of the program, not its structure)."""
+    (field, spec), = body["query"]["match"].items()
+    return dict(body, query={"match": {field: dict(spec, boost=boost)}})
+
+
+class Calls:
+    """Outermost calls of the parse and of the bind on any thread (a
+    bool query's parse and bind recurse into their clauses)."""
+
+    def __init__(self, monkeypatch):
+        import threading
+        from elasticsearch_tpu.search.executor import QueryBinder
+        from elasticsearch_tpu.search.query_dsl import QueryParser
+        self.parse = self.bind = 0
+        local = threading.local()
+
+        def counting(name, fn):
+            def call(*a, **kw):
+                if getattr(local, name, False):
+                    return fn(*a, **kw)
+                setattr(local, name, True)
+                setattr(self, name, getattr(self, name) + 1)
+                try:
+                    return fn(*a, **kw)
+                finally:
+                    setattr(local, name, False)
+            return call
+
+        monkeypatch.setattr(QueryParser, "parse",
+                            counting("parse", QueryParser.parse))
+        monkeypatch.setattr(QueryBinder, "bind",
+                            counting("bind", QueryBinder.bind))
+
+
+def _moved(before: dict) -> dict:
+    from elasticsearch_tpu.search import bound_plans
+    after = bound_plans.counts()
+    return {k: after[k] - before[k]
+            for k in ("hits", "misses", "bypassed", "assembled")}
+
+
+def test_a_group_of_bodies_served_alone_is_assembled_without_a_bind(
+        placed, monkeypatch):
+    from elasticsearch_tpu.search import bound_plans
+    searcher = _fresh(_searcher(placed))
+    bodies = [b for b, _s in drawn(placed.corpus, "or", 5)]
+    for b in bodies:
+        searcher.search(b)
+    calls = Calls(monkeypatch)
+    before = bound_plans.counts()
+    got = searcher.msearch(bodies)
+    assert (calls.parse, calls.bind) == (0, 0)
+    assert _moved(before) == {"hits": 0, "misses": 1, "bypassed": 0,
+                              "assembled": 1}
+    # the group is kept on its first body, and counted as an entry
+    assert bound_plans.counts()["entries"] - before["entries"] == 1
+    assert len(searcher._plans) == len(bodies)
+    # the same group again launches from what its first body led; in
+    # another order it is assembled again
+    before = bound_plans.counts()
+    assert [_answer(r) for r in searcher.msearch(bodies)] \
+        == [_answer(r) for r in got]
+    assert _moved(before) == {"hits": 1, "misses": 0, "bypassed": 0,
+                              "assembled": 0}
+    before = bound_plans.counts()
+    searcher.msearch(bodies[::-1])
+    assert _moved(before) == {"hits": 0, "misses": 1, "bypassed": 0,
+                              "assembled": 1}
+    assert (calls.parse, calls.bind) == (0, 0)
+    monkeypatch.undo()
+    want = _fresh(searcher).msearch(bodies)
+    assert [_answer(r) for r in got] == [_answer(r) for r in want]
+    assert all(r["hits"]["hits"] for r in got)
+
+
+@pytest.mark.parametrize("n", [2, 4])
+def test_bodies_served_alone_are_kept_once_each(placed, n):
+    searcher = _fresh(_searcher(placed))
+    body = drawn(placed.corpus, "and", 3)[0][0]
+    bodies = [_boosted(body, 1.0 + i) for i in range(n)]
+    for b in bodies:
+        searcher.search(b)
+    # one entry a body, its group of one with it
+    assert len(searcher._plans) == n
+    from elasticsearch_tpu.search import bound_plans
+    before = bound_plans.counts()
+    for b in bodies:
+        searcher.search(b)
+    assert _moved(before) == {"hits": n, "misses": 0, "bypassed": 0,
+                              "assembled": 0}
+
+
+@pytest.mark.parametrize("kept", [1, 3])
+def test_a_group_of_kept_and_new_bodies_binds_only_the_new(
+        placed, monkeypatch, kept):
+    from elasticsearch_tpu.search import bound_plans
+    searcher = _fresh(_searcher(placed))
+    body = drawn(placed.corpus, "or", 4)[0][0]
+    bodies = [_boosted(body, 1.0 + i) for i in range(4)]
+    for b in bodies[:kept]:
+        searcher.search(b)
+    calls = Calls(monkeypatch)
+    before = bound_plans.counts()
+    got = searcher.msearch(bodies)
+    new = len(bodies) - kept
+    views = len(searcher.packed.bind_views)
+    assert (calls.parse, calls.bind) == (new, new * views)
+    assert _moved(before) == {"hits": 0, "misses": 1, "bypassed": 0,
+                              "assembled": 0}
+    assert len(searcher._plans) == len(bodies)
+    monkeypatch.undo()
+    want = _fresh(searcher).msearch(bodies)
+    assert [_answer(r) for r in got] == [_answer(r) for r in want]
+
+
+def test_a_body_of_no_positive_boost_keeps_its_group_off_the_fused_walk(
+        placed):
+    searcher = _fresh(_searcher(placed))
+    body = drawn(placed.corpus, "or", 3)[0][0]
+    bodies = [_boosted(body, 2.0), _boosted(body, 0.0)]
+    searcher.search(bodies[0])          # alone: admitted, and kept
+    before = placed.stats()
+    got = searcher.msearch(bodies)
+    after = placed.stats()
+    d0, d1 = before["dispatch"]["launches"], after["dispatch"]["launches"]
+    assert d1.get("fused_xla", 0) == d0.get("fused_xla", 0)
+    assert d1["unfused"] - d0.get("unfused", 0) == 1
+    a0 = before["fused_scoring"]["admission"]["rejected"]
+    a1 = after["fused_scoring"]["admission"]["rejected"]
+    assert a1["nonpositive_boost"] - a0.get("nonpositive_boost", 0) == 1
+    want = _fresh(searcher).msearch(bodies)
+    assert [_answer(r) for r in got] == [_answer(r) for r in want]
+    # the kept body alone is still the fused walk's
+    before = placed.stats()["dispatch"]["launches"]
+    searcher.search(bodies[0])
+    assert placed.stats()["dispatch"]["launches"]["fused_xla"] \
+        - before["fused_xla"] == 1
+
+
+def test_a_stepped_plan_is_not_kept(placed):
+    import time
+    from elasticsearch_tpu.search import bound_plans
+    searcher = _fresh(_searcher(placed))
+    body = drawn(placed.corpus, "or", 6)[0][0]
+    before = bound_plans.counts()
+    stepped = searcher.msearch([body], deadline=time.monotonic() + 300)
+    assert _moved(before) == {"hits": 0, "misses": 0, "bypassed": 1,
+                              "assembled": 0}
+    bb = searcher._plans.get(bound_plans.body_key(body))
+    assert bb is not None and bb.alone is None
+    before = bound_plans.counts()
+    plain = searcher.msearch([body])
+    # the body was kept, its group of one was not: assembled now
+    assert _moved(before) == {"hits": 0, "misses": 1, "bypassed": 0,
+                              "assembled": 1}
+    assert _answer(stepped[0]) == _answer(plain[0])
+
+
+def test_a_switch_of_the_executor_empties_what_the_mesh_reader_kept(
+        placed, monkeypatch):
+    """A body kept with the fused engines on is not launched from what
+    was kept once they are off (`plan_switches`, in the stamp)."""
+    from elasticsearch_tpu.search import bound_plans
+    searcher = _fresh(_searcher(placed))
+    body = drawn(placed.corpus, "or", 4)[0][0]
+    searcher.search(body)
+    monkeypatch.setenv("ES_TPU_FUSED", "0")
+    before = bound_plans.counts()
+    launched = placed.stats()["dispatch"]["launches"]
+    got = searcher.search(body)
+    after = placed.stats()["dispatch"]["launches"]
+    assert _moved(before) == {"hits": 0, "misses": 1, "bypassed": 0,
+                              "assembled": 0}
+    assert after.get("fused_xla", 0) == launched.get("fused_xla", 0)
+    assert after["unfused"] - launched.get("unfused", 0) == 1
+    assert _answer(got) == _answer(_fresh(searcher).search(body))
+
+
+def test_callers_on_several_threads_share_what_is_kept(placed, monkeypatch):
+    """More threads than cores on one searcher, groups of kept and new
+    bodies and room for six entries: every answer is a lone caller's,
+    and the searcher never holds more than its capacity."""
+    import os
+    import sys
+    import threading
+    from elasticsearch_tpu.search import bound_plans
+    searcher = _fresh(_searcher(placed))
+    body = drawn(placed.corpus, "or", 3)[0][0]
+    bodies = [_boosted(body, 1.0 + i) for i in range(5)]
+    alone = [_answer(searcher.search(b)) for b in bodies]
+    monkeypatch.setattr(bound_plans, "CAPACITY", 6)
+    wrong, errors, over = [], [], []
+
+    def work(seed: int) -> None:
+        try:
+            for i in range(6):
+                idx = [(seed + i + j) % len(bodies) for j in range(1 + i % 3)]
+                got = searcher.msearch([bodies[j] for j in idx])
+                if [_answer(r) for r in got] != [alone[j] for j in idx]:
+                    wrong.append(idx)
+                if len(searcher._plans) > 6:
+                    over.append(idx)
+        except Exception as e:  # noqa: BLE001 — reported below
+            errors.append(e)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=work, args=(t,))
+                   for t in range(2 * (os.cpu_count() or 4))]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=300)
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in threads)
+    assert not errors and not wrong and not over
+
+
+def test_a_mapping_change_empties_what_was_kept(placed):
+    """Last in the module: the mapping it gains stays."""
+    from elasticsearch_tpu.search import bound_plans
+    searcher = _searcher(placed)
+    body = drawn(placed.corpus, "and", 5)[0][0]
+    first = placed.search([body], together=False)[0]
+    before = bound_plans.counts()
+    placed.search([body], together=False)
+    assert _moved(before)["hits"] == 1
+    assert len(searcher._plans) > 2
+    placed.call("PUT", f"/{INDEX}/_mapping",
+                {"properties": {"kept_plans_probe": {"type": "keyword"}}})
+    before = bound_plans.counts()
+    again = placed.search([body], together=False)[0]
+    assert _moved(before) == {"hits": 0, "misses": 1, "bypassed": 0,
+                              "assembled": 0}
+    assert _searcher(placed) is searcher
+    assert len(searcher._plans) == 1
+    assert _answer(again) == _answer(first)
